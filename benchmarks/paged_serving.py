@@ -270,7 +270,9 @@ def main():
         }
         entry["resolved_layout"] = kernel_ops.resolve_kv_layout(
             (batch, 4 * hkv, hkv, mean_len, hd), capacity=capacity,
-            page_size=PAGE_SIZE, backend="tpu" if "tpu" in tname else "gpu")
+            page_size=PAGE_SIZE,
+            # The CPU rehearsal topology carries the v5p megacore figures.
+            backend="cpu" if "tpu" in tname else "gpu")
         payload["placement"][tname] = entry
 
     # Extend-phase prefill: modeled cost of the PR-3 paged prefill kernel

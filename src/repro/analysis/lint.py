@@ -428,14 +428,16 @@ _COLLECTIVE_IDENTS = ("psum", "psum_scatter", "all_gather", "ppermute",
 _COLLECTIVE_SCOPES = ("src/repro/kernels", "src/repro/serving",
                       "src/repro/cache")
 _COLLECTIVE_ALLOWED = ("src/repro/kernels/decode_common.py",
+                       "src/repro/kernels/ops.py",
                        "src/repro/serving/sampling.py")
 
 
 @rule(
     "collectives-only-in-combine",
     "cross-device collectives (psum/all_gather/ppermute/...) may only "
-    "appear in the sanctioned combine and sampling modules "
-    "(kernels/decode_common.py, serving/sampling.py) — never in kernel "
+    "appear in the sanctioned combine, dispatch and sampling modules "
+    "(kernels/decode_common.py, kernels/ops.py's head-parallel gather, "
+    "serving/sampling.py) — never in kernel "
     "bodies, the scheduler, or the page pool, which must stay "
     "device-pure under the head-sharded mesh",
 )

@@ -1,120 +1,70 @@
-"""JAX version / backend compatibility layer.
+"""JAX API surface the repo funnels through one module.
 
-Every JAX API whose surface has churned across the versions this repo
-supports is funnelled through here, so a JAX bump is a one-file change:
+The supported JAX is 0.9.0 (``requirements-dev.txt``, ``pyproject.toml``).
+Every API that has been renamed across JAX releases goes through here, so
+a JAX bump is a one-file change:
 
-  * ``tpu_compiler_params(...)`` — Pallas TPU compiler params. Newer JAX
-    exposes ``pltpu.CompilerParams`` and a ``GridDimensionSemantics`` enum;
-    0.4.x exposes ``pltpu.TPUCompilerParams`` taking the literal strings
-    ``"parallel"`` / ``"arbitrary"``. Callers always pass the string
-    constants :data:`PARALLEL` / :data:`ARBITRARY`; this shim converts to
-    whatever the installed JAX wants.
-  * ``make_mesh(...)`` — ``jax.make_mesh`` grew an ``axis_types=`` kwarg
-    (with ``jax.sharding.AxisType``) after 0.4.37. Callers pass the string
-    names ``"auto"`` / ``"explicit"`` / ``"manual"``; on JAX without axis
-    types the kwarg is dropped (0.4.x meshes behave like all-Auto).
+  * ``tpu_compiler_params(...)`` — Pallas TPU compiler params
+    (``pltpu.CompilerParams``). Callers pass the grid-dimension semantics
+    :data:`PARALLEL` / :data:`ARBITRARY` re-exported here.
+  * ``make_mesh(...)`` — ``jax.make_mesh`` with ``axis_types`` spelled as
+    :data:`AXIS_AUTO` / :data:`AXIS_EXPLICIT` / :data:`AXIS_MANUAL`.
   * backend / interpret detection — ``default_backend()`` / ``on_tpu()`` /
     ``use_interpret()`` centralize the "can this host lower Mosaic?" test
     that the kernels, ops dispatch and models previously duplicated.
-  * ``enable_compilation_cache(...)`` — the persistent compilation cache
-    moved from ``jax.experimental.compilation_cache.initialize_cache`` to
-    plain config flags across the supported range; the serving engine calls
-    this once so steady-state decode never recompiles across processes.
+  * ``enable_compilation_cache()`` — JAX's persistent compilation cache at
+    ``JAX_COMPILATION_CACHE_DIR`` when that is set, else at the fixed
+    :data:`DEFAULT_CACHE_DIR` inside the checkout.
   * ``donating_jit(...)`` — ``jax.jit`` with ``donate_argnums`` that stays
     quiet on backends where donation is unsupported (CPU XLA warns
     "Some donated buffers were not usable" on every call).
 
-Supported-JAX policy (see ROADMAP.md): oldest supported is 0.4.37 (the
-container's pinned toolchain); the shims are written against the 0.5-0.7
-renames so a newer host works unmodified. No other module may reference
-``CompilerParams`` / ``TPUCompilerParams`` / ``AxisType`` directly —
-the ``compat-only-versioned-jax`` linter rule (``repro.analysis.lint``,
-run by CI as ``python -m repro.analysis --strict`` and by tier-1 via
-``tests/test_mapping_resolver.py``) enforces this over the AST.
+No other module may name the version-dependent symbols directly (the
+list is ``analysis.lint._VERSIONED_JAX``) — the
+``compat-only-versioned-jax`` linter rule
+(``repro.analysis.lint``, run by CI as ``python -m repro.analysis --strict``
+and by tier-1 via ``tests/test_mapping_resolver.py``) enforces this over
+the AST.
 """
 
 from __future__ import annotations
 
 import functools
-import inspect
+import pathlib
 import warnings
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Callable, Optional, Sequence
 
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
+#: Grid-dimension semantics for ``tpu_compiler_params``.
+PARALLEL = pltpu.GridDimensionSemantics.PARALLEL
+ARBITRARY = pltpu.GridDimensionSemantics.ARBITRARY
 
-def _parse_version(v: str) -> Tuple[int, ...]:
-    parts = []
-    for p in v.split("."):
-        if not p.isdigit():
-            break
-        parts.append(int(p))
-    return tuple(parts) or (0,)
+#: Mesh axis types for ``make_mesh``.
+AXIS_AUTO = jax.sharding.AxisType.Auto
+AXIS_EXPLICIT = jax.sharding.AxisType.Explicit
+AXIS_MANUAL = jax.sharding.AxisType.Manual
 
-
-JAX_VERSION: Tuple[int, ...] = _parse_version(jax.__version__)
-
-# Grid-dimension semantics, spelled as the lowercase strings the 0.4.x
-# dataclass accepts. ``tpu_compiler_params`` upgrades them to the enum on
-# newer JAX.
-PARALLEL = "parallel"
-ARBITRARY = "arbitrary"
-
-# Mesh axis types, spelled as strings; upgraded to jax.sharding.AxisType
-# members when the installed JAX has them.
-AXIS_AUTO = "auto"
-AXIS_EXPLICIT = "explicit"
-AXIS_MANUAL = "manual"
-
-# The params dataclass was renamed TPUCompilerParams -> CompilerParams.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-_DIM_SEMANTICS_ENUM = getattr(pltpu, "GridDimensionSemantics", None)
-
-
-def _convert_dim_semantics(dims):
-    if dims is None:
-        return None
-    if _DIM_SEMANTICS_ENUM is None:
-        # Old JAX: pass the literal strings through (and downgrade any
-        # enum-ish values a caller might hand us).
-        return tuple(getattr(d, "name", str(d)).lower() for d in dims)
-    out = []
-    for d in dims:
-        if isinstance(d, str):
-            d = getattr(_DIM_SEMANTICS_ENUM, d.upper())
-        out.append(d)
-    return tuple(out)
+#: Where the persistent compilation cache lives when
+#: ``JAX_COMPILATION_CACHE_DIR`` is not set: one fixed path inside the
+#: checkout (the path is part of the cache key, so it must not move
+#: between runs). Listed in ``.gitignore``.
+DEFAULT_CACHE_DIR = str(pathlib.Path(__file__).resolve().parents[2] / ".jax_cache")
 
 
 def tpu_compiler_params(
     *, dimension_semantics: Optional[Sequence] = None, **kwargs
 ):
-    """Build the Pallas TPU compiler-params object for the installed JAX.
-
-    ``dimension_semantics`` entries are the :data:`PARALLEL` /
-    :data:`ARBITRARY` strings (enum members also accepted). Remaining
-    kwargs (``vmem_limit_bytes``, ...) are forwarded unchanged.
-    """
-    return _COMPILER_PARAMS_CLS(
-        dimension_semantics=_convert_dim_semantics(dimension_semantics),
+    """Build ``pltpu.CompilerParams``. ``dimension_semantics`` entries are
+    :data:`PARALLEL` / :data:`ARBITRARY`; remaining kwargs
+    (``vmem_limit_bytes``, ...) are forwarded unchanged."""
+    return pltpu.CompilerParams(
+        dimension_semantics=(
+            None if dimension_semantics is None else tuple(dimension_semantics)
+        ),
         **kwargs,
     )
-
-
-def _axis_type(name):
-    axis_type_enum = getattr(jax.sharding, "AxisType", None)
-    if axis_type_enum is None:
-        return None
-    if isinstance(name, axis_type_enum):
-        return name
-    return {
-        AXIS_AUTO: axis_type_enum.Auto,
-        AXIS_EXPLICIT: axis_type_enum.Explicit,
-        AXIS_MANUAL: axis_type_enum.Manual,
-    }[str(name).lower()]
 
 
 def make_mesh(
@@ -124,62 +74,31 @@ def make_mesh(
     axis_types: Optional[Sequence] = None,
     devices=None,
 ) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` that tolerates the ``axis_types`` API gap.
+    """``jax.make_mesh``; ``axis_types`` entries are :data:`AXIS_AUTO` /
+    :data:`AXIS_EXPLICIT` / :data:`AXIS_MANUAL`."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=None if axis_types is None else tuple(axis_types),
+        devices=devices,
+    )
 
-    ``axis_types`` entries are :data:`AXIS_AUTO` / :data:`AXIS_EXPLICIT` /
-    :data:`AXIS_MANUAL` strings. On JAX without ``jax.sharding.AxisType``
-    (<= 0.4.x) the argument is dropped: those versions have no explicit
-    sharding mode, so every axis already behaves like ``Auto``.
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    A directory already configured (``JAX_COMPILATION_CACHE_DIR``, which
+    JAX reads at import) is kept as it is; otherwise the cache goes to
+    :data:`DEFAULT_CACHE_DIR`. Every compilation is cached, however small
+    or fast, so a second process with the same programs compiles nothing.
+    Program entry points call this once; the library never does.
     """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if axis_types is not None:
-        sig = inspect.signature(jax.make_mesh)
-        if "axis_types" in sig.parameters and _axis_type(axis_types[0]) is not None:
-            kwargs["axis_types"] = tuple(_axis_type(t) for t in axis_types)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> bool:
-    """Turn on JAX's persistent compilation cache, best-effort.
-
-    Returns True when a cache directory is active afterwards. The API
-    surface moved across the supported range (``initialize_cache(path)``
-    on 0.4.x, ``jax.config.update("jax_compilation_cache_dir", ...)`` plus
-    threshold flags later), so every path is attempted and failures are
-    swallowed: the cache is a steady-state-latency optimization, never a
-    correctness dependency.
-    """
-    active = False
-    if cache_dir is not None:
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            active = True
-        except Exception:
-            try:
-                from jax.experimental.compilation_cache import (
-                    compilation_cache as _cc,
-                )
-                _cc.initialize_cache(cache_dir)
-                active = True
-            except Exception:
-                pass
-    else:
-        active = getattr(
-            jax.config, "jax_compilation_cache_dir", None
-        ) is not None
-    # Cache even tiny/fast compilations (the decode scan body is small on
-    # CPU CI but the retrace guarantee must still be exercised there).
-    for flag, value in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(flag, value)
-        except Exception:
-            pass
-    return active
+    cache_dir = jax.config.jax_compilation_cache_dir
+    if cache_dir is None:
+        cache_dir = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return cache_dir
 
 
 def donating_jit(

@@ -97,6 +97,18 @@ TPU_V5P_MEGACORE = Topology(
     link_bw=100e9,
 )
 
+#: What plans resolved on a CPU host (interpret-mode tests, dry-runs) are
+#: priced against. It carries the v5p megacore figures so that those plans
+#: exercise the two-domain swizzle arithmetic; it describes no device.
+CPU_REHEARSAL = dataclasses.replace(TPU_V5P_MEGACORE, name="cpu_rehearsal")
+
+#: Topology of each TPU ``jax.Device.device_kind`` the planner knows. A
+#: TPU whose kind is missing here is an error, never a guess.
+TPU_BY_DEVICE_KIND = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5": TPU_V5P_MEGACORE,
+}
+
 
 @dataclasses.dataclass(frozen=True)
 class MeshTopology:

@@ -9,10 +9,10 @@ dimension rides the MXU rows. Grid order is head-first by construction
 decode; there is no block-first analogue because a single token has one row
 block.
 
-Sequence lengths are dynamic (per-request): ``lengths`` rides in SMEM and
-gates both the masking and the chunk relevance test
-(``decode_common.chunk_relevant``), so compute scales with the actual
-prefix length, not the cache capacity.
+Sequence lengths are dynamic (per-request): ``lengths`` rides in SMEM via
+scalar prefetch (as in the paged kernels) and gates both the masking and
+the chunk relevance test (``decode_common.chunk_relevant``), so compute
+scales with the actual prefix length, not the cache capacity.
 
 Split-K (PR 4): with ``num_splits > 1`` a third PARALLEL grid axis
 partitions the chunk walk into ``num_splits`` contiguous ranges
@@ -50,10 +50,14 @@ def split_chunk_index_map(cps, num_chunks):
     kernel's range test skips its compute. Module-level so the access
     tracer replays the exact function handed to ``pallas_call``."""
 
-    def kv_index(b_, h_, s_, j_):
+    def kv_index(b_, h_, s_, j_, *prefetch):
         return (b_, h_, jnp.minimum(s_ * cps + j_, num_chunks - 1), 0)
 
     return kv_index
+
+
+def _split_out_index_map(b_, h_, s_, j_, ln):
+    return (b_, h_, s_, 0, 0)
 
 
 def _decode_kernel(
@@ -61,7 +65,7 @@ def _decode_kernel(
     *, scale, softcap, window, chunk, num_chunks, group_padded,
 ):
     n_idx = pl.program_id(2)
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
 
     @pl.when(n_idx == 0)
     def _init():
@@ -97,7 +101,7 @@ def _decode_split_kernel(
     skipped by the relevance test and emit the empty state."""
     s_idx = pl.program_id(2)
     j_idx = pl.program_id(3)
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
 
     @pl.when(j_idx == 0)
     def _init():
@@ -160,13 +164,13 @@ def flash_decode(
     qg = q.reshape(b, hkv, group, d)
     if gp != group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
-    lengths2d = lengths.reshape(b, 1).astype(jnp.int32)
+    lengths = lengths.reshape(b).astype(jnp.int32)
 
     ranges = layout_lib.decode_split_ranges(num_chunks, num_splits)
     num_splits = len(ranges)
     if num_splits > 1:
         return _flash_decode_split(
-            qg, k_cache, v_cache, lengths2d, ranges,
+            qg, k_cache, v_cache, lengths, ranges,
             scale=scale, softcap=softcap, window=window, chunk=chunk,
             num_chunks=num_chunks, gp=gp, group=group, interpret=interpret,
             out_dtype=q.dtype,
@@ -178,20 +182,24 @@ def flash_decode(
             scale=scale, softcap=softcap, window=window,
             chunk=chunk, num_chunks=num_chunks, group_padded=gp,
         ),
-        grid=(b, hkv, num_chunks),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b_, h_, n_: (b_, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, gp, d), lambda b_, h_, n_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, chunk, d), lambda b_, h_, n_: (b_, h_, n_, 0)),
-            pl.BlockSpec((1, 1, chunk, d), lambda b_, h_, n_: (b_, h_, n_, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, gp, d), lambda b_, h_, n_: (b_, h_, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv, num_chunks),
+            in_specs=[
+                pl.BlockSpec((1, 1, gp, d), lambda b_, h_, n_, ln: (b_, h_, 0, 0)),
+                pl.BlockSpec((1, 1, chunk, d), lambda b_, h_, n_, ln: (b_, h_, n_, 0)),
+                pl.BlockSpec((1, 1, chunk, d), lambda b_, h_, n_, ln: (b_, h_, n_, 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, gp, d), lambda b_, h_, n_, ln: (b_, h_, 0, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((gp, d), jnp.float32),
+                pltpu.VMEM((gp, 128), jnp.float32),
+                pltpu.VMEM((gp, 128), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((gp, d), jnp.float32),
-            pltpu.VMEM((gp, 128), jnp.float32),
-            pltpu.VMEM((gp, 128), jnp.float32),
-        ],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=(
                 compat.PARALLEL,
@@ -209,12 +217,12 @@ def flash_decode(
         interpret=interpret,
         name="flash_decode",
     )
-    out = fn(lengths2d, qg, k_cache, v_cache)
+    out = fn(lengths, qg, k_cache, v_cache)
     return out[:, :, :group, :].reshape(b, hq, d)
 
 
 def _flash_decode_split(
-    qg, k_cache, v_cache, lengths2d, ranges,
+    qg, k_cache, v_cache, lengths, ranges,
     *, scale, softcap, window, chunk, num_chunks, gp, group, interpret,
     out_dtype,
 ):
@@ -230,36 +238,31 @@ def _flash_decode_split(
             scale=scale, softcap=softcap, window=window,
             chunk=chunk, num_chunks=num_chunks, chunks_per_split=cps,
         ),
-        grid=(b, hkv, num_splits, cps),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1), lambda b_, h_, s_, j_: (b_, 0),
-                memory_space=pltpu.SMEM,
-            ),
-            pl.BlockSpec((1, 1, gp, d), lambda b_, h_, s_, j_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, chunk, d), kv_index),
-            pl.BlockSpec((1, 1, chunk, d), kv_index),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (1, 1, 1, gp, d), lambda b_, h_, s_, j_: (b_, h_, s_, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, 1, gp, 128), lambda b_, h_, s_, j_: (b_, h_, s_, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, 1, gp, 128), lambda b_, h_, s_, j_: (b_, h_, s_, 0, 0)
-            ),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv, num_splits, cps),
+            in_specs=[
+                pl.BlockSpec(
+                    (1, 1, gp, d), lambda b_, h_, s_, j_, ln: (b_, h_, 0, 0)
+                ),
+                pl.BlockSpec((1, 1, chunk, d), kv_index),
+                pl.BlockSpec((1, 1, chunk, d), kv_index),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, 1, gp, d), _split_out_index_map),
+                pl.BlockSpec((1, 1, 1, gp, 128), _split_out_index_map),
+                pl.BlockSpec((1, 1, 1, gp, 128), _split_out_index_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((gp, d), jnp.float32),
+                pltpu.VMEM((gp, 128), jnp.float32),
+                pltpu.VMEM((gp, 128), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, num_splits, gp, d), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, num_splits, gp, 128), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, num_splits, gp, 128), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((gp, d), jnp.float32),
-            pltpu.VMEM((gp, 128), jnp.float32),
-            pltpu.VMEM((gp, 128), jnp.float32),
         ],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=(
@@ -280,6 +283,6 @@ def _flash_decode_split(
         interpret=interpret,
         name="flash_decode_split",
     )
-    acc, m, l = fn(lengths2d, qg, k_cache, v_cache)
+    acc, m, l = fn(lengths, qg, k_cache, v_cache)
     out = decode_common.combine_split_states(acc, m[..., :1], l[..., :1])
     return out[:, :, :group, :].reshape(b, hkv * group, d).astype(out_dtype)

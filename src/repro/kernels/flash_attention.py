@@ -19,7 +19,8 @@ Two structural axes, mirroring paper §3.2/3.3:
                        q-block (and every q-head of a GQA group). K/V is
                        fetched from HBM ONCE per ACC — the TPU analogue of
                        the paper's 97 % L2 hit rate. Requires
-                       2*S*D*dtype <= vmem budget.
+                       4*S*D*dtype (K and V, double-buffered) <= the
+                       VMEM budget.
   kv_resident=False    K/V streamed in (block_n, D) tiles (classic FA2);
                        under head_first the Q block is still revisited
                        across the KV sweep.
@@ -75,10 +76,15 @@ class MappingConfig:
     acc_parallel: bool = True
     block_m: int = 128
     block_n: int = 128
-    # VMEM budget for the resident K/V copy (per core); beyond this the
-    # wrapper falls back to streaming. ~half of v5e VMEM, leaving room for
-    # double-buffered Q/O and accumulators.
-    vmem_budget_bytes: int = 64 * 1024 * 1024
+    # VMEM the resident K/V may occupy, counting both pipeline buffers of
+    # K and of V; beyond this the wrapper streams. The plan derives it and
+    # the limit below from the chip's VMEM (``plan.vmem_limits``); these
+    # defaults are the v5e figures (128 MiB VMEM per core).
+    vmem_budget_bytes: int = 48 * 1024 * 1024
+    # Scoped-VMEM limit handed to the compiler for the forward and
+    # backward kernels: the resident budget plus room for the Q/O blocks
+    # and the in-kernel f32 tiles.
+    vmem_limit_bytes: int = 96 * 1024 * 1024
     # KV-sweep traversal for the *streaming* path (sawtooth wavefront,
     # ROADMAP 5(a)): "linear" walks tiles 0..num_n-1 every sweep;
     # "sawtooth" serpentines so consecutive sweeps share their boundary
@@ -89,7 +95,8 @@ class MappingConfig:
     def resolve_resident(self, skv: int, head_dim: int, dtype_bytes: int) -> bool:
         if not self.kv_resident:
             return False
-        return 2 * skv * head_dim * dtype_bytes <= self.vmem_budget_bytes
+        # K and V, each double-buffered by the Pallas pipeline.
+        return 4 * skv * head_dim * dtype_bytes <= self.vmem_budget_bytes
 
 
 PAPER_MAPPINGS = {
@@ -208,8 +215,8 @@ def _fwd_stream_kernel(
         l = l_ref[:, 0:1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-        lse = m_ref[:, 0] + jnp.log(jnp.where(l[:, 0] == 0.0, 1.0, l[:, 0]))
-        lse_ref[0, 0] = jnp.where(l[:, 0] == 0.0, NEG_INF, lse)
+        lse = m_ref[:, 0:1] + jnp.log(l_safe)
+        lse_ref[0, 0] = jnp.where(l == 0.0, NEG_INF, lse)
 
 
 # -----------------------------------------------------------------------------
@@ -277,8 +284,8 @@ def _fwd_resident_kernel(
     m_fin, l_fin, acc = jax.lax.fori_loop(n_lo, n_hi, body, init)
     l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
     o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
-    lse = m_fin[:, 0] + jnp.log(l_safe[:, 0])
-    lse_ref[0, 0] = jnp.where(l_fin[:, 0] == 0.0, NEG_INF, lse)
+    lse = m_fin + jnp.log(l_safe)
+    lse_ref[0, 0] = jnp.where(l_fin == 0.0, NEG_INF, lse)
 
 
 # -----------------------------------------------------------------------------
@@ -337,9 +344,12 @@ def flash_attention_fwd(
         def gidx(b_, m_, h_):
             return b_, h_, m_
 
+    # lse carries a trailing unit dim: a (bm, 1) block is tileable on TPU
+    # (the last dim equals the array's), where a (bm,) row over (B, Hq, S)
+    # is not.
     out_shape = [
         jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
+        jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
     ]
 
     if resident:
@@ -368,19 +378,21 @@ def flash_attention_fwd(
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, bm, d), q_idx),
-                pl.BlockSpec((1, 1, bm), lambda *g: gidx(*g)),
+                pl.BlockSpec((1, 1, bm, 1), q_idx),
             ],
             out_shape=out_shape,
             compiler_params=compat.tpu_compiler_params(
                 dimension_semantics=_dim_semantics(
                     mapping.order, mapping.acc_parallel, len(grid)
                 ),
+                vmem_limit_bytes=mapping.vmem_limit_bytes,
             ),
             cost_estimate=_fwd_cost(b, hq, sq, skv, d, causal, q.dtype.itemsize),
             interpret=interpret,
             name=f"fa2_fwd_resident_{mapping.order}",
         )
-        return tuple(fn(q, k, v))
+        o, lse = fn(q, k, v)
+        return o, lse[..., 0]
 
     # streaming
     grid = (
@@ -414,7 +426,7 @@ def flash_attention_fwd(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bm, d), q_idx),
-            pl.BlockSpec((1, 1, bm), lambda *g: gidx(*g[:3])),
+            pl.BlockSpec((1, 1, bm, 1), q_idx),
         ],
         out_shape=out_shape,
         scratch_shapes=[
@@ -426,12 +438,14 @@ def flash_attention_fwd(
             dimension_semantics=_dim_semantics(
                 mapping.order, mapping.acc_parallel, len(grid)
             ),
+            vmem_limit_bytes=mapping.vmem_limit_bytes,
         ),
         cost_estimate=_fwd_cost(b, hq, sq, skv, d, causal, q.dtype.itemsize),
         interpret=interpret,
         name=f"fa2_fwd_stream_{mapping.order}",
     )
-    return tuple(fn(q, k, v))
+    o, lse = fn(q, k, v)
+    return o, lse[..., 0]
 
 
 # -----------------------------------------------------------------------------

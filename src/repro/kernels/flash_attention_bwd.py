@@ -87,8 +87,8 @@ def _dq_kernel(
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
+        lse = lse_ref[0, 0]
+        delta = delta_ref[0, 0]
         rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_m, 1), 0)
         cols = kv_start + jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
         p, s_capped, _ = _recompute_p(
@@ -136,8 +136,8 @@ def _dkv_kernel(
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
+        lse = lse_ref[0, 0]
+        delta = delta_ref[0, 0]
         rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_m, 1), 0)
         cols = kv_start + jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
         p, s_capped, _ = _recompute_p(
@@ -202,7 +202,12 @@ def flash_attention_bwd(
     num_m, num_n = sq // bm, skv // bn
 
     # delta = rowsum(dO * O): tiny elementwise reduction, done in XLA.
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    # Row statistics ride as (B, Hq, S, 1) so their (bm, 1) blocks tile on
+    # TPU (see the forward's lse output).
+    delta = jnp.sum(
+        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
+    )
+    lse = lse[..., None]
 
     # ---- dQ ----
     if mapping.order == HEAD_FIRST:
@@ -222,9 +227,6 @@ def flash_attention_bwd(
         b_, h_, m_ = gidx3(*g[:3])
         return (b_, h_ // group, g[3], 0)
 
-    def row_idx(*g):
-        return gidx3(*g[:3])
-
     dq_fn = pl.pallas_call(
         functools.partial(
             _dq_kernel,
@@ -237,8 +239,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, bn, d), kv_idx),
             pl.BlockSpec((1, 1, bn, d), kv_idx),
             pl.BlockSpec((1, 1, bm, d), q_idx),
-            pl.BlockSpec((1, 1, bm), row_idx),
-            pl.BlockSpec((1, 1, bm), row_idx),
+            pl.BlockSpec((1, 1, bm, 1), q_idx),
+            pl.BlockSpec((1, 1, bm, 1), q_idx),
         ],
         out_specs=pl.BlockSpec((1, 1, bm, d), q_idx),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
@@ -247,6 +249,7 @@ def flash_attention_bwd(
             dimension_semantics=_dim_semantics(
                 mapping.order, mapping.acc_parallel, len(dq_grid)
             ),
+            vmem_limit_bytes=mapping.vmem_limit_bytes,
         ),
         cost_estimate=_bwd_cost(b, hq, sq, skv, d, causal, q.dtype.itemsize),
         interpret=interpret,
@@ -272,10 +275,6 @@ def flash_attention_bwd(
         b_, hkv_, n_ = gidx_kv(*g[:3])
         return (b_, hkv_ * group + g[3], g[4], 0)
 
-    def row_idx2(*g):
-        b_, hkv_, n_ = gidx_kv(*g[:3])
-        return (b_, hkv_ * group + g[3], g[4])
-
     dkv_fn = pl.pallas_call(
         functools.partial(
             _dkv_kernel,
@@ -289,8 +288,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, bn, d), kv_idx2),
             pl.BlockSpec((1, 1, bn, d), kv_idx2),
             pl.BlockSpec((1, 1, bm, d), q_idx2),
-            pl.BlockSpec((1, 1, bm), row_idx2),
-            pl.BlockSpec((1, 1, bm), row_idx2),
+            pl.BlockSpec((1, 1, bm, 1), q_idx2),
+            pl.BlockSpec((1, 1, bm, 1), q_idx2),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bn, d), kv_idx2),
@@ -308,6 +307,7 @@ def flash_attention_bwd(
             dimension_semantics=_dim_semantics(
                 mapping.order, mapping.acc_parallel, len(dkv_grid)
             ),
+            vmem_limit_bytes=mapping.vmem_limit_bytes,
         ),
         cost_estimate=_bwd_cost(b, hq, sq, skv, d, causal, q.dtype.itemsize),
         interpret=interpret,

@@ -24,6 +24,10 @@ and KV layout (dense / paged). The legacy entry points below —
 resolver, kept for benchmarks and tests that only want the mapping or the
 layout ranking.
 
+On a serving mesh (:func:`kernel_mesh`) every Pallas kernel runs under
+``shard_map``, once per device on that device's slice of heads: Mosaic
+kernels cannot be partitioned automatically.
+
 The paged serving pair: ``paged_decode_attention`` dispatches the
 page-table flash-decode kernel, and ``paged_prefill_attention`` dispatches
 the prefix-aware paged prefill kernel — prefix K/V read straight from the
@@ -33,11 +37,14 @@ page table, no gather and no XLA ``q_offset`` fallback (which survives on
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import plan as plan_lib
 from repro.kernels import ref as ref_mod
@@ -54,6 +61,58 @@ from repro.kernels.plan import AttentionPlan, plan_attention  # noqa: F401
 
 
 # -----------------------------------------------------------------------------
+# Head-parallel kernels on a serving mesh
+# -----------------------------------------------------------------------------
+
+_KERNEL_MESH: contextvars.ContextVar = contextvars.ContextVar(
+    "kernel_mesh", default=None
+)
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh):
+    """Inside the block, Pallas kernels traced by this module run once per
+    device of the 1-D ``mesh``, each on its device's contiguous slice of
+    query and KV heads, and their outputs are gathered back to replicated.
+    The serving backends trace their jitted steps under it; a mesh of one
+    device (or None) changes nothing."""
+    if mesh is not None and mesh.devices.size > 1 and len(mesh.axis_names) != 1:
+        raise ValueError(f"kernel_mesh needs a 1-D mesh, got {mesh.axis_names}")
+    active = mesh if mesh is not None and mesh.devices.size > 1 else None
+    token = _KERNEL_MESH.set(active)
+    try:
+        yield
+    finally:
+        _KERNEL_MESH.reset(token)
+
+
+def _head_parallel(kernel, args: Sequence, head_axes: Sequence[Optional[int]],
+                   out_head_axis: int):
+    """``kernel(*args)``, or under :func:`kernel_mesh` that call per device
+    with ``args[i]`` split along ``head_axes[i]`` (None = replicated) and
+    the output re-gathered along ``out_head_axis``. Every head's attention
+    is computed whole on one device, so the result equals the one-device
+    call."""
+    mesh = _KERNEL_MESH.get()
+    if mesh is None:
+        return kernel(*args)
+    (axis,) = mesh.axis_names
+
+    def spec(head_axis):
+        return P() if head_axis is None else P(*([None] * head_axis), axis)
+
+    def body(*xs):
+        return jax.lax.all_gather(
+            kernel(*xs), axis, axis=out_head_axis, tiled=True
+        )
+
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=tuple(spec(a) for a in head_axes),
+        out_specs=P(), check_vma=False,
+    )(*args)
+
+
+# -----------------------------------------------------------------------------
 # Legacy resolvers: thin wrappers over the plan layer
 # -----------------------------------------------------------------------------
 
@@ -63,7 +122,7 @@ def resolve_mapping(
     backend: Optional[str] = None,
     *,
     dtype_bytes: int = 2,
-    vmem_budget_bytes: int = MappingConfig.vmem_budget_bytes,
+    vmem_budget_bytes: Optional[int] = None,
     decode: bool = False,
     window: Optional[int] = None,
 ) -> MappingConfig:
@@ -339,8 +398,12 @@ def flash_attention(
     qp = _pad_to(q, 2, bm)
     kp = _pad_to(k, 2, bn)
     vp = _pad_to(v, 2, bn)
-    o = _pallas_attention(
-        qp, kp, vp, causal, window, softcap, scale, mapping, plan.interpret
+    o = _head_parallel(
+        lambda q_, k_, v_: _pallas_attention(
+            q_, k_, v_, causal, window, softcap, scale, mapping,
+            plan.interpret,
+        ),
+        (qp, kp, vp), (1, 1, 1), 1,
     )
     return o[:, :, :sq]
 
@@ -382,11 +445,14 @@ def decode_attention(
     if smax % chunk:
         k_cache = _pad_to(k_cache, 2, chunk)
         v_cache = _pad_to(v_cache, 2, chunk)
-    return flash_decode(
-        q, k_cache, v_cache, lengths,
-        softcap=softcap, scale=scale, window=window, chunk=chunk,
-        num_splits=plan.num_splits,
-        interpret=plan.interpret,
+    return _head_parallel(
+        lambda q_, k_, v_, ln: flash_decode(
+            q_, k_, v_, ln,
+            softcap=softcap, scale=scale, window=window, chunk=chunk,
+            num_splits=plan.num_splits,
+            interpret=plan.interpret,
+        ),
+        (q, k_cache, v_cache, lengths), (1, 1, 1, None), 1,
     )
 
 
@@ -430,13 +496,22 @@ def paged_decode_attention(
         )
     if plan.impl != "pallas":
         raise ValueError(f"unknown impl {plan.impl!r}")
-    return paged_flash_decode(
-        q, k_pages, v_pages, page_table, lengths,
-        softcap=softcap, scale=scale, window=window,
-        k_scales=k_scales, v_scales=v_scales,
-        num_splits=plan.num_splits,
-        interpret=plan.interpret,
-    )
+    args = [q, k_pages, v_pages, page_table, lengths]
+    head_axes = [1, 0, 0, None, None]
+    if k_scales is not None:
+        args += [k_scales, v_scales]
+        head_axes += [0, 0]
+
+    def kernel(q_, kp, vp, pt, ln, ks=None, vs=None):
+        return paged_flash_decode(
+            q_, kp, vp, pt, ln,
+            softcap=softcap, scale=scale, window=window,
+            k_scales=ks, v_scales=vs,
+            num_splits=plan.num_splits,
+            interpret=plan.interpret,
+        )
+
+    return _head_parallel(kernel, args, head_axes, 1)
 
 
 def paged_prefill_attention(
@@ -490,13 +565,22 @@ def paged_prefill_attention(
         )
     if plan.impl != "pallas":
         raise ValueError(f"unknown impl {plan.impl!r}")
-    return paged_flash_prefill(
-        q, k_pages, v_pages, page_table, k_tail, v_tail,
-        prefix_len, tail_len,
-        softcap=softcap, scale=scale, window=window,
-        k_scales=k_scales, v_scales=v_scales,
-        interpret=plan.interpret,
-    )
+    args = [q, k_pages, v_pages, page_table, k_tail, v_tail, prefix_len,
+            tail_len]
+    head_axes = [1, 0, 0, None, 1, 1, None, None]
+    if k_scales is not None:
+        args += [k_scales, v_scales]
+        head_axes += [0, 0]
+
+    def kernel(q_, kp, vp, pt, kt, vt, pl_, tl, ks=None, vs=None):
+        return paged_flash_prefill(
+            q_, kp, vp, pt, kt, vt, pl_, tl,
+            softcap=softcap, scale=scale, window=window,
+            k_scales=ks, v_scales=vs,
+            interpret=plan.interpret,
+        )
+
+    return _head_parallel(kernel, args, head_axes, 1)
 
 
 # -----------------------------------------------------------------------------

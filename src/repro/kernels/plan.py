@@ -56,9 +56,10 @@ Phases
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Iterator, List, Optional, Tuple
 
 from repro import compat
 from repro.kernels.flash_attention import (
@@ -148,15 +149,42 @@ _PAPER_NAME = {
 }
 
 
-def _topology_for(backend: str):
+def tpu_device_kind() -> str:
+    """``device_kind`` of the attached TPU (raises when none is attached)."""
+    import jax
+
+    return jax.devices("tpu")[0].device_kind
+
+
+def topology_for(backend: str):
+    """The topology plans for ``backend`` are priced against: the attached
+    chip's for TPU (keyed on its ``device_kind``; an unknown kind raises),
+    the named CPU rehearsal topology for CPU hosts."""
     from repro.core import numa
 
     if backend == "gpu":
         return numa.MI300X
-    # TPU and CPU alike schedule for the megacore TPU target: CPU hosts run
-    # the kernels in interpret mode, and using the same topology guarantees
-    # dry-runs pick the same mapping the real hardware would.
-    return numa.TPU_V5P_MEGACORE
+    if backend == "tpu":
+        kind = tpu_device_kind()
+        if kind not in numa.TPU_BY_DEVICE_KIND:
+            raise ValueError(
+                f"no topology for TPU device kind {kind!r}; add it to "
+                "core.numa.TPU_BY_DEVICE_KIND"
+            )
+        return numa.TPU_BY_DEVICE_KIND[kind]
+    return numa.CPU_REHEARSAL
+
+
+def vmem_limits(backend: str) -> Tuple[int, int]:
+    """``(vmem_budget_bytes, vmem_limit_bytes)`` for the flash kernels. On
+    a TPU they come from its VMEM: the compiler limit is three quarters of
+    the core's VMEM, and resident K/V (both pipeline buffers) may take half
+    of it. Other backends run the kernels interpreted (or only model them)
+    and take the ``MappingConfig`` defaults, the v5e figures."""
+    if backend != "tpu":
+        return MappingConfig.vmem_budget_bytes, MappingConfig.vmem_limit_bytes
+    limit = topology_for(backend).cache_bytes * 3 // 4
+    return limit // 2, limit
 
 
 @functools.lru_cache(maxsize=1024)
@@ -170,6 +198,7 @@ def _score_mapping(
     dtype_bytes: int,
     backend: str,
     vmem_budget_bytes: int,
+    vmem_limit_bytes: int,
     decode: bool,
     window: Optional[int],
 ) -> MappingConfig:
@@ -178,7 +207,7 @@ def _score_mapping(
     from repro.core.cache_sim import AttentionWorkload
     from repro.core.swizzle import AttentionGrid
 
-    topo = _topology_for(backend)
+    topo = topology_for(backend)
     group = max(1, num_q_heads // max(num_kv_heads, 1))
     # A sliding window bounds the KV each row actually touches: score (and
     # choose blocks for) the live span, rounded up to a whole tile, not the
@@ -219,6 +248,7 @@ def _score_mapping(
                         block_m=bm_eff,
                         block_n=bn_eff,
                         vmem_budget_bytes=vmem_budget_bytes,
+                        vmem_limit_bytes=vmem_limit_bytes,
                         traversal=traversal,
                     )
                     if kv_resident and not cand.resolve_resident(
@@ -286,7 +316,7 @@ def _score_kv_layout(
 ) -> Tuple[str, float, float]:
     from repro.core import perf_model
 
-    topo = _topology_for(backend)
+    topo = topology_for(backend)
     dense = perf_model.estimate_dense_decode(
         batch=batch, num_q_heads=num_q_heads, num_kv_heads=num_kv_heads,
         capacity=capacity, head_dim=head_dim, dtype_bytes=dtype_bytes,
@@ -362,7 +392,7 @@ def _score_extend_route(
     the kernel (no gather is the better default at equal cost)."""
     from repro.core import perf_model
 
-    topo = _topology_for(backend)
+    topo = topology_for(backend)
     kw = dict(
         batch=batch, num_q_heads=num_q_heads, num_kv_heads=num_kv_heads,
         prefix_len=prefix_len, tail_len=tail_len, page_size=page_size,
@@ -457,6 +487,7 @@ def _plan_cached(
     mapping_name: str,
     impl: str,
     vmem_budget_bytes: int,
+    vmem_limit_bytes: int,
     num_devices: int,
     device_link_bw: Optional[float],
     kv_dtype: str,
@@ -471,7 +502,7 @@ def _plan_cached(
     else:
         mapping = _score_mapping(
             batch, num_q_heads, num_kv_heads, seq_q, seq_kv, head_dim,
-            dtype_bytes, backend, vmem_budget_bytes,
+            dtype_bytes, backend, vmem_budget_bytes, vmem_limit_bytes,
             phase == DECODE, window,
         )
 
@@ -517,14 +548,14 @@ def _plan_cached(
             mesh = None
             if num_devices > 1:
                 mesh = numa.mesh_topology(
-                    num_devices, chip=_topology_for(backend),
+                    num_devices, chip=topology_for(backend),
                     device_link_bw=device_link_bw,
                 )
             split = perf_model.estimate_decode_splits(
                 batch=batch, num_q_heads=num_q_heads,
                 num_kv_heads=num_kv_heads, seq_kv=seq_kv, granule=granule,
                 head_dim=head_dim, dtype_bytes=dtype_bytes,
-                topo=_topology_for(backend), window=window, mesh=mesh,
+                topo=topology_for(backend), window=window, mesh=mesh,
             )
             num_splits = split.num_splits
             split_device_pure = split.device_pure
@@ -561,7 +592,7 @@ def plan_attention(
     prefix_pages: int = 0,
     mapping_name: str = "auto",
     impl: str = "auto",
-    vmem_budget_bytes: int = MappingConfig.vmem_budget_bytes,
+    vmem_budget_bytes: Optional[int] = None,
     num_devices: int = 1,
     device_link_bw: Optional[float] = None,
     kv_dtype: str = "fp32",
@@ -589,6 +620,9 @@ def plan_attention(
     (domain, device) via ``numa.mesh_topology`` — ``device_link_bw``
     overrides the inter-device fabric figure (``None`` = the chip preset's
     link) — and records the verdict in ``plan.split_device_pure``.
+
+    ``vmem_budget_bytes=None`` takes the resident-K/V budget from the
+    chip's VMEM (:func:`vmem_limits`); the compiler limit always does.
     """
     if phase not in PHASES:
         raise ValueError(f"unknown phase {phase!r}; expected one of {PHASES}")
@@ -612,7 +646,10 @@ def plan_attention(
     backend = backend or compat.default_backend()
     if interpret is None:
         interpret = compat.use_interpret(backend)
-    return _plan_cached(
+    budget, limit = vmem_limits(backend)
+    if vmem_budget_bytes is not None:
+        budget = vmem_budget_bytes
+    plan = _plan_cached(
         b, hq, hkv, sq, skv, d,
         phase, kv_layout, backend, bool(interpret),
         int(dtype_bytes),
@@ -620,11 +657,32 @@ def plan_attention(
         int(page_size) if page_size else None,
         int(prefix_pages),
         mapping_name, impl,
-        int(vmem_budget_bytes),
+        int(budget),
+        int(limit),
         int(num_devices),
         float(device_link_bw) if device_link_bw is not None else None,
         kv_dtype,
     )
+    for plans in _RECORDERS:
+        plans.append(plan)
+    return plan
+
+
+#: Open :func:`record_plans` blocks, innermost last.
+_RECORDERS: List[List[AttentionPlan]] = []
+
+
+@contextlib.contextmanager
+def record_plans() -> Iterator[List[AttentionPlan]]:
+    """Collect every plan :func:`plan_attention` returns inside the
+    block, cache hits included — which schedules and impls a traced
+    program actually ran with (plans resolve at trace time)."""
+    plans: List[AttentionPlan] = []
+    _RECORDERS.append(plans)
+    try:
+        yield plans
+    finally:
+        _RECORDERS.remove(plans)
 
 
 @functools.lru_cache(maxsize=256)

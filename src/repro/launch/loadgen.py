@@ -48,6 +48,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro import compat
 from repro.configs import registry
 from repro.models import transformer
 from repro.obs import Telemetry
@@ -401,12 +402,12 @@ def run_sharded_sweep(args) -> Dict:
         raise ValueError("--mesh-sweep needs a comma-separated list of "
                          "device counts, e.g. 1,2,4")
     avail = len(jax.devices())
-    runnable = [d for d in counts if d <= avail]
-    if runnable != counts:
-        print(f"[loadgen] skipping device counts beyond the "
-              f"{avail} available: {sorted(set(counts) - set(runnable))}")
-    if not runnable:
-        raise RuntimeError(f"no runnable device counts (have {avail})")
+    if max(counts) > avail:
+        raise RuntimeError(
+            f"--mesh-sweep asks for {max(counts)} devices but the host has "
+            f"{avail}: {sorted(c for c in counts if c > avail)} cannot run"
+        )
+    runnable = counts
 
     get_cfg = (registry.get_smoke_config if args.smoke
                else registry.get_config)
@@ -539,6 +540,7 @@ def main(argv=None):
                     help="artifact directory (default "
                          "artifacts/benchmarks)")
     args = ap.parse_args(argv)
+    compat.enable_compilation_cache()
 
     if args.mesh_sweep:
         run_sharded_sweep(args)

@@ -26,6 +26,7 @@ import math
 import jax
 import numpy as np
 
+from repro import compat
 from repro.configs import registry
 from repro.models import transformer
 from repro.serving import LLMEngine, Request, SamplingParams
@@ -68,6 +69,7 @@ def main(argv=None):
                          "cold pages demote host-side under pool "
                          "pressure and promote back on prefix match")
     args = ap.parse_args(argv)
+    compat.enable_compilation_cache()
 
     cfg = (registry.get_smoke_config if args.smoke else registry.get_config)(args.arch)
     if args.mesh > 1 and args.smoke and cfg.n_kv_heads % args.mesh:

@@ -32,10 +32,10 @@ def init_attention(key, cfg: ModelConfig) -> dict:
     s = 1.0 / math.sqrt(d)
     so = 1.0 / math.sqrt(h * hd)
     p = {
-        "wq_dm": jax.random.normal(ks[0], (d, h * hd), layers.default_dtype()) * s,
-        "wk_dm": jax.random.normal(ks[1], (d, hkv * hd), layers.default_dtype()) * s,
-        "wv_dm": jax.random.normal(ks[2], (d, hkv * hd), layers.default_dtype()) * s,
-        "wo_md": jax.random.normal(ks[3], (h * hd, d), layers.default_dtype()) * so,
+        "wq_dm": layers.normal_init(ks[0], (d, h * hd), s),
+        "wk_dm": layers.normal_init(ks[1], (d, hkv * hd), s),
+        "wv_dm": layers.normal_init(ks[2], (d, hkv * hd), s),
+        "wo_md": layers.normal_init(ks[3], (h * hd, d), so),
     }
     if cfg.qk_norm:
         p["q_norm"] = layers.init_rmsnorm(hd)

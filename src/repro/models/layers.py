@@ -24,6 +24,14 @@ def default_dtype():
     return jnp.float32  # params kept in f32; compute dtype set per-model
 
 
+def normal_init(key, shape, scale) -> jnp.ndarray:
+    """N(0, scale**2) params. The barrier keeps a jitted init from folding
+    ``scale`` into the normal sampler's own constant, so jitted and
+    op-by-op inits agree bitwise."""
+    n = jax.random.normal(key, shape, default_dtype())
+    return jax.lax.optimization_barrier(n) * scale
+
+
 # -----------------------------------------------------------------------------
 # Norms
 # -----------------------------------------------------------------------------
@@ -77,9 +85,9 @@ def init_mlp(key, d_model: int, d_ff: int) -> dict:
     s_in = 1.0 / math.sqrt(d_model)
     s_out = 1.0 / math.sqrt(d_ff)
     return {
-        "wi_gate_dm": jax.random.normal(k1, (d_model, d_ff), default_dtype()) * s_in,
-        "wi_up_dm": jax.random.normal(k2, (d_model, d_ff), default_dtype()) * s_in,
-        "wo_md": jax.random.normal(k3, (d_ff, d_model), default_dtype()) * s_out,
+        "wi_gate_dm": normal_init(k1, (d_model, d_ff), s_in),
+        "wi_up_dm": normal_init(k2, (d_model, d_ff), s_in),
+        "wo_md": normal_init(k3, (d_ff, d_model), s_out),
     }
 
 
@@ -110,7 +118,7 @@ def init_embedding(key, vocab: int, d_model: int) -> dict:
     # 1/sqrt(d) keeps tied-unembedding logits O(1) at init.
     scale = d_model**-0.5
     return {
-        "table_vd": jax.random.normal(key, (vocab, d_model), default_dtype()) * scale
+        "table_vd": normal_init(key, (vocab, d_model), scale)
     }
 
 
@@ -133,7 +141,7 @@ def unembed(params: dict, x: jnp.ndarray, *, softcap: Optional[float] = None
 
 def init_linear(key, d_in: int, d_out: int, tag: str = "dm") -> dict:
     s = 1.0 / math.sqrt(d_in)
-    return {f"w_{tag}": jax.random.normal(key, (d_in, d_out), default_dtype()) * s}
+    return {f"w_{tag}": normal_init(key, (d_in, d_out), s)}
 
 
 def linear(params: dict, x: jnp.ndarray, tag: str = "dm") -> jnp.ndarray:

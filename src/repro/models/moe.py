@@ -33,11 +33,11 @@ def init_moe(key, d_model: int, cfg: MoEConfig) -> dict:
     s_out = 1.0 / math.sqrt(cfg.d_ff)
     e = cfg.num_experts
     p = {
-        "router_de": jax.random.normal(ks[0], (d_model, e), layers.default_dtype()) * s_in,
+        "router_de": layers.normal_init(ks[0], (d_model, e), s_in),
         # Expert weights: leading expert dim is the EP shard axis.
-        "wi_gate_edm": jax.random.normal(ks[1], (e, d_model, cfg.d_ff), layers.default_dtype()) * s_in,
-        "wi_up_edm": jax.random.normal(ks[2], (e, d_model, cfg.d_ff), layers.default_dtype()) * s_in,
-        "wo_emd": jax.random.normal(ks[3], (e, cfg.d_ff, d_model), layers.default_dtype()) * s_out,
+        "wi_gate_edm": layers.normal_init(ks[1], (e, d_model, cfg.d_ff), s_in),
+        "wi_up_edm": layers.normal_init(ks[2], (e, d_model, cfg.d_ff), s_in),
+        "wo_emd": layers.normal_init(ks[3], (e, cfg.d_ff, d_model), s_out),
     }
     if cfg.num_shared_experts:
         p["shared"] = layers.init_mlp(

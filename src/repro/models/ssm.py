@@ -46,17 +46,18 @@ def init_mamba(key, d_model: int, cfg: SSMConfig) -> dict:
     dt_init = jnp.log(jnp.expm1(jnp.linspace(1e-3, 0.1, h)))  # softplus^-1
     return {
         # order: [z(d_in), x(d_in), B(g*n), C(g*n), dt(h)]
-        "win_dm": jax.random.normal(
-            ks[0], (d_model, 2 * d_in + 2 * g * n + h), layers.default_dtype()
-        ) * s,
-        "conv_w": jax.random.normal(ks[1], (w, conv_ch), layers.default_dtype()) * 0.1,
+        "win_dm": layers.normal_init(
+            ks[0], (d_model, 2 * d_in + 2 * g * n + h), s
+        ),
+        "conv_w": layers.normal_init(ks[1], (w, conv_ch), 0.1),
         "conv_b_r": jnp.zeros((conv_ch,), layers.default_dtype()),
         "a_log_r": jnp.log(jnp.linspace(1.0, 16.0, h)).astype(layers.default_dtype()),
         "d_skip_r": jnp.ones((h,), layers.default_dtype()),
         "dt_bias_r": dt_init.astype(layers.default_dtype()),
         "norm": layers.init_rmsnorm(d_in),
-        "wout_md": jax.random.normal(ks[2], (d_in, d_model), layers.default_dtype())
-        * (1.0 / math.sqrt(d_in)),
+        "wout_md": layers.normal_init(
+            ks[2], (d_in, d_model), 1.0 / math.sqrt(d_in)
+        ),
     }
 
 

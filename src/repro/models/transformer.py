@@ -225,30 +225,41 @@ def _mamba_with_cache(params, h, cfg: ModelConfig):
 
 
 def init_model(key, cfg: ModelConfig) -> Params:
+    """Random params for ``cfg`` from ``key``. Jitted with ``cfg``
+    static, so a full-width init holds only its outputs and one tensor's
+    temporaries on the device (bitwise equal to
+    :func:`init_model_eager`)."""
+    return _init_model_jit(key, cfg)
+
+
+def init_model_eager(key, cfg: ModelConfig) -> Params:
+    """The op-by-op body of :func:`init_model`."""
     pattern, rem = cfg.pattern_for_depth()
     n_periods = cfg.n_periods
     ks = jax.random.split(key, 4 + len(rem))
     params: Params = {}
     if cfg.num_codebooks > 1:
         params["embed"] = {
-            "table_kvd": jax.random.normal(
+            "table_kvd": layers.normal_init(
                 ks[0], (cfg.num_codebooks, cfg.vocab, cfg.d_model),
-                layers.default_dtype(),
-            ) * cfg.d_model**-0.5
+                cfg.d_model**-0.5,
+            )
         }
     else:
         params["embed"] = layers.init_embedding(ks[0], cfg.vocab, cfg.d_model)
     if cfg.vision_tokens:
         params["vision_proj"] = layers.init_linear(ks[1], cfg.vision_dim, cfg.d_model)
 
-    # Scanned period stacks: one stacked tree per pattern position.
+    # Scanned period stacks: one stacked tree per pattern position, built
+    # by vmapping the layer init over the periods' keys (bitwise what a
+    # per-period loop and a stack would give, without the per-period list).
     stacks = []
     for j, spec in enumerate(pattern):
-        per_period = [
-            init_layer(jax.random.fold_in(ks[2], p * len(pattern) + j), cfg, spec)
+        keys = jnp.stack([
+            jax.random.fold_in(ks[2], p * len(pattern) + j)
             for p in range(n_periods)
-        ]
-        stacks.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per_period))
+        ])
+        stacks.append(jax.vmap(lambda k, spec=spec: init_layer(k, cfg, spec))(keys))
     params["layers"] = tuple(stacks)
     params["layers_rem"] = tuple(
         init_layer(ks[4 + i], cfg, spec) for i, spec in enumerate(rem)
@@ -257,6 +268,9 @@ def init_model(key, cfg: ModelConfig) -> Params:
     if not cfg.tie_embeddings:
         params["head"] = layers.init_linear(ks[3], cfg.d_model, cfg.vocab)
     return params
+
+
+_init_model_jit = jax.jit(init_model_eager, static_argnums=1)
 
 
 def _embed_tokens(params, cfg: ModelConfig, tokens):

@@ -39,6 +39,7 @@ query offsets.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -56,6 +57,7 @@ from repro.cache import quant
 from repro.cache.prefix import PrefixCache, page_hashes
 from repro.cache.tier import HostPageStore
 from repro.configs.base import ModelConfig
+from repro.kernels import ops as kernel_ops
 from repro.kernels import plan as plan_lib
 from repro.models import transformer
 from repro.serving import sampling as sampling_lib
@@ -101,6 +103,21 @@ class _Backend:
             is_leaf=lambda x: isinstance(x, PartitionSpec),
         )
         self.caches = jax.device_put(self.caches, self._cache_shardings)
+
+    def _on_mesh(self, fn):
+        """``fn`` traced under ``ops.kernel_mesh``: on a serving mesh its
+        Pallas kernels run per device on that device's heads (Mosaic
+        kernels cannot be partitioned automatically). Off-mesh, ``fn``."""
+        if self.num_devices == 1:
+            return fn
+        mesh = self.mesh
+
+        @functools.wraps(fn)
+        def traced_on_mesh(*args):
+            with kernel_ops.kernel_mesh(mesh):
+                return fn(*args)
+
+        return traced_on_mesh
 
     def _constrain(self, caches):
         """Inside-jit sharding pin for the cache tree (identity off-mesh)."""
@@ -304,7 +321,7 @@ class _Backend:
         # Donate the KV caches: the scan carry aliases its input buffers
         # in place of a copy (halves peak cache HBM on TPU/GPU; a silent
         # hint on CPU).
-        return compat.donating_jit(run, donate_argnums=(1,))
+        return compat.donating_jit(self._on_mesh(run), donate_argnums=(1,))
 
 
 # -----------------------------------------------------------------------------
@@ -349,11 +366,11 @@ class DenseBackend(_Backend):
         self._setup_mesh(mesh, specs)
         self.slot_req: List[Optional[object]] = [None] * rows
         constrain = self._constrain
-        self._decode = jax.jit(
+        self._decode = jax.jit(self._on_mesh(
             lambda params, tok, caches, lengths: transformer.decode_step(
                 params, cfg, tok, constrain(caches), lengths
             )
-        )
+        ))
         self._prefill = {}
 
     # -- capacity ----------------------------------------------------------
@@ -385,7 +402,7 @@ class DenseBackend(_Backend):
             num_kv_heads=self.cfg.n_kv_heads, capacity=self.cache_len,
             head_dim=self.cfg.head_dim,
             dtype_bytes=jnp.dtype(self.cfg.compute_dtype).itemsize,
-            topo=plan_lib._topology_for(compat.default_backend()),
+            topo=plan_lib.topology_for(compat.default_backend()),
         ).time
 
     @property
@@ -443,7 +460,7 @@ class DenseBackend(_Backend):
                     last_positions=last_positions,
                 )
 
-            self._prefill[bucket] = jax.jit(f)
+            self._prefill[bucket] = jax.jit(self._on_mesh(f))
         return self._prefill[bucket]
 
     def _write_slot_cache(self, slot: int, new_caches):
@@ -668,11 +685,11 @@ class PagedBackend(_Backend):
         self.seqs: List[Optional[_SeqState]] = [None] * rows
 
         constrain = self._constrain
-        self._decode = jax.jit(
+        self._decode = jax.jit(self._on_mesh(
             lambda params, tok, caches, lengths, pt: transformer.decode_step(
                 params, cfg, tok, constrain(caches), lengths, page_table=pt
             )
-        )
+        ))
         self._prefill_p: Dict = {}
         self._scatter_jit = jax.jit(
             lambda caches, tails, pids: constrain(
@@ -818,7 +835,7 @@ class PagedBackend(_Backend):
             page_size=self.page_size, head_dim=self.cfg.head_dim,
             dtype_bytes=self._kv_dtype_bytes,
         )
-        chip = plan_lib._topology_for(compat.default_backend())
+        chip = plan_lib.topology_for(compat.default_backend())
         if self.num_devices > 1:
             return perf_model.estimate_sharded_paged_decode(
                 mesh=numa.mesh_topology(self.num_devices, chip=chip), **kw
@@ -853,7 +870,7 @@ class PagedBackend(_Backend):
             return 0.0
         prefix = min(matched * self.page_size, n - 1)
         both = min((matched + host_run) * self.page_size, n - 1)
-        topo = plan_lib._topology_for(compat.default_backend())
+        topo = plan_lib.topology_for(compat.default_backend())
         dtype_bytes = self._kv_dtype_bytes
 
         def _t(prefix_len: int) -> float:
@@ -1077,7 +1094,7 @@ class PagedBackend(_Backend):
                         prefix_len=prefix_len, plan=plan,
                     )
 
-            self._prefill_p[key] = jax.jit(f)
+            self._prefill_p[key] = jax.jit(self._on_mesh(f))
         return self._prefill_p[key]
 
     # -- admission ---------------------------------------------------------

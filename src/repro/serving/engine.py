@@ -48,7 +48,6 @@ from typing import Callable, Dict, Iterable, List, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.cache.pool import OutOfPages
 from repro.configs.base import ModelConfig
 from repro.kernels import plan as plan_lib
@@ -120,7 +119,6 @@ class LLMEngine:
         scheduler: Optional[Scheduler] = None,
         telemetry: Optional[Telemetry] = None,
         steps_per_sync=1,
-        compilation_cache_dir: Optional[str] = None,
         mesh=None,
         shard_params: bool = False,
         device_hbm_bytes=None,
@@ -229,10 +227,6 @@ class LLMEngine:
         #: (scheduler, output flush, telemetry) intervenes every N tokens.
         self.steps_per_sync = int(steps_per_sync)
         self.backend.steps_per_sync = self.steps_per_sync
-        # Persistent compilation cache (best-effort): with the scan's jit
-        # keys O(1) per engine, a warm cache means steady-state serving
-        # never compiles at all — across processes, not just ticks.
-        compat.enable_compilation_cache(compilation_cache_dir)
 
         self._pending: Dict[int, np.ndarray] = {}   # row -> next token
         self._last_ticks = 0                        # live ticks, last scan

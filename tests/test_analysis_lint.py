@@ -211,6 +211,9 @@ def test_collectives_rule_allows_sanctioned_modules():
                        rules=["collectives-only-in-combine"]) == []
     assert lint_source(src, "src/repro/serving/sampling.py",
                        rules=["collectives-only-in-combine"]) == []
+    # the head-parallel kernel dispatch re-gathers per-device outputs
+    assert lint_source(src, "src/repro/kernels/ops.py",
+                       rules=["collectives-only-in-combine"]) == []
     # outside the scoped dirs (e.g. optim's gradient allreduce) the rule
     # does not apply
     assert lint_source(src, "src/repro/optim/grad_compress.py",

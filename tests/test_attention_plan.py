@@ -29,6 +29,13 @@ from repro.kernels.flash_attention import (
 SHAPE = (2, 8, 2, 2048, 2048, 64)
 
 
+@pytest.fixture
+def v5e_kind(monkeypatch):
+    """Price "tpu" plans as the v5e its device kind names, on a host
+    that has no TPU attached."""
+    monkeypatch.setattr(plan_lib, "tpu_device_kind", lambda: "TPU v5 lite")
+
+
 # --- resolution ---------------------------------------------------------------
 
 
@@ -63,12 +70,12 @@ def test_plan_phases_resolve_distinct_impls():
     assert pinned.impl == "xla"
 
 
-def test_plan_on_tpu_backend_targets_mosaic():
+def test_plan_on_tpu_backend_targets_mosaic(v5e_kind):
     p = plan_lib.plan_attention(SHAPE, backend="tpu")
     assert p.impl == "pallas" and not p.interpret
 
 
-def test_plan_cache_keys_on_backend_and_interpret():
+def test_plan_cache_keys_on_backend_and_interpret(v5e_kind):
     """Same shape, different backend / interpret flag -> distinct entries;
     identical key -> the same LRU object."""
     cpu = plan_lib.plan_attention(SHAPE, backend="cpu")
@@ -80,6 +87,36 @@ def test_plan_cache_keys_on_backend_and_interpret():
     again = plan_lib.plan_attention(SHAPE, backend="cpu")
     assert again is cpu
     hash(cpu)  # usable as a jit-closure constant / custom_vjp nondiff arg
+
+
+def test_topology_is_keyed_on_device_kind(monkeypatch):
+    """A TPU is priced as the chip its device kind names; an unknown kind
+    raises instead of borrowing another chip's figures; CPU hosts use the
+    named rehearsal topology."""
+    from repro.core import numa
+
+    monkeypatch.setattr(plan_lib, "tpu_device_kind", lambda: "TPU v5 lite")
+    assert plan_lib.topology_for("tpu") is numa.TPU_V5E
+    budget, limit = plan_lib.vmem_limits("tpu")
+    assert limit == numa.TPU_V5E.cache_bytes * 3 // 4 and budget == limit // 2
+    monkeypatch.setattr(plan_lib, "tpu_device_kind", lambda: "TPU v99")
+    with pytest.raises(ValueError, match="TPU v99"):
+        plan_lib.topology_for("tpu")
+    assert plan_lib.topology_for("cpu") is numa.CPU_REHEARSAL
+    assert numa.CPU_REHEARSAL.name == "cpu_rehearsal"
+
+
+def test_record_plans_sees_cache_hits():
+    """record_plans collects every plan resolved inside it, including
+    LRU hits, and nothing after the block closes."""
+    plan_lib.plan_attention(SHAPE, backend="cpu")  # warm the cache
+    with plan_lib.record_plans() as plans:
+        a = plan_lib.plan_attention(SHAPE, backend="cpu")
+        b = plan_lib.plan_attention(
+            (2, 8, 2, 1, 2048, 64), phase=plan_lib.DECODE, backend="cpu"
+        )
+    plan_lib.plan_attention(SHAPE, backend="cpu")
+    assert plans == [a, b]
 
 
 def test_plan_decode_chunk_prefers_capacity_divisor():

@@ -2,7 +2,8 @@
 
 Covers the PR's acceptance criteria directly:
   * ``resolve_mapping`` prefers kv-resident head-first exactly when
-    ``2*S*D*dtype`` fits the VMEM budget (``MappingConfig.resolve_resident``),
+    ``4*S*D*dtype`` (K and V, double-buffered) fits the VMEM budget
+    (``MappingConfig.resolve_resident``),
   * the HBM traffic model never reports reuse_efficiency > 1,
   * no versioned JAX API (CompilerParams / TPUCompilerParams / AxisType)
     is referenced outside ``src/repro/compat.py``.
@@ -25,14 +26,18 @@ from repro.kernels.flash_attention import (
 # --- resolve_mapping ---------------------------------------------------------
 
 
-@pytest.mark.parametrize("seq", [1024, 4096, 131072, 131072 + 128, 262144])
+@pytest.mark.parametrize(
+    "seq", [1024, 4096, 49152, 49152 + 128, 131072, 131072 + 128, 262144]
+)
 def test_resolver_residency_matches_vmem_budget(seq):
-    """kv_resident head-first is chosen exactly when 2*S*D*dtype fits VMEM."""
+    """kv_resident head-first is chosen exactly when 4*S*D*dtype fits VMEM."""
     d, dtype_bytes = 128, 2
     mc = ops.resolve_mapping((1, 16, 4, seq, seq, d), dtype_bytes=dtype_bytes)
     fits = MappingConfig().resolve_resident(seq, d, dtype_bytes)
-    # (budget boundary: 2*131072*128*2 == 64 MiB fits; one block more spills)
+    # (budget boundary: 4*49152*128*2 == 48 MiB fits; one block more spills)
+    assert fits == (seq <= 49152)
     assert mc.kv_resident == fits
+    assert mc.vmem_limit_bytes == MappingConfig().vmem_limit_bytes
     assert mc.order == HEAD_FIRST
     assert mc.acc_parallel
 
@@ -41,7 +46,7 @@ def test_resolver_respects_explicit_budget():
     seq, d = 8192, 128
     assert ops.resolve_mapping((1, 8, 8, seq, seq, d)).kv_resident
     tiny = ops.resolve_mapping(
-        (1, 8, 8, seq, seq, d), vmem_budget_bytes=seq * d  # << 2*S*D*2
+        (1, 8, 8, seq, seq, d), vmem_budget_bytes=seq * d  # << 4*S*D*2
     )
     assert not tiny.kv_resident
 
@@ -53,9 +58,13 @@ def test_resolver_is_cached_and_hashable():
     hash(a)  # usable as a custom_vjp nondiff arg
 
 
-def test_resolver_backends_agree_on_headline_result():
+def test_resolver_backends_agree_on_headline_result(monkeypatch):
     """Every modeled backend prefers the paper's swizzled head-first when
-    K/V fits; the paper's Fig. 12 headline is backend-independent."""
+    K/V fits; the paper's Fig. 12 headline is backend-independent. The TPU
+    is priced as the v5e its device kind names."""
+    from repro.kernels import plan as plan_lib
+
+    monkeypatch.setattr(plan_lib, "tpu_device_kind", lambda: "TPU v5 lite")
     for backend in ("cpu", "gpu", "tpu"):
         mc = ops.resolve_mapping((8, 32, 8, 8192, 8192, 128), backend)
         assert (mc.order, mc.kv_resident) == (HEAD_FIRST, True), backend
@@ -177,7 +186,17 @@ def test_compat_interpret_detection():
     assert compat.use_interpret("cpu")
     assert not compat.use_interpret("tpu")
     assert compat.use_interpret() == (not compat.on_tpu())
-    assert compat.JAX_VERSION >= (0, 4, 37)
+
+
+def test_installed_jax_is_the_pinned_one():
+    """One installation: the JAX the compat layer is written for is the
+    one ``requirements-dev.txt`` and ``pyproject.toml`` pin."""
+    import pathlib
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    pin = f"jax=={jax.__version__}"
+    assert pin in (root / "requirements-dev.txt").read_text()
+    assert f'"{pin}"' in (root / "pyproject.toml").read_text()
 
 
 def test_no_versioned_jax_api_outside_compat():
@@ -188,3 +207,28 @@ def test_no_versioned_jax_api_outside_compat():
     from repro.analysis import run_rules
 
     assert run_rules(rules=["compat-only-versioned-jax"]) == []
+
+
+def test_compilation_cache_dir_is_fixed_or_from_env():
+    """With no directory configured the cache lands at the fixed,
+    git-ignored path in the checkout; a configured one (what
+    JAX_COMPILATION_CACHE_DIR sets) is kept."""
+    import pathlib
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compat.enable_compilation_cache() == compat.DEFAULT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == compat.DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", "/elsewhere")
+        assert compat.enable_compilation_cache() == "/elsewhere"
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)
+    path = pathlib.Path(compat.DEFAULT_CACHE_DIR)
+    assert path.parent == root
+    assert f"{path.name}/" in (root / ".gitignore").read_text().split()

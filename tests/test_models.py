@@ -110,3 +110,16 @@ def test_param_counts_match_published():
     for arch, want in expected.items():
         got = registry.get_config(arch).param_count()
         assert abs(got - want) / want < 0.06, (arch, got)
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_jitted_init_matches_eager(arch):
+    """The jitted init_model is bitwise the op-by-op init, per smoke arch."""
+    cfg = registry.get_smoke_config(arch)
+    key = jax.random.PRNGKey(3)
+    jitted = transformer.init_model(key, cfg)
+    eager = transformer.init_model_eager(key, cfg)
+    assert jax.tree.structure(jitted) == jax.tree.structure(eager)
+    for a, b in zip(jax.tree.leaves(jitted), jax.tree.leaves(eager)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert bool(jnp.array_equal(a, b))
