@@ -3,20 +3,29 @@
 Two record streams, one tracer:
 
   * **Spans** — nested wall-clock intervals around the serving phases
-    (``step`` > ``schedule`` / ``flush`` / ``decode``), recorded by the
+    (``step`` > ``schedule`` / ``flush`` / ``decode``, with ``flush`` >
+    ``prefill`` / ``sample_first`` and ``decode`` > ``decode.launch`` /
+    ``decode.sync`` / ``decode.replay``), recorded by the
     ``LLMEngine.step`` instrumentation. Nesting is positional: a span
-    opened while another is live gets ``depth = parent.depth + 1``.
+    opened while another is live gets ``depth = parent.depth + 1`` and
+    ``parent = parent.sid``. While a span is open it also holds a
+    ``jax.profiler.TraceAnnotation`` of the same name, so a profiler
+    trace shows the engine's phases on the host beside the device's
+    programs; the records themselves stay on the tracer's clock.
   * **Request lifecycle events** — instants on a request's timeline
-    (``arrival -> admitted -> first_token -> ... -> finish``, with
-    ``preempt`` / ``resume`` in between and one ``tokens`` event per
-    streamed emission). These give *measured* TTFT and inter-token
-    latencies — the numbers ``SchedulerStats.modeled_tok_s`` only
-    predicts — via :meth:`Tracer.request_latencies`.
+    (``arrival -> admitted -> first_sampled -> first_token -> ... ->
+    finish``, with ``preempt`` / ``resume`` in between and one ``tokens``
+    event per streamed emission). These give *measured* TTFT and
+    inter-token latencies — the numbers ``SchedulerStats.modeled_tok_s``
+    only predicts — via :meth:`Tracer.request_latencies`.
+    ``first_sampled`` marks when the first token reached the host;
+    ``first_token`` when it was returned to the caller.
 
 Export is Chrome ``trace_event`` JSON (:meth:`Tracer.to_chrome_trace`),
 loadable in Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``:
-engine spans on one track, each request as an async (``b``/``e``) slice
-with its lifecycle instants riding on it.
+engine spans on one track (each naming its ``sid`` and ``parent``),
+each request as an async (``b``/``e``) slice with its lifecycle instants
+riding on it.
 
 :class:`NullTracer` is the disabled path: ``span()`` returns one shared
 no-op context manager and the event methods do nothing, so a disabled
@@ -39,6 +48,7 @@ ARRIVAL = "arrival"
 ADMITTED = "admitted"
 RESUME = "resume"
 PREEMPT = "preempt"
+FIRST_SAMPLED = "first_sampled"
 FIRST_TOKEN = "first_token"
 TOKENS = "tokens"
 FINISH = "finish"
@@ -46,13 +56,18 @@ FINISH = "finish"
 
 @dataclasses.dataclass
 class SpanRecord:
-    """One closed span: ``[t0, t1)`` seconds on the tracer's clock."""
+    """One closed span: ``[t0, t1)`` seconds on the tracer's clock.
+    ``sid`` numbers the tracer's spans in the order they opened;
+    ``parent`` is the ``sid`` of the span open around this one (None at
+    the top)."""
 
     name: str
     t0: float
     t1: float
     depth: int
     args: Dict
+    sid: int = 0
+    parent: Optional[int] = None
 
     @property
     def duration(self) -> float:
@@ -63,7 +78,8 @@ class _Span:
     """Context manager recording one span; created per ``span()`` call
     (only when tracing is enabled — the null path shares one no-op)."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_depth", "sid",
+                 "_parent", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict):
         self._tracer = tracer
@@ -71,17 +87,24 @@ class _Span:
         self.args = args
 
     def __enter__(self):
-        self._depth = len(self._tracer._stack)
-        self._tracer._stack.append(self)
-        self._t0 = self._tracer._clock()
+        tr = self._tracer
+        self._depth = len(tr._stack)
+        self._parent = tr._stack[-1].sid if tr._stack else None
+        self.sid = tr._next_sid
+        tr._next_sid += 1
+        tr._stack.append(self)
+        self._annotation = tr._profiler_annotation(self.name)
+        self._annotation.__enter__()
+        self._t0 = tr._clock()
         return self
 
     def __exit__(self, *exc):
-        t1 = self._tracer._clock()
-        self._tracer._stack.pop()
-        self._tracer.spans.append(
-            SpanRecord(self.name, self._t0, t1, self._depth, self.args)
-        )
+        tr = self._tracer
+        t1 = tr._clock()
+        self._annotation.__exit__(*exc)
+        tr._stack.pop()
+        tr.spans.append(SpanRecord(self.name, self._t0, t1, self._depth,
+                                   self.args, self.sid, self._parent))
         return False
 
 
@@ -93,21 +116,27 @@ class Tracer:
     def __init__(self, clock: Callable[[], float] = time.perf_counter):
         self._clock = clock
         self._stack: List[_Span] = []
+        self._next_sid = 0
+        self._trace_annotation = None
         self.spans: List[SpanRecord] = []
         #: uid -> [(event, t, args)] in record order.
         self.requests: Dict[int, List[Tuple[str, float, Dict]]] = {}
-        #: free-form instants outside any request ((name, t, args)).
-        self.instants: List[Tuple[str, float, Dict]] = []
         self.t_start = clock()
+
+    def _profiler_annotation(self, name: str):
+        """A ``jax.profiler.TraceAnnotation`` named ``name`` (JAX is
+        imported on the first span, not with the tracer)."""
+        if self._trace_annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            self._trace_annotation = TraceAnnotation
+        return self._trace_annotation(name)
 
     # -- recording ---------------------------------------------------------
 
     def span(self, name: str, **args) -> _Span:
         """Context manager timing one phase; nests positionally."""
         return _Span(self, name, args)
-
-    def instant(self, name: str, **args) -> None:
-        self.instants.append((name, self._clock(), args))
 
     def request_event(self, uid: int, event: str, **args) -> None:
         """Record one lifecycle instant for request ``uid``."""
@@ -120,7 +149,6 @@ class Tracer:
         warmup); open spans and the clock origin survive."""
         self.spans.clear()
         self.requests.clear()
-        self.instants.clear()
         self.t_start = self._clock()
 
     # -- derived latencies -------------------------------------------------
@@ -182,7 +210,9 @@ class Tracer:
     def to_chrome_trace(self) -> Dict:
         """Chrome ``trace_event`` JSON (the dict; ``json.dump`` it or use
         :meth:`write_chrome_trace`). Engine spans are complete (``X``)
-        events on tid 0; each request is an async ``b``/``e`` pair with
+        events on tid 0, their args extended by ``sid`` and ``parent``
+        (the span tree, so a span's self time is its duration less its
+        children's); each request is an async ``b``/``e`` pair with
         its lifecycle instants, on its own tid so Perfetto lays requests
         out as parallel tracks."""
         base = self.t_start
@@ -198,12 +228,8 @@ class Tracer:
             events.append({
                 "name": s.name, "cat": "engine", "ph": "X",
                 "ts": us(s.t0), "dur": round(s.duration * 1e6, 3),
-                "pid": 1, "tid": 0, "args": s.args,
-            })
-        for name, t, args in self.instants:
-            events.append({
-                "name": name, "cat": "engine", "ph": "i", "s": "p",
-                "ts": us(t), "pid": 1, "tid": 0, "args": args,
+                "pid": 1, "tid": 0,
+                "args": {**s.args, "sid": s.sid, "parent": s.parent},
             })
         for uid, evs in sorted(self.requests.items()):
             tid = uid + 1
@@ -263,9 +289,6 @@ class NullTracer(Tracer):
 
     def span(self, name: str, **args):
         return NULL_SPAN
-
-    def instant(self, name: str, **args) -> None:
-        pass
 
     def request_event(self, uid: int, event: str, **args) -> None:
         pass

@@ -60,6 +60,8 @@ from repro.configs.base import ModelConfig
 from repro.kernels import ops as kernel_ops
 from repro.kernels import plan as plan_lib
 from repro.models import transformer
+from repro.obs import NULL_TELEMETRY
+from repro.obs.tracing import NULL_SPAN
 from repro.serving import sampling as sampling_lib
 from repro.serving.scheduler import default_choose_victim
 
@@ -84,6 +86,9 @@ class _Backend:
     #: None = single-device. Set by :meth:`_setup_mesh`.
     mesh = None
     num_devices: int = 1
+    #: Span recorder for the backend's own phases (``prefill``); the
+    #: engine hands over its tracer.
+    tracer = NULL_TELEMETRY.tracer
 
     def _setup_mesh(self, mesh, specs) -> None:
         """Place the cache tree under ``specs`` on ``mesh`` and remember
@@ -259,8 +264,8 @@ class _Backend:
         stats = self.stats
         constrain = self._constrain
 
-        def run(params, caches, pt, tok, lengths, gen, done,
-                temps, top_k, top_p, seeds, stops, max_toks):
+        def decode_scan(params, caches, pt, tok, lengths, gen, done,
+                        temps, top_k, top_p, seeds, stops, max_toks):
             # Trace-time side effect: fires once per compilation, so a
             # flat counter after warmup proves zero steady-state retraces.
             stats["decode_traces"] += 1
@@ -321,7 +326,8 @@ class _Backend:
         # Donate the KV caches: the scan carry aliases its input buffers
         # in place of a copy (halves peak cache HBM on TPU/GPU; a silent
         # hint on CPU).
-        return compat.donating_jit(self._on_mesh(run), donate_argnums=(1,))
+        return compat.donating_jit(self._on_mesh(decode_scan),
+                                   donate_argnums=(1,))
 
 
 # -----------------------------------------------------------------------------
@@ -366,11 +372,12 @@ class DenseBackend(_Backend):
         self._setup_mesh(mesh, specs)
         self.slot_req: List[Optional[object]] = [None] * rows
         constrain = self._constrain
-        self._decode = jax.jit(self._on_mesh(
-            lambda params, tok, caches, lengths: transformer.decode_step(
-                params, cfg, tok, constrain(caches), lengths
-            )
-        ))
+
+        def decode_step(params, tok, caches, lengths):
+            return transformer.decode_step(
+                params, cfg, tok, constrain(caches), lengths)
+
+        self._decode = jax.jit(self._on_mesh(decode_step))
         self._prefill = {}
 
     # -- capacity ----------------------------------------------------------
@@ -454,13 +461,13 @@ class DenseBackend(_Backend):
         if bucket not in self._prefill:
             cfg = self.cfg
 
-            def f(params, tokens, last_positions):
+            def prefill(params, tokens, last_positions):
                 return transformer.prefill(
                     params, cfg, tokens, cache_len=self.cache_len,
                     last_positions=last_positions,
                 )
 
-            self._prefill[bucket] = jax.jit(self._on_mesh(f))
+            self._prefill[bucket] = jax.jit(self._on_mesh(prefill))
         return self._prefill[bucket]
 
     def _write_slot_cache(self, slot: int, new_caches):
@@ -685,27 +692,27 @@ class PagedBackend(_Backend):
         self.seqs: List[Optional[_SeqState]] = [None] * rows
 
         constrain = self._constrain
-        self._decode = jax.jit(self._on_mesh(
-            lambda params, tok, caches, lengths, pt: transformer.decode_step(
-                params, cfg, tok, constrain(caches), lengths, page_table=pt
-            )
-        ))
+
+        # Each program is named before it is jitted: the name is the
+        # module's in a profiler trace (``jit_<name>``).
+        def decode_step(params, tok, caches, lengths, pt):
+            return transformer.decode_step(
+                params, cfg, tok, constrain(caches), lengths, page_table=pt)
+
+        def scatter_tail(caches, tails, pids):
+            return constrain(self._scatter_tail(caches, tails, pids))
+
+        def copy_page(caches, src, dst):
+            return constrain(self._copy_page(caches, src, dst))
+
+        def restore_page(caches, payload, dst):
+            return constrain(self._restore_page(caches, payload, dst))
+
+        self._decode = jax.jit(self._on_mesh(decode_step))
         self._prefill_p: Dict = {}
-        self._scatter_jit = jax.jit(
-            lambda caches, tails, pids: constrain(
-                self._scatter_tail(caches, tails, pids)
-            )
-        )
-        self._copy_jit = jax.jit(
-            lambda caches, src, dst: constrain(
-                self._copy_page(caches, src, dst)
-            )
-        )
-        self._restore_jit = jax.jit(
-            lambda caches, payload, dst: constrain(
-                self._restore_page(caches, payload, dst)
-            )
-        )
+        self._scatter_jit = jax.jit(scatter_tail)
+        self._copy_jit = jax.jit(copy_page)
+        self._restore_jit = jax.jit(restore_page)
 
     # -- capacity ----------------------------------------------------------
 
@@ -1070,11 +1077,13 @@ class PagedBackend(_Backend):
             cfg = self.cfg
 
             if prefix_pages == 0:
-                def f(params, tokens, last_positions):
+                def prefill(params, tokens, last_positions):
                     return transformer.prefill(
                         params, cfg, tokens, cache_len=bucket,
                         last_positions=last_positions,
                     )
+
+                fn = prefill
             else:
                 plan = plan_lib.plan_for_config(
                     cfg,
@@ -1085,8 +1094,8 @@ class PagedBackend(_Backend):
                     kv_dtype=self.kv_dtype,
                 )
 
-                def f(params, tokens, last_positions, caches, page_table,
-                      prefix_len):
+                def extend(params, tokens, last_positions, caches,
+                           page_table, prefix_len):
                     return transformer.prefill(
                         params, cfg, tokens, cache_len=bucket,
                         last_positions=last_positions,
@@ -1094,7 +1103,8 @@ class PagedBackend(_Backend):
                         prefix_len=prefix_len, plan=plan,
                     )
 
-            self._prefill_p[key] = jax.jit(self._on_mesh(f))
+                fn = extend
+            self._prefill_p[key] = jax.jit(self._on_mesh(fn))
         return self._prefill_p[key]
 
     # -- admission ---------------------------------------------------------
@@ -1369,62 +1379,78 @@ class PagedBackend(_Backend):
             for i, rec in enumerate(records):
                 groups[(rec["bucket"], rec["mb"], i)] = [rec]
         for (bucket, mb, *_), grp in groups.items():
-            rows = len(grp)
-            padded = np.stack(
-                [np.pad(r["tail"], (0, bucket - len(r["tail"]))) for r in grp]
-            )
-            last = jnp.asarray(
-                [len(r["tail"]) - 1 for r in grp], jnp.int32
-            )
-            self.stats["prefill_launches"] += 1
-            self.stats["batched_prefills"] += rows > 1
-            if mb == 0:
-                logits, tail_caches = self._prefill_paged_fn(bucket, 0, rows)(
-                    self.params, jnp.asarray(padded), last
+            with self._prefill_span(bucket, mb, grp):
+                rows = len(grp)
+                padded = np.stack([
+                    np.pad(r["tail"], (0, bucket - len(r["tail"])))
+                    for r in grp
+                ])
+                last = jnp.asarray(
+                    [len(r["tail"]) - 1 for r in grp], jnp.int32
                 )
-            else:
-                # Extend phase: each page-table row is padded to the
-                # power-of-two page bucket with null pages (the kernel
-                # masks them via the dynamic prefix_len), so every prefix
-                # length in a bucket shares one compilation — and the pool
-                # is consumed in place, no gather.
-                pt = np.full((rows, mb), NULL_PAGE, np.int32)
-                for i, r in enumerate(grp):
-                    pt[i, : len(r["matched"])] = r["matched"]
-                plens = jnp.asarray(
-                    [len(r["matched"]) * ps for r in grp], jnp.int32
-                )
-                self.stats["extend_prefills"] += rows
-                logits, tail_caches = self._prefill_paged_fn(bucket, mb, rows)(
-                    self.params, jnp.asarray(padded), last, self.caches,
-                    jnp.asarray(pt), plens,
-                )
-            # Scatter every row's tail K/V into its fresh pages (buckets
-            # are page-aligned; destinations beyond a tail's real pages
-            # sink into the null page).
-            pids = np.full((rows, bucket // ps), NULL_PAGE, np.int32)
-            for i, r in enumerate(grp):
-                tail_pages = r["seq"].pages[len(r["matched"]):]
-                pids[i, : len(tail_pages)] = tail_pages
-            self.caches = self._scatter_jit(
-                self.caches, tail_caches, jnp.asarray(pids)
-            )
-            logits_np = np.asarray(logits)
-            for i, r in enumerate(grp):
-                # Publish this prompt's full pages for later requests.
-                if self.prefix_sharing:
-                    nfull = r["n"] // ps
-                    if self.host is not None:
-                        # A freshly prefilled page supersedes any host
-                        # copy under the same hash (the content is hash-
-                        # determined): drop it so residency stays
-                        # exclusive — device OR host, never both.
-                        for h in r["hashes"][:nfull]:
-                            self.host.discard(h)
-                    self.prefix.insert(
-                        r["hashes"][:nfull], r["seq"].pages[:nfull]
+                self.stats["prefill_launches"] += 1
+                self.stats["batched_prefills"] += rows > 1
+                fn = self._prefill_paged_fn(bucket, mb, rows)
+                if mb == 0:
+                    logits, tail_caches = fn(
+                        self.params, jnp.asarray(padded), last
                     )
-                first_logits[r["row"]] = logits_np[i]
+                else:
+                    # Extend phase: each page-table row is padded to the
+                    # power-of-two page bucket with null pages (the kernel
+                    # masks them via the dynamic prefix_len), so every
+                    # prefix length in a bucket shares one compilation —
+                    # and the pool is consumed in place, no gather.
+                    pt = np.full((rows, mb), NULL_PAGE, np.int32)
+                    for i, r in enumerate(grp):
+                        pt[i, : len(r["matched"])] = r["matched"]
+                    plens = jnp.asarray(
+                        [len(r["matched"]) * ps for r in grp], jnp.int32
+                    )
+                    self.stats["extend_prefills"] += rows
+                    logits, tail_caches = fn(
+                        self.params, jnp.asarray(padded), last, self.caches,
+                        jnp.asarray(pt), plens,
+                    )
+                # Scatter every row's tail K/V into its fresh pages
+                # (buckets are page-aligned; destinations beyond a tail's
+                # real pages sink into the null page).
+                pids = np.full((rows, bucket // ps), NULL_PAGE, np.int32)
+                for i, r in enumerate(grp):
+                    tail_pages = r["seq"].pages[len(r["matched"]):]
+                    pids[i, : len(tail_pages)] = tail_pages
+                self.caches = self._scatter_jit(
+                    self.caches, tail_caches, jnp.asarray(pids)
+                )
+                logits_np = np.asarray(logits)
+                for i, r in enumerate(grp):
+                    # Publish this prompt's full pages for later requests.
+                    if self.prefix_sharing:
+                        nfull = r["n"] // ps
+                        if self.host is not None:
+                            # A freshly prefilled page supersedes any
+                            # host copy under the same hash (the content
+                            # is hash-determined): drop it so residency
+                            # stays exclusive — device OR host, never both.
+                            for h in r["hashes"][:nfull]:
+                                self.host.discard(h)
+                        self.prefix.insert(
+                            r["hashes"][:nfull], r["seq"].pages[:nfull]
+                        )
+                    first_logits[r["row"]] = logits_np[i]
+
+    def _prefill_span(self, bucket: int, mb: int, grp):
+        """``prefill`` span of one launch: its jit key and each row's
+        tokens (computed only when the tracer records)."""
+        if not self.tracer.enabled:
+            return NULL_SPAN
+        ps = self.page_size
+        return self.tracer.span(
+            "prefill", bucket=bucket, prefix_pages=mb, rows=len(grp),
+            tail_tokens=[len(r["tail"]) for r in grp],
+            prefix_tokens=[len(r["matched"]) * ps for r in grp],
+            uids=[r["req"].uid for r in grp],
+        )
 
     # -- preemption / decode ----------------------------------------------
 

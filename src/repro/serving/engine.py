@@ -24,13 +24,15 @@ picking a class. The facade collapses that:
     NUMA-occupancy admission cap from ``core.perf_model``); the execution
     backends (``serving.backends``) are pure cache mechanism;
   * observability is injected (``telemetry=repro.obs.Telemetry.create()``):
-    ``step()`` runs under spans (schedule / flush / decode), requests get
-    lifecycle events (arrival -> admitted -> first_token -> finish, with
-    preempt/resume), each decode tick's wall time feeds the
-    model-vs-measured drift collector, and all instruments are pre-bound
-    at construction (``obs-no-hot-loop-allocs`` lint rule). The default
-    is ``repro.obs.NULL_TELEMETRY`` — shared no-op instruments, no
-    span/metric objects allocated per step.
+    ``step()`` runs under spans (schedule / flush / decode, with the
+    prefill launches, first-token sample and the scan's launch / sync /
+    replay as their children, each also a profiler annotation), requests
+    get lifecycle events (arrival -> admitted -> first_sampled ->
+    first_token -> finish, with preempt/resume), each decode tick's wall
+    time feeds the model-vs-measured drift collector, and all instruments
+    are pre-bound at construction (``obs-no-hot-loop-allocs`` lint rule).
+    The default is ``repro.obs.NULL_TELEMETRY`` — shared no-op
+    instruments, no span/metric objects allocated per step.
 
 ``ServingEngine`` / ``PagedServingEngine`` survive as deprecated shims
 over the facade; nothing outside ``repro.serving`` may construct them
@@ -230,6 +232,7 @@ class LLMEngine:
 
         self._pending: Dict[int, np.ndarray] = {}   # row -> next token
         self._last_ticks = 0                        # live ticks, last scan
+        self._last_kv_lens: List[List[int]] = []    # per live tick (traced)
         self._streamed: Dict[int, int] = {}         # uid -> tokens emitted
         #: uid -> buffered outputs for live stream() consumers. Only uids
         #: with an open stream() generator have an entry; everything else
@@ -253,6 +256,7 @@ class LLMEngine:
         # step.
         self.telemetry = telemetry or NULL_TELEMETRY
         self._tr = self.telemetry.tracer
+        self.backend.tracer = self._tr
         self._drift = self.telemetry.drift
         m = self.telemetry.metrics
         self._m_requests = m.counter(
@@ -352,9 +356,13 @@ class LLMEngine:
         this sync.
 
         Instrumented (when telemetry is on) as one ``step`` span holding
-        ``schedule`` / ``flush`` / ``decode`` child spans; the scan's wall
-        time is folded into the drift collector under its live (batch,
-        mean-context) cell as one sample per live scan tick."""
+        ``schedule`` / ``flush`` / ``decode`` child spans. ``flush`` holds
+        one ``prefill`` per launch and ``sample_first``; ``decode`` holds
+        ``decode.launch`` / ``decode.sync`` / ``decode.replay`` and, once
+        the scan is on the host, its live ``ticks`` and each live tick's
+        KV lengths (``kv_lens``). The scan's wall time is folded into the
+        drift collector under its live (batch, mean-context) cell as one
+        sample per live scan tick."""
         if self._auto_steps:
             # Re-pick N from the live batch depth BEFORE admission: the
             # scheduler's page-budget check prices decode headroom off
@@ -383,10 +391,13 @@ class LLMEngine:
                     self._m_admitted.inc()
                     # A row whose output list is pre-seeded was admitted
                     # with replay tokens: that is a preemption resume.
-                    resumed = bool(self.backend.out[rec["row"]])
-                    self._tr.request_event(
-                        uid, "resume" if resumed else "admitted",
-                        row=rec["row"])
+                    if self.backend.out[rec["row"]]:
+                        self._tr.request_event(uid, "resume", row=rec["row"])
+                    else:
+                        self._tr.request_event(
+                            uid, "admitted", row=rec["row"],
+                            prompt_tokens=int(rec["n"]),
+                            reused_pages=len(rec.get("matched", ())))
                 if records:
                     tf = time.perf_counter()
                     with self._tr.span("flush", rows=len(records)):
@@ -395,11 +406,16 @@ class LLMEngine:
             outputs: List[RequestOutput] = []
             if self.backend.active.any():
                 nb = self.backend.num_active
-                live = self.backend.lengths[self.backend.active]
-                mean_len = float(live.mean()) if live.size else 0.0
+                mean_len = 0.0
+                if self._drift.enabled:
+                    live = self.backend.lengths[self.backend.active]
+                    mean_len = float(live.mean()) if live.size else 0.0
                 td = time.perf_counter()
-                with self._tr.span("decode", batch=nb, steps=n_steps):
+                with self._tr.span("decode", batch=nb, steps=n_steps) as sp:
                     outputs = self._decode_tick(n_steps)
+                    if self._tr.enabled:
+                        sp.args["ticks"] = self._last_ticks
+                        sp.args["kv_lens"] = self._last_kv_lens
                 dt = time.perf_counter() - td
                 self._h_decode.observe(dt)
                 self._decode_elapsed += dt
@@ -634,11 +650,17 @@ class LLMEngine:
         rows = sorted(first)
         if not rows:
             return
-        logits = np.stack([first[r] for r in rows])
-        params = self._sampling_arrays(len(rows), list(enumerate(rows)))
-        toks = np.asarray(sampling_lib.sample_tokens(logits, *params))
+        with self._tr.span("sample_first"):
+            logits = np.stack([first[r] for r in rows])
+            params = self._sampling_arrays(len(rows), list(enumerate(rows)))
+            toks = np.asarray(sampling_lib.sample_tokens(logits, *params))
         for i, r in enumerate(rows):
             self._pending[r] = toks[i]
+            # A resumed row's output list holds its replayed tokens; its
+            # first token was sampled before the preemption.
+            if not self.backend.out[r]:
+                self._tr.request_event(self.backend.row_req(r).uid,
+                                       "first_sampled")
 
     def _stop_array(self, rows) -> np.ndarray:
         """Per-row stop-token ids, padded with -1 to a power-of-two width
@@ -668,31 +690,33 @@ class LLMEngine:
         decode ticks on device, and hand the results to the sanctioned
         once-per-sync host sync point (:meth:`_sync_scan`)."""
         b = self.backend
-        # May preempt rows under page pressure; a preempted row drops out
-        # of the scan entirely (its done mask starts True).
-        b.reserve_rows(n_steps)
-        rows = [r for r in range(b.rows) if b.active[r]]
-        if not rows:
-            self._last_ticks = 0
-            return []
-        shape = (b.rows,) if self.cfg.num_codebooks == 1 else (
-            b.rows, self.cfg.num_codebooks)
-        tok = np.zeros(shape, np.int32)
-        for row in rows:
-            if row in self._pending:
-                nxt = self._pending.pop(row)
-            else:
-                nxt = b.out[row][-1]
-            tok[row] = nxt
-        temps, top_k, top_p, seeds, pos = self._sampling_arrays(
-            b.rows, [(r, r) for r in rows])
-        max_toks = np.zeros((b.rows,), np.int32)
-        for r in rows:
-            max_toks[r] = b.row_req(r).sampling.max_tokens
-        ys, lengths_f = b.fused_decode(
-            tok, pos, self._stop_array(rows), max_toks,
-            temps, top_k, top_p, seeds, n_steps,
-        )
+        with self._tr.span("decode.launch"):
+            # May preempt rows under page pressure; a preempted row drops
+            # out of the scan entirely (its done mask starts True).
+            b.reserve_rows(n_steps)
+            rows = [r for r in range(b.rows) if b.active[r]]
+            if not rows:
+                self._last_ticks = 0
+                self._last_kv_lens = []
+                return []
+            shape = (b.rows,) if self.cfg.num_codebooks == 1 else (
+                b.rows, self.cfg.num_codebooks)
+            tok = np.zeros(shape, np.int32)
+            for row in rows:
+                if row in self._pending:
+                    nxt = self._pending.pop(row)
+                else:
+                    nxt = b.out[row][-1]
+                tok[row] = nxt
+            temps, top_k, top_p, seeds, pos = self._sampling_arrays(
+                b.rows, [(r, r) for r in rows])
+            max_toks = np.zeros((b.rows,), np.int32)
+            for r in rows:
+                max_toks[r] = b.row_req(r).sampling.max_tokens
+            ys, lengths_f = b.fused_decode(
+                tok, pos, self._stop_array(rows), max_toks,
+                temps, top_k, top_p, seeds, n_steps,
+            )
         return self._sync_scan(ys, lengths_f)
 
     def _sync_scan(self, ys, lengths_f) -> List[RequestOutput]:
@@ -701,49 +725,59 @@ class LLMEngine:
         terminate finished rows, and emit the streamed increments — one
         :class:`RequestOutput` per row per sync, however many ticks ran."""
         b = self.backend
-        tok_seq, nxt_seq, live, appended, fed_stop, hit_max = (
-            np.asarray(y) for y in ys)
-        self._last_ticks = int(live.any(axis=1).sum())
-        b.commit_scan(np.asarray(lengths_f))
-        outputs: List[RequestOutput] = []
-        for r in range(b.rows):
-            col = live[:, r]
-            if not col.any():
-                continue
-            req = b.row_req(r)
-            reason = None
-            last_t = 0
-            for t in range(col.shape[0]):
-                if not col[t]:
-                    break
-                last_t = t
-                b.out[r].append(tok_seq[t, r].copy())
-                self._tokens_generated += 1
-                # Mask priority mirrors the single-step rules: a fed stop
-                # token outranks the length cap; a freshly *sampled* stop
-                # token is appended (no K/V write) and terminates.
-                if fed_stop[t, r]:
-                    reason = FINISH_STOP
-                    break
-                if hit_max[t, r]:
-                    reason = FINISH_LENGTH
-                    break
-                if appended[t, r]:
-                    b.out[r].append(nxt_seq[t, r].copy())
+        with self._tr.span("decode.sync"):
+            tok_seq, nxt_seq, live, appended, fed_stop, hit_max = (
+                np.asarray(y) for y in ys)
+            lengths_f = np.asarray(lengths_f)
+        with self._tr.span("decode.replay"):
+            self._last_ticks = int(live.any(axis=1).sum())
+            if self._tr.enabled:
+                # Live ticks come first; a row live at tick t attends over
+                # its pre-scan length plus the t + 1 tokens fed so far.
+                self._last_kv_lens = [
+                    (b.lengths[live[t]] + t + 1).tolist()
+                    for t in range(self._last_ticks)
+                ]
+            b.commit_scan(lengths_f)
+            outputs: List[RequestOutput] = []
+            for r in range(b.rows):
+                col = live[:, r]
+                if not col.any():
+                    continue
+                req = b.row_req(r)
+                reason = None
+                last_t = 0
+                for t in range(col.shape[0]):
+                    if not col[t]:
+                        break
+                    last_t = t
+                    b.out[r].append(tok_seq[t, r].copy())
                     self._tokens_generated += 1
-                    reason = FINISH_STOP
-                    break
-            if reason is not None:
-                outputs.append(self._finish(r, req, reason))
-            else:
-                self._pending[r] = nxt_seq[last_t, r]
-                delta = self._delta(req.uid, b.out[r])
-                if delta:
-                    outputs.append(RequestOutput(
-                        uid=req.uid, prompt_len=len(req.prompt),
-                        new_tokens=delta, tokens=list(b.out[r]),
-                    ))
-        return outputs
+                    # Mask priority mirrors the single-step rules: a fed stop
+                    # token outranks the length cap; a freshly *sampled* stop
+                    # token is appended (no K/V write) and terminates.
+                    if fed_stop[t, r]:
+                        reason = FINISH_STOP
+                        break
+                    if hit_max[t, r]:
+                        reason = FINISH_LENGTH
+                        break
+                    if appended[t, r]:
+                        b.out[r].append(nxt_seq[t, r].copy())
+                        self._tokens_generated += 1
+                        reason = FINISH_STOP
+                        break
+                if reason is not None:
+                    outputs.append(self._finish(r, req, reason))
+                else:
+                    self._pending[r] = nxt_seq[last_t, r]
+                    delta = self._delta(req.uid, b.out[r])
+                    if delta:
+                        outputs.append(RequestOutput(
+                            uid=req.uid, prompt_len=len(req.prompt),
+                            new_tokens=delta, tokens=list(b.out[r]),
+                        ))
+            return outputs
 
     def _delta(self, uid: int, out: List) -> List:
         """Tokens not yet streamed for ``uid`` (replayed resume tokens

@@ -224,6 +224,11 @@ def test_spans_nest_positionally():
         assert by_name["step"].t0 <= by_name[child].t0
         assert by_name[child].t1 <= by_name["step"].t1
     assert by_name["decode"].duration == pytest.approx(2.0)
+    # Each span names the one open around it.
+    assert by_name["step"].parent is None
+    assert by_name["schedule"].parent == by_name["step"].sid
+    assert by_name["decode"].parent == by_name["step"].sid
+    assert len({s.sid for s in tr.spans}) == 3
 
 
 def test_request_lifecycle_monotone_and_latencies():
@@ -279,6 +284,10 @@ def test_chrome_trace_round_trips():
     assert {"M", "X", "i", "b", "e"} <= phases
     xs = [e for e in evs if e["ph"] == "X"]
     assert {e["name"] for e in xs} == {"step", "decode"}
+    by_name = {e["name"]: e for e in xs}
+    assert by_name["decode"]["args"]["batch"] == 2
+    assert by_name["step"]["args"]["parent"] is None
+    assert by_name["decode"]["args"]["parent"] == by_name["step"]["args"]["sid"]
     for e in evs:
         if e["ph"] in ("X", "i", "b", "e"):
             assert e["ts"] >= 0  # all timestamps rebased to trace start
@@ -307,7 +316,7 @@ def test_tracer_reset_drops_records():
         clk.tick()
     tr.request_event(0, "arrival")
     tr.reset()
-    assert tr.spans == [] and tr.requests == {} and tr.instants == []
+    assert tr.spans == [] and tr.requests == {}
     clk.tick()
     with tr.span("measured"):
         clk.tick()
@@ -321,7 +330,7 @@ def test_null_tracer_shares_span():
     with tr.span("a"):
         pass
     tr.request_event(1, "arrival")
-    tr.instant("x")
+    tr.request_event(1, "first_sampled")
     assert tr.spans == [] and tr.requests == {}
 
 

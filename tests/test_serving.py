@@ -625,6 +625,7 @@ def test_disabled_telemetry_shares_null_instruments(llama):
     assert eng._m_steps is NULL_COUNTER
     assert eng._h_decode is NULL_HISTOGRAM
     assert eng._tr.span("step") is eng._tr.span("decode") is NULL_SPAN
+    assert eng.backend.tracer is NULL_TELEMETRY.tracer
     rng = np.random.default_rng(0)
     eng.generate([Request(uid=0, prompt=rng.integers(1, 400, size=(8,)),
                           max_new_tokens=3)])
