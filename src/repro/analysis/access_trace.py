@@ -8,17 +8,17 @@ whatever their **BlockSpec index maps** say — a refactor that changes a
 story while every numeric test still passes (attention output does not
 depend on where a page lives).
 
-This module closes that gap: it replays the *exact* index-map functions
-the kernels export (``paged_kv_index_map`` / ``split_kv_index_map`` /
-``prefix_page_index_map`` / ``split_chunk_index_map`` — module-level in
-the kernel files precisely so tracer and ``pallas_call`` cannot diverge)
-over a concrete page table, records which physical page every grid cell
-DMAs, and asserts:
+This module closes that gap: it replays the *exact* addressing functions
+the kernels use (the paged decode walk's ``decode_common.live_block_range``
+and ``kv_block_pages``, and the index maps ``prefix_page_index_map`` /
+``split_chunk_index_map`` — module-level precisely so tracer and kernel
+cannot diverge) over a concrete page table, records which physical page
+every grid cell DMAs, and asserts:
 
   * **domain purity** — each cell's *live* fetches (the ones whose compute
-    actually runs; clamped tail-overhang DMAs are recorded but skipped by
-    ``decode_common.chunk_relevant``, same as in the kernel) stay inside
-    one memory domain;
+    actually runs; clamped tail-overhang DMAs of the BlockSpec kernels are
+    recorded but skipped by ``decode_common.chunk_relevant``, same as in
+    the kernel) stay inside one memory domain;
   * **domain locality** — under ``HEAD_ALIGNED`` each live fetch lands in
     the very domain that executes the cell (``domain_of_head``);
   * **range consistency** — the split-K cells' live logical pages are
@@ -40,10 +40,7 @@ import numpy as np
 from repro.cache import layout as layout_lib
 from repro.kernels import decode_common
 from repro.kernels.decode_attention import split_chunk_index_map
-from repro.kernels.paged_decode_attention import (
-    paged_kv_index_map,
-    split_kv_index_map,
-)
+from repro.kernels.paged_decode_attention import kv_block_pages
 from repro.kernels.paged_prefill_attention import prefix_page_index_map
 
 __all__ = [
@@ -142,81 +139,68 @@ def trace_paged_decode(
     num_kv_heads: int,
     page_size: int,
     num_splits: int = 1,
+    pages_per_block: int = 1,
     window: Optional[int] = None,
     policy: str = layout_lib.HEAD_ALIGNED,
     num_domains: int = 2,
 ) -> AccessTrace:
-    """Replay the paged decode kernel's K/V index map (one-pass or
-    split-K) over ``page_table``/``lengths`` and return the per-cell
-    access trace. ``num_splits > 1`` additionally cross-checks every
-    cell's live logical pages against ``decode_split_ranges`` — the same
-    partition ``split_ranges_domain_aligned`` certifies analytically."""
+    """Replay the paged decode kernel's walk (one-pass or split-K) over
+    ``page_table``/``lengths`` and return the per-cell access trace: each
+    cell walks its row's live blocks (``decode_common.live_block_range``,
+    cut to its
+    split's range of blocks) and copies every slot ``kv_block_pages``
+    names; the live slots are the ones whose positions reach the
+    accumulator. ``num_splits > 1`` additionally cross-checks every cell's
+    live logical pages against ``decode_split_ranges`` over the table's
+    blocks — the same partition ``split_ranges_domain_aligned`` certifies
+    analytically."""
     pt = np.asarray(page_table, dtype=np.int64)
     lens = np.asarray(lengths, dtype=np.int64)
     b, max_pages = pt.shape
-    ranges = layout_lib.decode_split_ranges(max_pages, num_splits)
+    bk = pages_per_block * page_size
+    num_blocks = -(-max_pages // pages_per_block)
+    ranges = layout_lib.decode_split_ranges(num_blocks, num_splits)
+    split = len(ranges) > 1
+    kernel = "paged_flash_decode_split" if split else "paged_flash_decode"
     cells: List[CellTrace] = []
-
-    def live_at(batch: int, p_logical: int) -> bool:
-        return bool(decode_common.chunk_relevant(
-            p_logical * page_size, page_size, int(lens[batch]), window))
-
-    if len(ranges) == 1:
-        kernel = "paged_flash_decode"
-        for b_ in range(b):
-            for h_ in range(num_kv_heads):
+    for b_ in range(b):
+        first, last = (int(x) for x in decode_common.live_block_range(
+            int(lens[b_]), window, bk))
+        for h_ in range(num_kv_heads):
+            for s_, (start, end) in enumerate(ranges):
                 touched, live, logical = [], [], []
-                for p_ in range(max_pages):
-                    _, pid, _, _ = paged_kv_index_map(b_, h_, p_, pt, lens)
-                    pid = _pt_lookup(pt, pid)
-                    touched.append(pid)
-                    if live_at(b_, p_):
-                        live.append(pid)
-                        logical.append(p_)
+                for block in range(max(first, start), min(last, end)):
+                    slots = kv_block_pages(
+                        b_, h_, block, pt, lens,
+                        pages_per_block=pages_per_block,
+                        page_size=page_size, window=window,
+                    )
+                    for j, (is_live, (_, page)) in enumerate(slots):
+                        touched.append(_pt_lookup(pt, page))
+                        if bool(is_live):
+                            live.append(touched[-1])
+                            logical.append(block * pages_per_block + j)
+                # The walk must cover exactly this range's slice of the
+                # row's live pages (the relevance predicate, page by page).
+                expect = tuple(
+                    p for p in range(start * pages_per_block,
+                                     min(end * pages_per_block, max_pages))
+                    if decode_common.chunk_relevant(
+                        p * page_size, page_size, int(lens[b_]), window)
+                )
+                if tuple(logical) != expect:
+                    raise DomainPurityError(
+                        f"{kernel}: cell {(b_, h_, s_)} walks logical "
+                        f"pages {tuple(logical)}; its live pages are "
+                        f"{expect}"
+                    )
                 cells.append(CellTrace(
-                    cell=(b_, h_), head=h_,
+                    cell=(b_, h_, s_) if split else (b_, h_), head=h_,
                     cell_domain=layout_lib.domain_of_head(
                         h_, num_kv_heads, num_domains),
                     touched=tuple(touched), live=tuple(live),
                     live_logical=tuple(logical),
                 ))
-    else:
-        kernel = "paged_flash_decode_split"
-        pps = ranges[0][1] - ranges[0][0]
-        kv_index = split_kv_index_map(pps, max_pages)
-        for b_ in range(b):
-            for h_ in range(num_kv_heads):
-                for s_, (start, end) in enumerate(ranges):
-                    touched, live, logical = [], [], []
-                    for j_ in range(pps):
-                        _, pid, _, _ = kv_index(b_, h_, s_, j_, pt, lens)
-                        pid = _pt_lookup(pt, pid)
-                        touched.append(pid)
-                        p_global = s_ * pps + j_
-                        if p_global < max_pages and live_at(b_, p_global):
-                            live.append(pid)
-                            logical.append(p_global)
-                    # The kernel's live walk must be exactly this split's
-                    # slice of the plan-layer partition, truncated to the
-                    # sequence's live pages (the relevance predicate).
-                    live_pages = -(-int(lens[b_]) // page_size)
-                    expect = tuple(
-                        p for p in range(start, min(end, max_pages))
-                        if live_at(b_, p)
-                    )
-                    if tuple(logical) != expect:
-                        raise DomainPurityError(
-                            f"{kernel}: cell {(b_, h_, s_)} walks logical "
-                            f"pages {tuple(logical)}; decode_split_ranges "
-                            f"says {expect} (live={live_pages})"
-                        )
-                    cells.append(CellTrace(
-                        cell=(b_, h_, s_), head=h_,
-                        cell_domain=layout_lib.domain_of_head(
-                            h_, num_kv_heads, num_domains),
-                        touched=tuple(touched), live=tuple(live),
-                        live_logical=tuple(logical),
-                    ))
     return AccessTrace(
         kernel=kernel, policy=policy, num_kv_heads=num_kv_heads,
         num_domains=num_domains, cells=cells,
@@ -334,13 +318,15 @@ def trace_plan(
 ) -> AccessTrace:
     """Trace whatever kernel an :class:`repro.kernels.plan.AttentionPlan`
     would launch for this page table (paged one-pass or split-K decode),
-    using the plan's own ``page_size``/``num_splits``/``placement``."""
+    using the plan's own ``page_size``/``num_splits``/``pages_per_block``/
+    ``placement``."""
     policy = getattr(plan, "placement", None) or layout_lib.HEAD_ALIGNED
     return trace_paged_decode(
         page_table, lengths,
         num_kv_heads=num_kv_heads,
         page_size=plan.page_size,
         num_splits=max(1, int(plan.num_splits or 1)),
+        pages_per_block=plan.pages_per_block,
         window=window if window is not None else plan.window,
         policy=policy,
         num_domains=num_domains,

@@ -78,7 +78,7 @@ def _decode_kernel(
     @pl.when(decode_common.chunk_relevant(chunk_start, chunk, length, window))
     def _compute():
         decode_common.accumulate_kv_block(
-            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+            q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], acc_ref, m_ref, l_ref,
             scale=scale, softcap=softcap, window=window,
             block_start=chunk_start, block_len=chunk, length=length,
         )
@@ -118,7 +118,7 @@ def _decode_split_kernel(
     @pl.when(relevant)
     def _compute():
         decode_common.accumulate_kv_block(
-            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+            q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], acc_ref, m_ref, l_ref,
             scale=scale, softcap=softcap, window=window,
             block_start=chunk_start, block_len=chunk, length=length,
         )

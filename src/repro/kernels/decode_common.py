@@ -2,10 +2,11 @@
 
 Both flash-decode kernels (dense ``decode_attention.py`` and paged
 ``paged_decode_attention.py``) walk the KV axis in fixed-size units — KV
-chunks for the dense stripe, pages for the pool — and both need the same
-two pieces of softmax bookkeeping:
+chunks for the dense stripe, blocks of whole pages for the pool — and both
+need the same softmax bookkeeping (:func:`accumulate_kv_block`) and:
 
-  * :func:`chunk_relevant` — may a KV unit starting at ``chunk_start``
+  * :func:`chunk_relevant` (and its range form :func:`live_block_range`)
+    — may a KV unit starting at ``chunk_start``
     contain *any* position the query attends? This gates the whole
     unit's compute (``pl.when``); per-position masking inside the unit
     does the fine trimming. The predicate is exact (sound *and*
@@ -51,45 +52,60 @@ def chunk_relevant(chunk_start, chunk_len: int, length, window):
     return relevant
 
 
+def live_block_range(length, window, block_len: int):
+    """``[first, last)``: the blocks of ``block_len`` positions (block ``i``
+    starts at ``i * block_len``) for which :func:`chunk_relevant` holds —
+    ``last = ceil(length / block_len)`` and, under a sliding window,
+    ``first = max(0, length - window) // block_len``. Empty for ``length
+    == 0``. ``length`` may be a traced SMEM scalar; the paged decode
+    kernel bounds its walk with this."""
+    last = (length + block_len - 1) // block_len
+    if window is not None and window > 0:
+        first = jnp.maximum(length - window, 0) // block_len
+    else:
+        first = jnp.zeros_like(last)
+    return first, last
+
+
 def accumulate_kv_block(
-    q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+    q, k, v, acc_ref, m_ref, l_ref,
     *, scale, softcap, window, block_start, block_len: int, length,
     k_scale=None, v_scale=None,
 ):
-    """One online-softmax step over a KV unit, shared by all four decode
-    kernel bodies (dense/paged x one-pass/split-K).
+    """One online-softmax step over a KV block, shared by every decode
+    kernel body (dense one-pass and split-K, and the paged kernel's one
+    walk).
 
-    q_ref/k_ref/v_ref: the current ``(1, 1, G, D)`` q block and ``(1, 1,
-    block_len, D)`` KV unit; acc/m/l_ref: VMEM running state ``(G, D)`` /
+    q: the ``(G, D)`` q block; k/v: the ``(block_len, D)`` KV block, both
+    already loaded from VMEM; acc/m/l_ref: VMEM running state ``(G, D)`` /
     ``(G, 128)`` / ``(G, 128)``. ``block_start`` and ``length`` may be
-    traced (grid index x unit size, SMEM length); ``block_len`` /
+    traced (loop index x block size, SMEM length); ``block_len`` /
     ``window`` / ``scale`` / ``softcap`` are jit constants. Positions at
     or past ``length`` (and outside the sliding window) are masked
-    per-element; the caller gates whole irrelevant units with
-    :func:`chunk_relevant`.
+    per-element in the scores *and* in ``v``: a dead position may hold
+    anything, NaN included (the paged kernel copies the null page into a
+    block's dead slots, and ``0 * NaN`` is NaN). The caller skips whole
+    blocks with no live position.
 
-    ``k_scale`` / ``v_scale`` are the quantized pools' per-(head, page)
-    dequant factors (traced SMEM scalars, prefetched next to the page
-    table): the unit's 1-byte codes widen to fp32 here, in VMEM, right
-    before the matmuls — HBM streamed only the codes. ``None`` keeps the
-    fp32 pools untouched.
+    ``k_scale`` / ``v_scale`` are the quantized pools' dequant factors: a
+    ``(1, block_len)`` row giving each position its page's ``(head,
+    page)`` scale. They multiply the scores' and the
+    probabilities' columns, which is the product of dequantized codes
+    without widening a ``(block_len, D)`` scale; the 1-byte codes widen to
+    fp32 here, in VMEM — HBM streamed only the codes. ``None`` leaves the
+    pools untouched.
     """
-    q = q_ref[0, 0].astype(jnp.float32)      # (G, D)
-    k = k_ref[0, 0].astype(jnp.float32)      # (block_len, D)
-    v = v_ref[0, 0].astype(jnp.float32)
-    if k_scale is not None:
-        k = k * k_scale
-    if v_scale is not None:
-        v = v * v_scale
+    q = q.astype(jnp.float32)                # (G, D)
+    k = k.astype(jnp.float32)                # (block_len, D)
+    v = v.astype(jnp.float32)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
+    if k_scale is not None:
+        s = s * k_scale
     if softcap is not None and softcap > 0:
         s = softcap * jnp.tanh(s / softcap)
-    pos = block_start + jax.lax.broadcasted_iota(jnp.int32, (1, block_len), 1)
-    valid = pos < length
-    if window is not None and window > 0:
-        valid &= pos > length - 1 - window
+    valid = _valid(block_start, (1, block_len), 1, length, window)
     s = jnp.where(valid, s, NEG_INF)
     m_prev = m_ref[:, 0:1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -98,10 +114,23 @@ def accumulate_kv_block(
     l_ref[...] = jnp.broadcast_to(
         l_ref[:, 0:1] * alpha + jnp.sum(p, axis=1, keepdims=True), l_ref.shape
     )
+    if v_scale is not None:
+        p = p * v_scale
+    v_valid = _valid(block_start, (block_len, 1), 0, length, window)
+    v = jnp.where(v_valid, v, 0.0)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+
+def _valid(block_start, shape, axis, length, window):
+    """The decode mask over one block's positions, laid along ``axis``."""
+    pos = block_start + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+    valid = pos < length
+    if window is not None and window > 0:
+        valid &= pos > length - 1 - window
+    return valid
 
 
 def combine_split_states(acc, m, l):

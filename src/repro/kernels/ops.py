@@ -508,6 +508,7 @@ def paged_decode_attention(
             softcap=softcap, scale=scale, window=window,
             k_scales=ks, v_scales=vs,
             num_splits=plan.num_splits,
+            pages_per_block=plan.pages_per_block,
             interpret=plan.interpret,
         )
 

@@ -5,18 +5,32 @@ a dense per-slot stripe ``(B, Hkv, Smax, D)``; here it is a pool of
 fixed-size pages ``(Hkv, num_pages, page_size, D)`` plus a per-sequence page
 table, so sequences grow page-at-a-time, share prefix pages, and never
 reserve capacity they don't use. The kernel consumes that layout *natively*:
-the page table rides in SMEM via scalar prefetch and the K/V BlockSpec index
-maps read it directly —
+the pools stay in HBM (``memory_space=pl.ANY``), the page table and lengths
+ride in SMEM via scalar prefetch, and the kernel copies the pages it needs
+itself, with no gather materializing a dense view first.
 
-    index_map = lambda b, h, p, pt, lens: (h, pt[b, p], 0, 0)
-
-so the Pallas pipeline DMAs exactly the pages the sequence owns, in logical
-order, with no gather/copy materializing a dense view first.
+The walk. The grid is ``(B, Hkv)``: one cell per (batch row, KV head).
+A cell walks its row's KV in compute blocks of ``pages_per_block`` pages
+(``plan.decode_pages_per_block`` picks the count from the shape), and only
+the blocks that hold a position the row attends: ``[first, last)`` with
+``last = ceil(length / bk)`` and, under a sliding window, ``first =
+max(0, length - window) // bk`` (``decode_common.live_block_range``). A
+row of length 0 spends its cell and nothing more. For each block the
+kernel issues one async copy per page of K and of V into a double-buffered
+VMEM block ``(2, pages_per_block, page_size, D)``, starting the next
+block's copies before it computes on the current one.
+:func:`kv_block_pages` names the (head, page) each slot copies and whether
+it is live; the domain-purity access tracer
+(``repro.analysis.access_trace``) replays the same function.
+Slots past the row's live pages copy the null page their table entry holds
+(past the table's end, its last entry): copying them costs less on a v5e
+than a branch per page (PERF.md). The block arithmetic masks their
+positions in the scores and in ``v``, so nothing they hold, NaN included,
+reaches the accumulator.
 
 The NUMA structure of the dense kernel is preserved:
-  * grid (B, Hkv, max_pages) is head-first — one ACC still owns each
-    (batch, kv-head) cell, and the leading two dims stay PARALLEL so a
-    megacore splits at ACC boundaries;
+  * the grid is head-first — one ACC owns each (batch, kv-head) cell, and
+    both grid axes are PARALLEL so a megacore splits at ACC boundaries;
   * the physical page array is **head-major**: all pages of one KV head are
     contiguous, i.e. they live in that head's domain stripe
     (``cache.layout.HEAD_ALIGNED``). The cell and its pages share a domain
@@ -26,26 +40,20 @@ The NUMA structure of the dense kernel is preserved:
     (batch, kv-head), never per q-head.
 
 Split-K (PR 4): ``num_splits > 1`` adds a PARALLEL grid axis over
-contiguous **page ranges** (``cache.layout.decode_split_ranges``). Each
-(b, hkv, split) cell walks only its range and emits partial ``(acc, m,
-l)``; ``decode_common.combine_split_states`` merges them. Split
-boundaries are page-granular by construction and, because the pool is
-head-major (every page of a KV head lives in that head's domain stripe),
-**no split ever straddles NUMA domains** — each partial pass stays inside
-one domain's cache (``layout.split_ranges_domain_aligned`` proves this in
-tests). ``num_splits`` comes from the plan layer's occupancy model; the
+contiguous **block ranges** (``cache.layout.decode_split_ranges`` over the
+table's blocks). Each (b, hkv, split) cell runs the same walk, cut to its
+range, and emits partial ``(acc, m, l)``; ``decode_common.
+combine_split_states`` merges them. Split boundaries are page-granular by
+construction and, because the pool is head-major, **no split ever
+straddles NUMA domains** (``layout.split_ranges_domain_aligned``).
+``num_splits`` comes from the plan layer's occupancy model; the
 long-context, small-batch serving regime is where it exceeds 1.
-
-Out-of-range page-table entries must hold a valid physical id (the engine
-pads with the reserved null page 0): the index map still issues the copy,
-and the in-kernel relevance test (``decode_common.chunk_relevant``) skips
-the compute.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -59,143 +67,153 @@ from repro.kernels import decode_common
 NEG_INF = decode_common.NEG_INF
 
 
-def paged_kv_index_map(b_, h_, p_, pt, ln, *scales):
-    """K/V BlockSpec index map of the one-pass paged kernel: grid cell
-    (batch, kv-head, logical page) DMAs physical page ``pt[b, p]`` of head
-    ``h``. Module-level (not a closure) so the domain-purity access tracer
-    (``repro.analysis.access_trace``) replays the *same* function the
-    kernel hands to ``pallas_call``. The trailing ``*scales`` absorbs the
-    quantized pools' prefetched scale tables (unused for addressing — the
-    physical page id keys both the pool and its scales)."""
-    return (h_, pt[b_, p_], 0, 0)
+def kv_block_pages(
+    b, h, block, page_table, lengths, *, pages_per_block: int,
+    page_size: int, window: Optional[int] = None,
+) -> List[Tuple[object, Tuple[object, object]]]:
+    """What block ``block`` of cell ``(b, h)`` copies: one ``(live, (head,
+    page))`` pair per slot of the block. Every slot is copied, ``(head,
+    page)`` indexing the head-major pool; the table index is clamped to its
+    last slot, so a slot past the table still names a valid page, and a
+    slot past the row's live pages names the null page its table entry
+    holds. ``live`` says whether the slot's page holds a position the row
+    attends (``decode_common.chunk_relevant``) and lies inside the table:
+    only live slots reach the accumulator.
+
+    Module-level so the kernel's DMA loop and the domain-purity access
+    tracer (``repro.analysis.access_trace``) call the *same* function:
+    ``page_table`` / ``lengths`` are the SMEM refs in the kernel and numpy
+    arrays in the tracer."""
+    max_pages = page_table.shape[1]
+    length = lengths[b]
+    slots = []
+    for j in range(pages_per_block):
+        slot = block * pages_per_block + j
+        live = (slot < max_pages) & decode_common.chunk_relevant(
+            slot * page_size, page_size, length, window
+        )
+        page = page_table[b, jnp.minimum(slot, max_pages - 1)]
+        slots.append((live, (h, page)))
+    return slots
 
 
-def _q_index_map(b_, h_, p_, pt, ln, *scales):
+def _q_index_map(b_, h_, *rest):
     return (b_, h_, 0, 0)
 
 
-def split_kv_index_map(pps, max_pages):
-    """K/V index map of the split-K paged kernel for ``pps`` pages per
-    split over a ``max_pages``-wide table. The tail split's overhang is
-    clamped to the last table slot — the DMA must name a valid page; the
-    kernel's range test skips its compute."""
-
-    def kv_index(b_, h_, s_, j_, pt, ln, *scales):
-        return (h_, pt[b_, jnp.minimum(s_ * pps + j_, max_pages - 1)], 0, 0)
-
-    return kv_index
-
-
-def _split_q_index_map(b_, h_, s_, j_, pt, ln, *scales):
-    return (b_, h_, 0, 0)
-
-
-def _split_out_index_map(b_, h_, s_, j_, pt, ln, *scales):
+def _split_out_index_map(b_, h_, s_, *prefetch):
     return (b_, h_, s_, 0, 0)
+
+
+def _scale_index_map(b_, h_, *rest):
+    return (h_, b_, 0, 0)
 
 
 def _paged_decode_kernel(
     pt_ref, len_ref,            # scalar-prefetch: (B, max_pages), (B,)
-    *refs,                      # [ks_ref, vs_ref,] q, k, v, o, acc, m, l
-    scale, softcap, window, page_size, max_pages, quantized,
+    *refs,                      # q, k/v HBM, [ks, vs,] out(s), scratch
+    scale, softcap, window, page_size, pages_per_block, blocks_per_split,
+    quantized, split,
 ):
+    """One walk for both paths: a cell's ``[first, last)`` live blocks,
+    cut to its split's range under split-K. One-pass emits the normalized
+    output; split-K emits the raw ``(acc, m, l)``."""
+    q_ref, k_hbm, v_hbm, *refs = refs
     if quantized:
-        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        ks_ref, vs_ref, *refs = refs
+    if split:
+        acc_out, m_out, l_out, *refs = refs
     else:
-        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-        ks_ref = vs_ref = None
+        o_ref, *refs = refs
+    k_buf, v_buf, sem, acc_ref, m_ref, l_ref = refs
+    d = k_buf.shape[-1]
+    bk = pages_per_block * page_size
     b_idx = pl.program_id(0)
     h_idx = pl.program_id(1)
-    p_idx = pl.program_id(2)
     length = len_ref[b_idx]
+    first, last = decode_common.live_block_range(length, window, bk)
+    if split:
+        s_idx = pl.program_id(2)
+        first = jnp.maximum(first, s_idx * blocks_per_split)
+        last = jnp.minimum(last, (s_idx + 1) * blocks_per_split)
 
-    @pl.when(p_idx == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    page_start = p_idx * page_size
-
-    @pl.when(
-        decode_common.chunk_relevant(page_start, page_size, length, window)
-    )
-    def _compute():
-        if quantized:
-            pid = pt_ref[b_idx, p_idx]
-            k_scale = ks_ref[h_idx, pid]
-            v_scale = vs_ref[h_idx, pid]
-        else:
-            k_scale = v_scale = None
-        decode_common.accumulate_kv_block(
-            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
-            scale=scale, softcap=softcap, window=window,
-            block_start=page_start, block_len=page_size, length=length,
-            k_scale=k_scale, v_scale=v_scale,
+    def block_slots(block):
+        return kv_block_pages(
+            b_idx, h_idx, block, pt_ref, len_ref,
+            pages_per_block=pages_per_block, page_size=page_size,
+            window=window,
         )
 
-    @pl.when(p_idx == max_pages - 1)
-    def _emit():
-        l = l_ref[:, 0:1]
-        o_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+    def copies(block, buf):
+        out = []
+        for j, (_, (head, page)) in enumerate(block_slots(block)):
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[head, page], k_buf.at[buf, j], sem.at[0, buf]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[head, page], v_buf.at[buf, j], sem.at[1, buf]))
+        return out
 
+    def start(block, buf):
+        for c in copies(block, buf):
+            c.start()
 
-def _paged_decode_split_kernel(
-    pt_ref, len_ref,            # scalar-prefetch: (B, max_pages), (B,)
-    *refs,                      # [ks, vs,] q, k, v, acc/m/l out, acc/m/l
-    scale, softcap, window, page_size, max_pages, pages_per_split,
-    quantized,
-):
-    """Stage one of paged split-K decode: one (b, hkv, split) cell walks
-    its page range (domain-pure under the head-major pool) and emits raw
-    ``(acc, m, l)``. Overhanging tail-split steps (non-divisible ranges:
-    their DMA is clamped to the last table slot) are skipped by the range
-    test and contribute the empty state."""
-    if quantized:
-        (ks_ref, vs_ref, q_ref, k_ref, v_ref,
-         acc_out, m_out, l_out, acc_ref, m_ref, l_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref,
-         acc_out, m_out, l_out, acc_ref, m_ref, l_ref) = refs
-        ks_ref = vs_ref = None
-    b_idx = pl.program_id(0)
-    h_idx = pl.program_id(1)
-    s_idx = pl.program_id(2)
-    j_idx = pl.program_id(3)
-    length = len_ref[b_idx]
+    def wait(block, buf):
+        for c in copies(block, buf):
+            c.wait()
 
-    @pl.when(j_idx == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def page_scales(scale_ref, block):
+        """``(1, bk)`` row: each position's page scale (0 on dead slots)."""
+        max_pages = pt_ref.shape[1]
+        col_page = jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1) // page_size
+        row = jnp.zeros((1, bk), jnp.float32)
+        for j, (live, _) in enumerate(block_slots(block)):
+            slot = jnp.minimum(block * pages_per_block + j, max_pages - 1)
+            row = jnp.where(col_page == j,
+                            jnp.where(live, scale_ref[0, 0, 0, slot], 0.0),
+                            row)
+        return row
 
-    p_global = s_idx * pages_per_split + j_idx
-    page_start = p_global * page_size
-    relevant = (p_global < max_pages) & decode_common.chunk_relevant(
-        page_start, page_size, length, window
-    )
+    @pl.when(first < last)
+    def _prime():
+        start(first, 0)
 
-    @pl.when(relevant)
-    def _compute():
+    def body(block, carry):
+        buf = jax.lax.rem(block - first, 2)
+
+        @pl.when(block + 1 < last)
+        def _prefetch():
+            start(block + 1, 1 - buf)
+
+        wait(block, buf)
+        k_scale = v_scale = None
         if quantized:
-            pid = pt_ref[b_idx, jnp.minimum(p_global, max_pages - 1)]
-            k_scale = ks_ref[h_idx, pid]
-            v_scale = vs_ref[h_idx, pid]
-        else:
-            k_scale = v_scale = None
+            k_scale = page_scales(ks_ref, block)
+            v_scale = page_scales(vs_ref, block)
         decode_common.accumulate_kv_block(
-            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+            q_ref[0, 0],
+            k_buf[buf].astype(jnp.float32).reshape(bk, d),
+            v_buf[buf].astype(jnp.float32).reshape(bk, d),
+            acc_ref, m_ref, l_ref,
             scale=scale, softcap=softcap, window=window,
-            block_start=page_start, block_len=page_size, length=length,
+            block_start=block * bk, block_len=bk, length=length,
             k_scale=k_scale, v_scale=v_scale,
         )
+        return carry
 
-    @pl.when(j_idx == pages_per_split - 1)
-    def _emit():
+    jax.lax.fori_loop(first, last, body, 0)
+
+    if split:
         acc_out[0, 0, 0] = acc_ref[...]
         m_out[0, 0, 0] = m_ref[...]
         l_out[0, 0, 0] = l_ref[...]
+    else:
+        l = l_ref[:, 0:1]
+        o_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
 
 
 def paged_flash_decode(
@@ -209,6 +227,7 @@ def paged_flash_decode(
     scale: Optional[float] = None,
     window: Optional[int] = None,
     num_splits: int = 1,
+    pages_per_block: Optional[int] = None,
     interpret: bool = False,
     k_scales: Optional[jnp.ndarray] = None,
     v_scales: Optional[jnp.ndarray] = None,
@@ -218,15 +237,19 @@ def paged_flash_decode(
     sequence's live pages must point at a valid page — the null page);
     lengths: (B,) int32. Returns (B, Hq, D).
 
+    ``pages_per_block`` is the compute block in pages; None takes the
+    plan layer's choice for this shape (``plan.decode_pages_per_block``).
     ``num_splits > 1`` runs the sequence-parallel (split-K) path over
-    domain-aligned page ranges (clamped to the table width; 1 keeps the
-    one-pass kernel).
+    domain-aligned ranges of blocks (the block shrinks to at most
+    ``ceil(max_pages / num_splits)`` pages, so each range has one; 1 keeps
+    the one-pass walk).
 
     ``k_scales`` / ``v_scales`` (``(Hkv, P)`` fp32, both or neither) mark
-    the pools as quantized codes (``cache.quant``): the scales prefetch
-    into SMEM next to the page table — metadata keyed by the *physical*
-    page id the table resolves — and the kernel bodies dequantize each
-    page in VMEM right before the matmuls.
+    the pools as quantized codes (``cache.quant``): each page of a block is
+    dequantized with its own ``(head, page)`` scale in VMEM. The scales
+    are gathered through the page table to one ``(max_pages,)`` row per
+    cell, which the pipeline brings into SMEM cell by cell: a whole pool's
+    tables would not fit SMEM at long contexts and many heads.
     """
     b, hq, d = q.shape
     hkv, num_pages, page_size, _ = k_pages.shape
@@ -239,54 +262,84 @@ def paged_flash_decode(
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be passed together")
     quantized = k_scales is not None
+    if pages_per_block is None:
+        from repro.kernels import plan as plan_lib
+
+        pages_per_block = plan_lib.decode_pages_per_block(
+            max_pages, page_size, d, k_pages.dtype.itemsize,
+        )
+    # Every split-K range keeps a block of its own.
+    pages_per_block = min(pages_per_block,
+                          -(-max_pages // max(1, num_splits)))
 
     gp = max(8, -(-group // 8) * 8)  # pad GQA group to the sublane quantum
     qg = q.reshape(b, hkv, group, d)
     if gp != group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
 
-    ranges = layout_lib.decode_split_ranges(max_pages, num_splits)
+    num_blocks = -(-max_pages // pages_per_block)
+    ranges = layout_lib.decode_split_ranges(num_blocks, num_splits)
     num_splits = len(ranges)
-    if num_splits > 1:
-        return _paged_flash_decode_split(
-            qg, k_pages, v_pages, page_table, lengths, ranges,
-            scale=scale, softcap=softcap, window=window,
-            max_pages=max_pages, gp=gp, group=group, interpret=interpret,
-            out_dtype=q.dtype, k_scales=k_scales, v_scales=v_scales,
-        )
+    split = num_splits > 1
 
-    prefetch = [page_table.astype(jnp.int32), lengths.astype(jnp.int32)]
+    page_table = page_table.astype(jnp.int32)
+    prefetch = [page_table, lengths.astype(jnp.int32)]
+    in_specs = [
+        pl.BlockSpec((1, 1, gp, d), _q_index_map),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    scale_rows = []
     if quantized:
-        prefetch += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
+        for sc in (k_scales, v_scales):
+            rows = sc.astype(jnp.float32)[:, page_table]  # (Hkv, B, max_pages)
+            scale_rows.append(rows.reshape(hkv, b, 1, max_pages))
+            in_specs.append(pl.BlockSpec(
+                (1, 1, 1, max_pages), _scale_index_map,
+                memory_space=pltpu.SMEM,
+            ))
+    if split:
+        grid = (b, hkv, num_splits)
+        out_specs = [
+            pl.BlockSpec((1, 1, 1, gp, d), _split_out_index_map),
+            pl.BlockSpec((1, 1, 1, gp, 128), _split_out_index_map),
+            pl.BlockSpec((1, 1, 1, gp, 128), _split_out_index_map),
+        ]
+        out_shape = [
+            jax.ShapeDtypeStruct((b, hkv, num_splits, gp, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, num_splits, gp, 128), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, num_splits, gp, 128), jnp.float32),
+        ]
+    else:
+        grid = (b, hkv)
+        out_specs = pl.BlockSpec((1, 1, gp, d), _q_index_map)
+        out_shape = jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype)
+    block_shape = (2, pages_per_block, page_size, d)
     fn = pl.pallas_call(
         functools.partial(
             _paged_decode_kernel,
             scale=scale, softcap=softcap, window=window,
-            page_size=page_size, max_pages=max_pages, quantized=quantized,
+            page_size=page_size, pages_per_block=pages_per_block,
+            blocks_per_split=ranges[0][1] - ranges[0][0],
+            quantized=quantized, split=split,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=(b, hkv, max_pages),
-            in_specs=[
-                pl.BlockSpec((1, 1, gp, d), _q_index_map),
-                pl.BlockSpec((1, 1, page_size, d), paged_kv_index_map),
-                pl.BlockSpec((1, 1, page_size, d), paged_kv_index_map),
-            ],
-            out_specs=pl.BlockSpec((1, 1, gp, d), _q_index_map),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
             scratch_shapes=[
+                pltpu.VMEM(block_shape, k_pages.dtype),
+                pltpu.VMEM(block_shape, v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((gp, d), jnp.float32),
                 pltpu.VMEM((gp, 128), jnp.float32),
                 pltpu.VMEM((gp, 128), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
+        out_shape=out_shape,
         compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=(
-                compat.PARALLEL,
-                compat.PARALLEL,
-                compat.ARBITRARY,
-            ),
+            dimension_semantics=(compat.PARALLEL,) * len(grid),
         ),
         cost_estimate=pl.CostEstimate(
             flops=int(4.0 * b * hq * max_pages * page_size * d),
@@ -297,79 +350,11 @@ def paged_flash_decode(
             transcendentals=int(b * hq * max_pages * page_size),
         ),
         interpret=interpret,
-        name="paged_flash_decode",
+        name="paged_flash_decode_split" if split else "paged_flash_decode",
     )
-    out = fn(*prefetch, qg, k_pages, v_pages)
-    return out[:, :, :group, :].reshape(b, hq, d)
-
-
-def _paged_flash_decode_split(
-    qg, k_pages, v_pages, page_table, lengths, ranges,
-    *, scale, softcap, window, max_pages, gp, group, interpret, out_dtype,
-    k_scales=None, v_scales=None,
-):
-    b = qg.shape[0]
-    hkv, _, page_size, d = k_pages.shape
-    num_splits = len(ranges)
-    pps = ranges[0][1] - ranges[0][0]  # pages per split (tail may be short)
-    quantized = k_scales is not None
-
-    kv_index = split_kv_index_map(pps, max_pages)
-    prefetch = [page_table.astype(jnp.int32), lengths.astype(jnp.int32)]
-    if quantized:
-        prefetch += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
-
-    fn = pl.pallas_call(
-        functools.partial(
-            _paged_decode_split_kernel,
-            scale=scale, softcap=softcap, window=window,
-            page_size=page_size, max_pages=max_pages, pages_per_split=pps,
-            quantized=quantized,
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=(b, hkv, num_splits, pps),
-            in_specs=[
-                pl.BlockSpec((1, 1, gp, d), _split_q_index_map),
-                pl.BlockSpec((1, 1, page_size, d), kv_index),
-                pl.BlockSpec((1, 1, page_size, d), kv_index),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, 1, gp, d), _split_out_index_map),
-                pl.BlockSpec((1, 1, 1, gp, 128), _split_out_index_map),
-                pl.BlockSpec((1, 1, 1, gp, 128), _split_out_index_map),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((gp, d), jnp.float32),
-                pltpu.VMEM((gp, 128), jnp.float32),
-                pltpu.VMEM((gp, 128), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, num_splits, gp, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, num_splits, gp, 128), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, num_splits, gp, 128), jnp.float32),
-        ],
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=(
-                compat.PARALLEL,
-                compat.PARALLEL,
-                compat.PARALLEL,
-                compat.ARBITRARY,
-            ),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=int(4.0 * b * hkv * group * max_pages * page_size * d),
-            bytes_accessed=int(
-                k_pages.dtype.itemsize
-                * b * (2 * hkv * max_pages * page_size * d + 2 * hkv * group * d)
-            ),
-            transcendentals=int(b * hkv * group * max_pages * page_size),
-        ),
-        interpret=interpret,
-        name="paged_flash_decode_split",
-    )
-    acc, m, l = fn(*prefetch, qg, k_pages, v_pages)
+    if not split:
+        out = fn(*prefetch, qg, k_pages, v_pages, *scale_rows)
+        return out[:, :, :group, :].reshape(b, hq, d)
+    acc, m, l = fn(*prefetch, qg, k_pages, v_pages, *scale_rows)
     out = decode_common.combine_split_states(acc, m[..., :1], l[..., :1])
-    return out[:, :, :group, :].reshape(b, hkv * group, d).astype(out_dtype)
+    return out[:, :, :group, :].reshape(b, hq, d).astype(q.dtype)

@@ -11,9 +11,10 @@ collapses all of that into a single value:
   ``AttentionPlan`` — phase (prefill | extend | decode), KV layout (dense |
   paged), the resolved ``MappingConfig``, the concrete kernel impl, the
   decode KV chunk, the split-K ``num_splits`` (PR 4: chosen by
-  ``perf_model.estimate_decode_splits``' occupancy model), the NUMA
-  placement policy, and the backend/interpret environment it was resolved
-  for. The paged-extend impl is likewise a scored choice
+  ``perf_model.estimate_decode_splits``' occupancy model), the paged
+  decode kernel's ``pages_per_block`` (:func:`decode_pages_per_block`),
+  the NUMA placement policy, and the backend/interpret environment it was
+  resolved for. The paged-extend impl is likewise a scored choice
   (``perf_model.estimate_extend_prefill``): the prefix-aware kernel vs
   the gather route, per shape.
 
@@ -117,6 +118,9 @@ class AttentionPlan:
     #: its owner's HBM); False when striping the pool across devices won
     #: (fast fabric + too few KV heads to feed every device). None off-mesh.
     split_device_pure: Optional[bool] = None
+    #: DECODE/PAGED: pages the paged decode kernel loads per compute block
+    #: (:func:`decode_pages_per_block`). 1 elsewhere.
+    pages_per_block: int = 1
 
     @property
     def prefix_capacity(self) -> int:
@@ -463,6 +467,48 @@ def _decode_chunk(mapping: MappingConfig, smax: int) -> int:
     return chunk
 
 
+#: Target compute block of the paged decode kernel, in tokens. Chosen by a
+#: sweep of 128-1024 on a TPU v5e at the benchmark's decode shapes
+#: (PERF.md): larger blocks ran faster up to the largest.
+PAGED_DECODE_BLOCK_TOKENS = 1024
+
+
+def decode_pages_per_block(
+    max_pages: int,
+    page_size: int,
+    head_dim: int,
+    dtype_bytes: int,
+    *,
+    num_splits: int = 1,
+    vmem_budget_bytes: Optional[int] = None,
+) -> int:
+    """Pages per compute block of the paged decode kernel, from the shape
+    alone: :data:`PAGED_DECODE_BLOCK_TOKENS` worth of pages, clamped to the
+    table (one block covers it whole) and to ``ceil(max_pages /
+    num_splits)`` so every split-K range keeps a block of its own, then
+    halved until the block's VMEM fits ``vmem_budget_bytes`` (the
+    backend's resident-K/V budget when None). The block need not divide
+    ``max_pages``: the tail block's slots past the table are never
+    copied."""
+    if vmem_budget_bytes is None:
+        vmem_budget_bytes = vmem_limits(compat.default_backend())[0]
+    ppb = max(1, PAGED_DECODE_BLOCK_TOKENS // page_size)
+    ppb = min(ppb, max_pages, -(-max_pages // max(1, num_splits)))
+    ppb = max(1, ppb)
+    while ppb > 1 and paged_decode_vmem_bytes(
+        ppb * page_size, head_dim, dtype_bytes
+    ) > vmem_budget_bytes:
+        ppb //= 2
+    return ppb
+
+
+def paged_decode_vmem_bytes(block_tokens: int, head_dim: int,
+                            dtype_bytes: int) -> int:
+    """VMEM the paged decode kernel holds for one block size: K and V
+    double buffers in the pool's dtype, plus their fp32 widenings."""
+    return block_tokens * head_dim * (2 * 2 * dtype_bytes + 2 * 4)
+
+
 # -----------------------------------------------------------------------------
 # The resolver
 # -----------------------------------------------------------------------------
@@ -560,6 +606,13 @@ def _plan_cached(
             num_splits = split.num_splits
             split_device_pure = split.device_pure
 
+    pages_per_block = 1
+    if phase == DECODE and kv_layout == PAGED:
+        pages_per_block = decode_pages_per_block(
+            -(-seq_kv // page_size), page_size, head_dim, dtype_bytes,
+            num_splits=num_splits, vmem_budget_bytes=vmem_budget_bytes,
+        )
+
     return AttentionPlan(
         phase=phase,
         kv_layout=kv_layout,
@@ -576,6 +629,7 @@ def _plan_cached(
         num_devices=num_devices,
         split_device_pure=split_device_pure,
         kv_dtype=kv_dtype,
+        pages_per_block=pages_per_block,
     )
 
 

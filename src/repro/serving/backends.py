@@ -1649,7 +1649,8 @@ class PagedBackend(_Backend):
     def decode_plan(self) -> plan_lib.AttentionPlan:
         """The resolved steady-state decode plan, scored jointly over
         (domain, device) when this backend runs on a mesh — exposes
-        ``num_splits`` / ``split_device_pure`` for stats and tests."""
+        ``num_splits`` / ``pages_per_block`` / ``split_device_pure`` for
+        stats and tests."""
         return plan_lib.plan_for_config(
             self.cfg,
             (self.rows, self.cfg.n_heads, self.cfg.n_kv_heads,

@@ -564,6 +564,7 @@ class LLMEngine:
         prefix = b.prefix_stats() if hasattr(b, "prefix_stats") else {}
         nb = max(b.num_active, 1)
         t = b.decode_time_model(nb)
+        plan = b.decode_plan() if hasattr(b, "decode_plan") else None
         return SchedulerStats(
             kv_layout=b.kv_layout,
             running=b.num_active,
@@ -590,6 +591,8 @@ class LLMEngine:
             demoted_pages=int(prefix.get("demoted_pages", 0)),
             promoted_pages=int(prefix.get("promoted_pages", 0)),
             host_bytes_resident=int(prefix.get("host_bytes_resident", 0)),
+            decode_splits=plan.num_splits if plan else 1,
+            pages_per_block=plan.pages_per_block if plan else 0,
         )
 
     def drift_model_fn(self):

@@ -118,6 +118,8 @@ class SchedulerStats:
     demoted_pages: int = 0         # pages demoted device -> host tier
     promoted_pages: int = 0        # pages promoted host tier -> device
     host_bytes_resident: int = 0   # host-tier bytes currently held
+    decode_splits: int = 1         # paged decode plan: split-K ranges
+    pages_per_block: int = 0       # paged decode plan: pages a kernel block
 
     def summary(self) -> str:
         prefix = ("n/a" if self.prefix_hit_rate is None
@@ -129,6 +131,10 @@ class SchedulerStats:
                     f"{self.promoted_pages} promoted "
                     f"({self.host_bytes_resident} host bytes)")
         dtype = f" {self.kv_dtype}" if self.kv_dtype != "fp32" else ""
+        block = ""
+        if self.pages_per_block:
+            block = (f" | decode blocks of {self.pages_per_block} pages x "
+                     f"{self.decode_splits} splits")
         return (
             f"[{self.kv_layout}{dtype}{mesh} N={self.steps_per_sync}] "
             f"{self.completed} done / {self.running} "
@@ -143,7 +149,7 @@ class SchedulerStats:
             f"({self.resumed_tokens} tokens resumed) | "
             f"{self.prefill_launches} prefill launches "
             f"({self.batched_prefills} batched)"
-            f"{tier}"
+            f"{block}{tier}"
         )
 
 

@@ -22,15 +22,38 @@ def _table(b, mp, start=1):
 
 
 def test_one_pass_trace_touches_every_table_slot():
+    """The walk copies exactly the pages of each row's live blocks (slots
+    past the table clamp to its last entry) and no block past the row's
+    length; compute is gated to the live pages."""
     pt = _table(2, 6)
-    tr = at.trace_paged_decode(pt, [48, 20], num_kv_heads=4, page_size=8)
-    assert len(tr.cells) == 2 * 4
+    for ppb in (1, 4):
+        tr = at.trace_paged_decode(pt, [48, 20], num_kv_heads=4,
+                                   page_size=8, pages_per_block=ppb)
+        assert len(tr.cells) == 2 * 4
+        for c in tr.cells:
+            b = c.cell[0]
+            live = -(-(48 if b == 0 else 20) // 8)
+            blocks = -(-live // ppb)
+            assert c.touched == tuple(                 # live blocks only
+                pt[b, min(slot, 5)] for slot in range(blocks * ppb))
+            assert c.live == tuple(pt[b, :live])       # compute gated
+            assert c.live_logical == tuple(range(live))
+        tr.assert_domain_local()                       # head-major pool
+
+
+def test_one_pass_trace_with_a_window():
+    """Under a sliding window the walk starts at the block holding the
+    window's edge and copies only the pages the window reaches."""
+    pt = _table(1, 12)
+    tr = at.trace_paged_decode(pt, [90], num_kv_heads=2, page_size=8,
+                               pages_per_block=4, window=30)
+    # Positions 60..89 attend: pages 7..11 (the edge falls inside page 7,
+    # in the second block of four).
     for c in tr.cells:
-        b = c.cell[0]
-        assert c.touched == tuple(pt[b])          # every slot DMA'd
-        live = -(-(48 if b == 0 else 20) // 8)
-        assert c.live == tuple(pt[b, :live])      # compute gated by length
-    tr.assert_domain_local()                      # head-major pool
+        assert c.live_logical == (7, 8, 9, 10, 11)
+        assert c.live == tuple(pt[0, 7:12])
+        assert c.touched == tuple(pt[0, 4:12])   # blocks 1 and 2, whole
+    tr.assert_domain_local()
 
 
 def test_split_trace_matches_decode_split_ranges():
@@ -48,13 +71,14 @@ def test_split_trace_matches_decode_split_ranges():
 
 
 def test_split_trace_clamps_tail_overhang():
-    # 10 pages over 4 splits -> pps=3, last split covers (9, 10): two
-    # overhang steps clamp to slot 9, recorded as touched but not live.
+    # 10 pages over 4 splits -> 3 pages a split, last split covers (9, 10):
+    # the walk stops at the range's end, so the short tail split copies its
+    # one page and nothing past the table.
     pt = _table(1, 10)
     tr = at.trace_paged_decode(pt, [80], num_kv_heads=1, page_size=8,
                                num_splits=4)
     tail = tr.cells[-1]
-    assert tail.touched == (pt[0, 9], pt[0, 9], pt[0, 9])
+    assert tail.touched == (pt[0, 9],)
     assert tail.live == (pt[0, 9],)
     assert tail.live_logical == (9,)
 
@@ -66,7 +90,7 @@ def test_window_gates_live_pages():
                                      window=16)
     assert full.live_pages == 8
     assert windowed.live_pages == 2   # only the last two pages attend
-    assert windowed.touched_pages == 8  # DMAs still issue, compute skips
+    assert windowed.touched_pages == 2  # and only their blocks are copied
 
 
 def test_interleaved_straddle_fails_purity():

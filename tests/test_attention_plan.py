@@ -357,3 +357,60 @@ def test_engine_resolves_schedules_through_plans():
         cfg, (2, cfg.n_heads, cfg.n_kv_heads, 1, 64, cfg.head_dim),
         phase=plan_lib.DECODE, kv_layout=plan_lib.PAGED, page_size=16,
     ).mapping
+
+
+def test_paged_decode_block_comes_from_the_shape():
+    """``pages_per_block`` is a function of the decode shape alone: the
+    target block worth of pages, clamped to the table (a short table is one
+    block; a long one need not be a multiple of it, the tail block's slots
+    past the table are never read), to one block per split, and halved
+    under the VMEM budget."""
+    ps, d = 16, 128
+    target = plan_lib.PAGED_DECODE_BLOCK_TOKENS // ps
+
+    def decode_plan(b, hq, hkv, max_pages, **kw):
+        return plan_lib.plan_attention(
+            (b, hq, hkv, 1, max_pages * ps, d), phase=plan_lib.DECODE,
+            kv_layout=plan_lib.PAGED, page_size=ps, backend="cpu", **kw)
+
+    # The two benchmark cells' decode shapes: 256 and 120 pages of 16.
+    session, chat = decode_plan(16, 32, 32, 256), decode_plan(32, 32, 8, 120)
+    assert session.pages_per_block == chat.pages_per_block == target
+    assert 120 % chat.pages_per_block  # a dynamic tail, not a divisor
+    # Rows and heads do not enter; a short table is one block.
+    assert decode_plan(1, 8, 2, 256).pages_per_block == target
+    assert decode_plan(4, 8, 2, 6).pages_per_block == 6
+    # Under a small VMEM budget the block halves until it fits.
+    budget = plan_lib.paged_decode_vmem_bytes(4 * ps, d, 2)
+    small = decode_plan(16, 32, 32, 256, vmem_budget_bytes=budget)
+    assert small.pages_per_block == 4
+    assert plan_lib.paged_decode_vmem_bytes(
+        small.pages_per_block * ps, d, 2) <= budget
+    # Every split-K range keeps a block; dense and prefill plans carry 1.
+    assert plan_lib.decode_pages_per_block(
+        256, ps, d, 2, num_splits=32, vmem_budget_bytes=1 << 30) == 8
+    assert plan_lib.plan_attention(
+        (2, 8, 2, 1, 2048, 64), phase=plan_lib.DECODE,
+        backend="cpu").pages_per_block == 1
+
+
+def test_engine_stats_report_the_decode_block():
+    """``decode_plan()`` records what the paged decode kernel runs with,
+    and ``stats()`` reports it beside the split count."""
+    from repro.models import transformer
+    from repro.serving import LLMEngine
+
+    cfg = registry.get_smoke_config("llama3-8b")
+    params = transformer.init_model(jax.random.PRNGKey(0), cfg)
+    paged = LLMEngine(cfg, params, kv_layout="paged", num_pages=32,
+                      page_size=16, max_batch=2, max_pages_per_seq=4,
+                      prompt_buckets=(16, 32))
+    plan = paged.backend.decode_plan()
+    assert plan.pages_per_block == 4   # the whole 4-page table
+    stats = paged.stats()
+    assert (stats.pages_per_block, stats.decode_splits) == (
+        plan.pages_per_block, plan.num_splits)
+    assert "decode blocks of 4 pages x 1 splits" in stats.summary()
+    dense = LLMEngine(cfg, params, kv_layout="dense", max_batch=2,
+                      cache_len=64, prompt_buckets=(16,))
+    assert dense.stats().pages_per_block == 0

@@ -19,6 +19,7 @@ try:  # dev-only dep (requirements-dev.txt); the exhaustive sweep below
 except ImportError:
     HAVE_HYPOTHESIS = False
 
+from repro.kernels import decode_common
 from repro.kernels.decode_common import chunk_relevant
 
 
@@ -99,3 +100,16 @@ def test_both_decode_kernels_share_the_predicate():
     from repro.analysis import run_rules
 
     assert run_rules(rules=["decode-relevance-shared"]) == []
+
+
+@pytest.mark.parametrize("block_len", [16, 48, 256])
+@pytest.mark.parametrize("window", [None, 1, 37, 4096])
+def test_live_block_range_is_the_relevant_blocks(block_len, window):
+    """The paged walk's ``[first, last)`` is exactly the set of blocks the
+    relevance predicate admits, for every length up to a few blocks."""
+    for length in range(0, 5 * block_len + 3):
+        first, last = (int(x) for x in decode_common.live_block_range(
+            length, window, block_len))
+        relevant = [i for i in range(8) if decode_common.chunk_relevant(
+            i * block_len, block_len, length, window)]
+        assert list(range(first, last)) == relevant

@@ -14,6 +14,8 @@ from repro.kernels import ops, ref
 from repro.kernels.decode_attention import flash_decode
 from repro.kernels.paged_decode_attention import paged_flash_decode
 
+from paged_cases import mk_edge_batch
+
 
 def mk_paged(b, hq, hkv, d, ps, max_pages, seed=0, num_pages=None,
              shared_pages=0, dtype=jnp.float32):
@@ -127,3 +129,42 @@ def test_page_size_must_be_sublane_multiple():
     with pytest.raises(ValueError):
         paged_flash_decode(q, kp, kp, pt, jnp.asarray([5], jnp.int32),
                            interpret=True)
+
+
+# --- the block walk ------------------------------------------------------------
+
+# 3 divides neither the 8-page table nor a power of two: the tail block
+# overhangs the table.
+BLOCKS = [1, 3, 4, 8]
+HEADS = [(8, 2), (4, 1), (4, 4)]   # GQA, MQA, MHA
+
+
+@pytest.mark.parametrize("pages_per_block", BLOCKS)
+@pytest.mark.parametrize("hq,hkv", HEADS)
+@pytest.mark.parametrize("window", [None, 37])
+def test_block_walk_vs_oracle(pages_per_block, hq, hkv, window):
+    """Lengths on every block edge in one batch, against the oracle; the
+    window of 37 puts its left edge inside a page and inside a block."""
+    q, kp, vp, pt, lengths = mk_edge_batch(hq, hkv, 32, pages_per_block)
+    o = paged_flash_decode(q, kp, vp, pt, lengths, window=window,
+                           pages_per_block=pages_per_block, interpret=True)
+    o_ref = ref.paged_decode_attention(q, kp, vp, pt, lengths, window=window)
+    assert jnp.max(jnp.abs(o - o_ref)) < 2e-5
+    assert jnp.max(jnp.abs(o[0])) == 0.0          # the length-0 row
+
+
+@pytest.mark.parametrize("pages_per_block", [1, 3, 8])
+def test_block_walk_ignores_nan_in_dead_pages(pages_per_block):
+    """NaN in the null page and in every page no row references: the walk
+    never copies them, and masks what its block buffer holds past a row's
+    live pages, so the output is finite and equal to the clean run."""
+    q, kp, vp, pt, lengths = mk_edge_batch(8, 2, 32, pages_per_block, seed=5)
+    clean = paged_flash_decode(q, kp, vp, pt, lengths,
+                               pages_per_block=pages_per_block, interpret=True)
+    referenced = set(np.asarray(pt)[np.asarray(pt) > 0].tolist())
+    dead = np.asarray([p not in referenced for p in range(kp.shape[1])])
+    poison = jnp.where(jnp.asarray(dead)[None, :, None, None], jnp.nan, 0.0)
+    o = paged_flash_decode(q, kp + poison, vp + poison, pt, lengths,
+                           pages_per_block=pages_per_block, interpret=True)
+    assert bool(jnp.all(jnp.isfinite(o)))
+    assert jnp.max(jnp.abs(o - clean)) == 0.0

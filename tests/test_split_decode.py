@@ -20,6 +20,8 @@ from repro.kernels import plan as plan_lib
 from repro.kernels.decode_attention import flash_decode
 from repro.kernels.paged_decode_attention import paged_flash_decode
 
+from paged_cases import mk_edge_batch
+
 
 def mk(b, hq, hkv, smax, d, seed=0, dtype=jnp.float32):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
@@ -134,6 +136,22 @@ def test_paged_split_parity(b, hq, hkv, d, num_splits, window, softcap):
     o_ref = ref.paged_decode_attention(q, kp, vp, pt, lengths, **kw)
     assert jnp.max(jnp.abs(o - o_one)) < 2e-5
     assert jnp.max(jnp.abs(o - o_ref)) < 2e-5
+
+
+@pytest.mark.parametrize("pages_per_block", [1, 2, 3])
+@pytest.mark.parametrize("hq,hkv", [(8, 2), (4, 1), (4, 4)])
+@pytest.mark.parametrize("window", [None, 37])
+def test_paged_split_block_walk_vs_oracle(pages_per_block, hq, hkv, window):
+    """The block-walk edge cases (lengths 0, 1, bk - 1, bk, bk + 1 and the
+    whole table in one batch) through three split-K ranges of blocks: the
+    splits run the same walk as one pass, cut to their ranges."""
+    q, kp, vp, pt, lengths = mk_edge_batch(hq, hkv, 32, pages_per_block)
+    o = paged_flash_decode(q, kp, vp, pt, lengths, window=window,
+                           num_splits=3, pages_per_block=pages_per_block,
+                           interpret=True)
+    o_ref = ref.paged_decode_attention(q, kp, vp, pt, lengths, window=window)
+    assert jnp.max(jnp.abs(o - o_ref)) < 2e-5
+    assert jnp.max(jnp.abs(o[0])) == 0.0
 
 
 def test_paged_split_length_zero_row_is_zero():

@@ -41,6 +41,8 @@ from repro.models import transformer
 from repro.serving import LLMEngine, Request, SamplingParams
 from repro.serving.scheduler import SchedulerStats
 
+from paged_cases import mk_edge_batch
+
 #: Worst-case |dequant(quant(x)) - x| through attention, per format.
 QTOL = {"int8": 0.03, "fp8": 0.12}
 
@@ -123,6 +125,24 @@ def test_quantized_decode_split_k_parity(kv_dtype):
                             k_scales=ksc, v_scales=vsc, num_splits=4,
                             interpret=True)
     assert jnp.max(jnp.abs(o1 - o4)) < 2e-5
+
+
+@pytest.mark.parametrize("pages_per_block", [1, 3, 8])
+@pytest.mark.parametrize("hq,hkv", [(8, 2), (4, 1), (4, 4)])
+@pytest.mark.parametrize("window", [None, 37])
+def test_quantized_block_walk_parity(pages_per_block, hq, hkv, window):
+    """The block-walk edge cases over int8 pools: each page of a block is
+    dequantized with its own (head, page) scale."""
+    q, kp, vp, pt, lengths = mk_edge_batch(hq, hkv, 32, pages_per_block)
+    kq, ksc = quant.quantize_pages(kp, "int8")
+    vq, vsc = quant.quantize_pages(vp, "int8")
+    o = paged_flash_decode(q, kq, vq, pt, lengths, window=window,
+                           pages_per_block=pages_per_block,
+                           k_scales=ksc, v_scales=vsc, interpret=True)
+    o_qref = ref.paged_decode_attention(q, kq, vq, pt, lengths, window=window,
+                                        k_scales=ksc, v_scales=vsc)
+    assert jnp.max(jnp.abs(o - o_qref)) < 2e-5
+    assert jnp.max(jnp.abs(o[0])) == 0.0
 
 
 def test_scales_both_or_neither_everywhere():

@@ -139,6 +139,46 @@ def test_paged_decode_compiles(one_chip, num_splits, kv_dtype):
     _compile(decode, *_paged_args(one_chip, kv_dtype))
 
 
+#: The benchmark cells' decode shapes (``bench/``): rows, query heads, KV
+#: heads, table pages, pool pages, window; head 128, pages of 16.
+CELL_DECODE = {
+    "deepseek-llm-7b.session": (16, 32, 32, 256, 16 * 256 + 1 + 256, None),
+    "mistral-7b.chat": (32, 32, 8, 120, 32 * 120 + 1 + 256, 4096),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_DECODE))
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_decode_compiles_at_cell_shapes(one_chip, v5e_kind, cell,
+                                              kv_dtype):
+    """The decode plan's block walk at both cells' widths: a (head, page)
+    scale table of the session pool would not fit SMEM whole."""
+    b, hq, hkv, mp, num_pages, window = CELL_DECODE[cell]
+    plan = plan_lib.plan_attention(
+        (b, hq, hkv, 1, mp * PAGE, 128), phase=plan_lib.DECODE,
+        kv_layout=plan_lib.PAGED, page_size=PAGE, window=window,
+        backend="tpu", kv_dtype="int8" if kv_dtype == "int8" else "fp32",
+    )
+    pages = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
+    args = [
+        _sds(one_chip, (b, hq, 128)),
+        _sds(one_chip, (hkv, num_pages, PAGE, 128), pages),
+        _sds(one_chip, (hkv, num_pages, PAGE, 128), pages),
+        _sds(one_chip, (b, mp), jnp.int32),
+        _sds(one_chip, (b,), jnp.int32),
+    ]
+    if kv_dtype == "int8":
+        args += [_sds(one_chip, (hkv, num_pages), jnp.float32)] * 2
+
+    def decode(q, kp, vp, pt, ln, ks=None, vs=None):
+        return paged_flash_decode(
+            q, kp, vp, pt, ln, window=window, num_splits=plan.num_splits,
+            pages_per_block=plan.pages_per_block, k_scales=ks, v_scales=vs,
+        )
+
+    _compile(decode, *args)
+
+
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 def test_paged_prefill_compiles(one_chip, kv_dtype):
     tail, prefix_pages = 128, 8
