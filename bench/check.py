@@ -57,19 +57,22 @@ def _inputs(r: Record):
     return tokens, pos, served
 
 
-def readings(params, conf: Dict, picked: Sequence[Record], control: bool = False
+def readings(params, cell, picked: Sequence[Record], control: bool = False
              ) -> Tuple[Dict[str, float], Optional[Dict[str, float]]]:
-    """The numbers compared for the served tokens over ``picked``, and with
-    ``control`` the same numbers for the float8 reference's first choices
-    at the same positions (``None`` without it)."""
+    """The numbers compared for the served tokens over ``picked``, by the
+    reference of ``cell``'s family, and with ``control`` the same numbers
+    for the float8 reference's first choices at the same positions
+    (``None`` without it)."""
+    conf, family = cell.conf, cell.family
     gap, ctrl, n = 0.0, 0.0, 0
     for r in picked:
         tokens, pos, served = _inputs(r)
-        ref = reference.logits(params, conf, tokens, pos)
+        ref = reference.logits(params, conf, tokens, pos, family=family)
         gap = max(gap, reference.widest_gap(ref, served))
         n += len(served)
         if control:
-            low = reference.logits(params, conf, tokens, pos, quantized=True)
+            low = reference.logits(params, conf, tokens, pos, quantized=True,
+                                   family=family)
             ctrl = max(ctrl, reference.widest_gap(ref, low.argmax(axis=1)))
     out = {"max_logit_gap": gap, "tokens_compared": float(n)}
     if not control:

@@ -14,7 +14,7 @@ from typing import Dict, List, Optional, Tuple
 
 from bench.driver import Window
 from bench.trace_reduce import Trace
-from bench.work import Shape
+from bench.work import Stack
 
 
 @dataclasses.dataclass
@@ -24,7 +24,7 @@ class Ctx:
     max_batch: int
     page_size: int
     num_pages: int
-    shape: Shape
+    shape: Stack                    # the model's layer groups (``work.py``)
     peak: Dict
     spans: List                     # program tracer SpanRecords in the window
     events: Dict[int, List]         # uid -> [(event, t, args)]

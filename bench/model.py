@@ -1,9 +1,11 @@
 """A configuration file turned into the program's model and its weights.
 
-The weights are the benchmark's, not the program's: one jitted call makes
-every leaf on the device from ``--seed``, in the structure and type the
-program serves (``transformer.init_model``'s tree), and the plain reference
-reads the same arrays. Nothing the program initialises is used.
+The model and the widths come from the configuration's family
+(``bench/families/``). The weights are the benchmark's, not the program's:
+one jitted call makes every leaf on the device from ``--seed``, in the
+structure and type the program serves (``transformer.init_model``'s tree),
+and the plain reference reads the same arrays. Nothing the program
+initialises is used.
 """
 
 from __future__ import annotations
@@ -13,48 +15,22 @@ from typing import Dict
 
 import numpy as np
 
-from bench.work import Shape
+from bench import registry
+from bench.work import Stack
 
 #: Standard deviation of the RMSNorm gains around 1 (stored as 1 + scale).
 NORM_SCALE_STD = 0.1
 
 
 def model_config(conf: Dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import LayerSpec, ModelConfig
-
-    head_dim = conf.get("head_dim") or conf["hidden_size"] // conf["num_attention_heads"]
-    window = conf.get("sliding_window")
-    theta = float(conf["rope_theta"])
-    return ModelConfig(
-        name=conf["name"],
-        d_model=conf["hidden_size"],
-        n_layers=conf["num_hidden_layers"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"],
-        head_dim=head_dim,
-        d_ff=conf["intermediate_size"],
-        vocab=conf["vocab_size"],
-        layer_pattern=(LayerSpec(window=window, rope_theta=theta),),
-        rope_theta=theta,
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        max_seq_len=conf["max_position_embeddings"],
-        compute_dtype=conf["torch_dtype"],
-    )
+    """The program's ``ModelConfig`` for a configuration file, from the
+    family it names (``registry.load_family``)."""
+    return registry.load_family(conf).model_config(conf)
 
 
-def work_shape(conf: Dict) -> Shape:
-    """The widths ``work.py`` counts with."""
-    return Shape(
-        n_layers=conf["num_hidden_layers"],
-        d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf.get("head_dim") or conf["hidden_size"] // conf["num_attention_heads"],
-        d_ff=conf["intermediate_size"],
-        vocab=conf["vocab_size"],
-        window=conf.get("sliding_window"),
-    )
+def work_shape(conf: Dict) -> Stack:
+    """The widths ``work.py`` counts with, from the configuration's family."""
+    return registry.load_family(conf).work_stack(conf)
 
 
 def key_data(seed: int) -> np.ndarray:

@@ -1,13 +1,12 @@
-"""The plain reference: a pre-norm RoPE / SwiGLU decoder in float32.
+"""The plain reference: the entry point and the helpers a family's forward
+is written with.
 
-Written from the published description of the Llama / Mistral / DeepSeek-LLM
-family in straightforward ``jax.numpy``, with no kernel, cache or batching,
-and under ``jax.default_matmul_precision("highest")`` so that float32 matmuls
-stay float32 on a TPU. It imports nothing of the program. It reads the
-benchmark's weights by their names in the served tree: ``embed.table_vd``,
-``layers[0]`` (stacked over layers: ``ln1``, ``attn.{wq,wk,wv}_dm``,
-``attn.wo_md``, ``ln2``, ``mlp.{wi_gate,wi_up}_dm``, ``mlp.wo_md``),
-``ln_f`` and ``head.w_dm``.
+Each family (``bench/families/<name>.py``) writes its model's forward from
+the published description in straightforward ``jax.numpy`` float32, with no
+kernel, cache or batching, and imports nothing of the program. ``logits``
+pads and blocks the positions and runs that forward under
+``jax.default_matmul_precision("highest")``, so that float32 matmuls stay
+float32 on a TPU.
 
 Departures, shared with the configuration as run (see its file): RMSNorm
 multiplies by (1 + scale) with eps 1e-6; a sliding window W lets query i
@@ -20,12 +19,14 @@ the precision step below the bfloat16 the configuration serves in.
 
 from __future__ import annotations
 
-import functools
-from typing import Dict
+from types import ModuleType
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import registry
 
 EPS = 1e-6
 #: Query rows per attention block: bounds the score tensor at
@@ -98,60 +99,22 @@ def _attention(q, k, v, window, quantized):
     return out.reshape(s, hq, d)
 
 
-@functools.partial(jax.jit, static_argnames=("conf_key", "quantized"))
-def _forward(params, tokens, score_pos, conf_key, quantized):
-    conf = dict(conf_key)
-    hq, hkv, hd = conf["heads"], conf["kv_heads"], conf["head_dim"]
-    window, theta = conf["window"], conf["theta"]
-    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
-    x = f32(params["embed"]["table_vd"])[tokens]
-    pos = jnp.arange(tokens.shape[0])
-    s = tokens.shape[0]
-
-    def layer(x, p):
-        a = p["attn"]
-        h = _rmsnorm(x, f32(p["ln1"]["scale_r"]))
-        q = _mm(h, f32(a["wq_dm"]), quantized).reshape(s, hq, hd)
-        k = _mm(h, f32(a["wk_dm"]), quantized).reshape(s, hkv, hd)
-        v = _mm(h, f32(a["wv_dm"]), quantized).reshape(s, hkv, hd)
-        q, k = _rope(q, pos, theta), _rope(k, pos, theta)
-        o = _attention(q, k, v, window, quantized).reshape(s, hq * hd)
-        x = x + _mm(o, f32(a["wo_md"]), quantized)
-        m = p["mlp"]
-        h = _rmsnorm(x, f32(p["ln2"]["scale_r"]))
-        g = jax.nn.silu(_mm(h, f32(m["wi_gate_dm"]), quantized))
-        u = _mm(h, f32(m["wi_up_dm"]), quantized)
-        return x + _mm(g * u, f32(m["wo_md"]), quantized), None
-
-    x, _ = jax.lax.scan(layer, x, params["layers"][0])
-    x = _rmsnorm(x[score_pos], f32(params["ln_f"]["scale_r"]))
-    return _mm(x, f32(params["head"]["w_dm"]), quantized)
-
-
-def conf_key(conf: Dict):
-    """The hashable slice of a configuration file the reference needs."""
-    heads = conf["num_attention_heads"]
-    return tuple(sorted({
-        "heads": heads,
-        "kv_heads": conf["num_key_value_heads"],
-        "head_dim": conf.get("head_dim") or conf["hidden_size"] // heads,
-        "window": conf.get("sliding_window"),
-        "theta": float(conf["rope_theta"]),
-    }.items()))
-
-
 def logits(params, conf: Dict, tokens: np.ndarray, score_pos: np.ndarray,
-           quantized: bool = False) -> np.ndarray:
+           quantized: bool = False, family: Optional[ModuleType] = None
+           ) -> np.ndarray:
     """Reference logits (len(score_pos), vocab) of ``tokens`` at
-    ``score_pos``: the logits each position gives for the token after it."""
+    ``score_pos``: the logits each position gives for the token after it,
+    from ``family`` (by default the one ``conf`` names in this checkout)."""
+    if family is None:
+        family = registry.load_family(conf)
     n, m = len(tokens), len(score_pos)
     padded = np.zeros((-(-n // PAD) * PAD,), np.int32)
     padded[:n] = tokens
     pos = np.full((-(-m // BLOCK) * BLOCK,), score_pos[-1], np.int32)
     pos[:m] = score_pos
     with jax.default_matmul_precision("highest"):
-        out = _forward(params, jnp.asarray(padded), jnp.asarray(pos),
-                       conf_key(conf), quantized)
+        out = family.forward(params, jnp.asarray(padded), jnp.asarray(pos),
+                             conf, quantized)
     return np.asarray(out)[:m]
 
 

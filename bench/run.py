@@ -214,7 +214,7 @@ def run_cell(cell, *, seed: int, seconds: float, trace: bool, device: dict,
     from repro.obs import Telemetry
 
     device = dict(device)
-    cfg = model.model_config(cell.conf)
+    cfg = cell.family.model_config(cell.conf)
     params = model.make_params(cfg, seed)
     jax.block_until_ready(params)
     telemetry = Telemetry.create() if trace else None
@@ -281,7 +281,7 @@ def run_cell(cell, *, seed: int, seconds: float, trace: bool, device: dict,
         ctx = context.Ctx(
             window=window, t0=t0, max_batch=mix["max_batch"],
             page_size=cell.conf["engine"]["page_size"], num_pages=num_pages,
-            shape=model.work_shape(cell.conf), peak=work.peaks(device["kind"]),
+            shape=cell.family.work_stack(cell.conf), peak=work.peaks(device["kind"]),
             spans=list(telemetry.tracer.spans),
             events=dict(telemetry.tracer.requests),
             prefix_before=prefix_before, prefix_after=prefix_after,
@@ -309,7 +309,7 @@ def run_cell(cell, *, seed: int, seconds: float, trace: bool, device: dict,
     picked = check.sample(window.records, seed)
     engine = backend = None
     gc.collect()
-    read, ctrl = (check.readings(params, cell.conf, picked, control)
+    read, ctrl = (check.readings(params, cell, picked, control)
                   if picked else ({}, None))
     checks = check.decide(read, cell.limits)
     result["correct"] = check.passed(checks)

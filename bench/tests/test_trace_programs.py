@@ -4,6 +4,7 @@ program before its device programs were named, and one after."""
 
 import gzip
 import pathlib
+import time
 
 import pytest
 
@@ -85,3 +86,21 @@ def test_engine_programs_and_spans_on_a_chip_trace():
             "decode.launch", "decode.sync", "decode.replay"} <= spans
     scan = trace_programs.module_s(t, modules, "jit_decode_scan")
     assert 0 < trace_programs.module_ops_s(t, modules, "jit_decode_scan", r"copy[.\d]*$") < scan
+
+
+def test_another_runs_unfinished_profile_is_passed_over(tmp_path, monkeypatch, trace):
+    # A traced run beside this one is still writing its profile: the
+    # newest directory holds a cut-off file, which ``of`` cannot parse.
+    monkeypatch.setattr(trace_programs.tempfile, "tempdir", str(tmp_path))
+    whole = gzip.decompress(DATA.read_bytes())
+    for d, data in (("bench-trace-mine", whole), ("bench-trace-other", whole[:4096])):
+        out = tmp_path / d / "plugins" / "profile" / "run"
+        out.mkdir(parents=True)
+        (out / "host.xplane.pb").write_bytes(data)
+        time.sleep(0.05)
+    with pytest.raises(RuntimeError):
+        trace_programs.read(tmp_path / "bench-trace-other" / "plugins" / "profile"
+                            / "run" / "host.xplane.pb")
+    monkeypatch.setattr(trace_programs, "_seen", {})
+    names = {e.name for e in trace_programs.of(trace)["/device:TPU:0"]}
+    assert "jit_run" in names

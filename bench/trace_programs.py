@@ -75,13 +75,22 @@ def of(trace: Optional[Trace]) -> Modules:
         return {}
     if trace.window not in _seen:
         found: Modules = {}
-        dirs = sorted(pathlib.Path(tempfile.gettempdir()).glob(TRACE_DIRS),
-                      key=lambda d: d.stat().st_mtime, reverse=True)
-        for d in dirs:
-            files = sorted(d.rglob("*.xplane.pb"))
-            if not files:
+        dirs = []
+        for d in pathlib.Path(tempfile.gettempdir()).glob(TRACE_DIRS):
+            try:
+                dirs.append((d.stat().st_mtime, d))
+            except FileNotFoundError:   # another run's, removed meanwhile
                 continue
-            window, modules = read(files[-1])
+        for _, d in sorted(dirs, reverse=True):
+            try:
+                files = sorted(d.rglob("*.xplane.pb"))
+                if not files:
+                    continue
+                window, modules = read(files[-1])
+            except (OSError, RuntimeError):
+                # Another run's profile, still being written or removed:
+                # this run's own was complete before its readers ran.
+                continue
             if window == trace.window:
                 found = modules
                 break

@@ -14,7 +14,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import pathlib
-from typing import Iterable, Optional, Sequence, Tuple
+from typing import Iterable, Optional, Sequence, Tuple, Union
 
 PEAKS_FILE = pathlib.Path(__file__).resolve().parent / "peaks.json"
 
@@ -33,7 +33,9 @@ def peaks(device_kind: str, path: pathlib.Path = PEAKS_FILE) -> dict:
 
 @dataclasses.dataclass(frozen=True)
 class Shape:
-    """The widths a decoder layer's work depends on."""
+    """The widths a decoder layer's work depends on. A family whose layers
+    compute differently (sparse experts, latent attention) subclasses it in
+    its own file and overrides the counts below."""
 
     n_layers: int
     d_model: int
@@ -50,45 +52,76 @@ class Shape:
         """KV tokens one query at context ``length`` (itself included) reads."""
         return length if self.window is None else min(length, self.window)
 
+    def decode_attn_call(self, lengths: Sequence[int]) -> Tuple[float, float]:
+        """(FLOPs, bytes) of ONE layer's decode attention over a batch whose
+        live rows hold ``lengths`` KV tokens each (the new token included)."""
+        hq, hkv, hd = self.n_heads, self.n_kv_heads, self.head_dim
+        flops = 0.0
+        nbytes = 0.0
+        for n in lengths:
+            live = self.visible(int(n))
+            flops += 4.0 * hq * live * hd
+            nbytes += 2.0 * hkv * live * hd * self.kv_bytes
+            nbytes += 2.0 * hq * hd * self.act_bytes
+        return flops, nbytes
+
+    def prefill_pairs(self, prefix: int, tail: int) -> int:
+        """(query, key) pairs a tail of ``tail`` tokens after ``prefix``
+        cached tokens attends to, causal and windowed."""
+        if self.window is None:
+            return tail * prefix + tail * (tail + 1) // 2
+        return sum(self.visible(prefix + i + 1) for i in range(tail))
+
+    def prefill_attn_call(self, prefix: int, tail: int) -> Tuple[float, float]:
+        """(FLOPs, bytes) of ONE layer's prefill (``prefix`` == 0) or extend
+        attention for one sequence: q and output of the tail, K/V of the
+        tail and of the part of the prefix that some tail query can see."""
+        hq, hkv, hd = self.n_heads, self.n_kv_heads, self.head_dim
+        flops = 4.0 * hq * hd * self.prefill_pairs(prefix, tail)
+        seen_prefix = prefix if self.window is None else min(prefix, self.window - 1)
+        nbytes = 2.0 * tail * hq * hd * self.act_bytes
+        nbytes += 2.0 * (seen_prefix + tail) * hkv * hd * self.kv_bytes
+        return flops, nbytes
+
+    def matmul_params(self) -> int:
+        """Weights one token multiplies through in one layer (q/k/v/o +
+        SwiGLU)."""
+        d, hq, hkv, hd = self.d_model, self.n_heads, self.n_kv_heads, self.head_dim
+        return d * hd * (hq + 2 * hkv) + hq * hd * d + 3 * d * self.d_ff
+
+
+@dataclasses.dataclass(frozen=True)
+class Stack:
+    """A model's layers as groups of alike layers: ``(count, layer)`` each,
+    ``layer`` a ``Shape`` (or a family's subclass) whose ``n_layers`` the
+    totals below do not read."""
+
+    vocab: int
+    d_model: int
+    groups: Tuple[Tuple[int, Shape], ...]
+
+
+def as_stack(shape: Union[Shape, Stack]) -> Stack:
+    """A plain ``Shape`` reads as one group of its ``n_layers``."""
+    if isinstance(shape, Stack):
+        return shape
+    return Stack(shape.vocab, shape.d_model, ((shape.n_layers, shape),))
+
 
 def decode_attn_call(shape: Shape, lengths: Sequence[int]) -> Tuple[float, float]:
-    """(FLOPs, bytes) of ONE layer's decode attention over a batch whose live
-    rows hold ``lengths`` KV tokens each (the new token included)."""
-    hq, hkv, hd = shape.n_heads, shape.n_kv_heads, shape.head_dim
-    flops = 0.0
-    nbytes = 0.0
-    for n in lengths:
-        live = shape.visible(int(n))
-        flops += 4.0 * hq * live * hd
-        nbytes += 2.0 * hkv * live * hd * shape.kv_bytes
-        nbytes += 2.0 * hq * hd * shape.act_bytes
-    return flops, nbytes
+    return shape.decode_attn_call(lengths)
 
 
 def prefill_pairs(shape: Shape, prefix: int, tail: int) -> int:
-    """(query, key) pairs a tail of ``tail`` tokens after ``prefix`` cached
-    tokens attends to, causal and windowed."""
-    if shape.window is None:
-        return tail * prefix + tail * (tail + 1) // 2
-    return sum(shape.visible(prefix + i + 1) for i in range(tail))
+    return shape.prefill_pairs(prefix, tail)
 
 
 def prefill_attn_call(shape: Shape, prefix: int, tail: int) -> Tuple[float, float]:
-    """(FLOPs, bytes) of ONE layer's prefill (``prefix`` == 0) or extend
-    attention for one sequence: q and output of the tail, K/V of the tail and
-    of the part of the prefix that some tail query can see."""
-    hq, hkv, hd = shape.n_heads, shape.n_kv_heads, shape.head_dim
-    flops = 4.0 * hq * hd * prefill_pairs(shape, prefix, tail)
-    seen_prefix = prefix if shape.window is None else min(prefix, shape.window - 1)
-    nbytes = 2.0 * tail * hq * hd * shape.act_bytes
-    nbytes += 2.0 * (seen_prefix + tail) * hkv * hd * shape.kv_bytes
-    return flops, nbytes
+    return shape.prefill_attn_call(prefix, tail)
 
 
 def layer_matmul_params(shape: Shape) -> int:
-    """Weights one token multiplies through in one layer (q/k/v/o + SwiGLU)."""
-    d, hq, hkv, hd = shape.d_model, shape.n_heads, shape.n_kv_heads, shape.head_dim
-    return d * hd * (hq + 2 * hkv) + hq * hd * d + 3 * d * shape.d_ff
+    return shape.matmul_params()
 
 
 def least_seconds(flops: float, nbytes: float, peak: dict) -> float:
@@ -96,40 +129,50 @@ def least_seconds(flops: float, nbytes: float, peak: dict) -> float:
     return max(flops / peak["bf16_flops"], nbytes / peak["hbm_bytes_per_s"])
 
 
-def decode_least_seconds(shape: Shape, ticks: Iterable[Sequence[int]],
-                         peak: dict) -> float:
+def decode_least_seconds(shape: Union[Shape, Stack],
+                         ticks: Iterable[Sequence[int]], peak: dict) -> float:
     """Least time of every decode-attention call of ``ticks`` (one entry per
     decode tick: the live rows' lengths), over all layers."""
-    total = 0.0
-    for lengths in ticks:
-        if len(lengths):
-            total += least_seconds(*decode_attn_call(shape, lengths), peak)
-    return total * shape.n_layers
+    ticks = [lengths for lengths in ticks if len(lengths)]
+    out = 0.0
+    for count, layer in as_stack(shape).groups:
+        total = 0.0
+        for lengths in ticks:
+            total += least_seconds(*layer.decode_attn_call(lengths), peak)
+        out += total * count
+    return out
 
 
-def prefill_least_seconds(shape: Shape, prefills: Iterable[Tuple[int, int]],
-                          peak: dict) -> float:
+def prefill_least_seconds(shape: Union[Shape, Stack],
+                          prefills: Iterable[Tuple[int, int]], peak: dict) -> float:
     """Least time of every prefill/extend attention call, over all layers.
     ``prefills``: (prefix tokens, tail tokens) per prefilled sequence."""
-    total = 0.0
-    for prefix, tail in prefills:
-        total += least_seconds(*prefill_attn_call(shape, prefix, tail), peak)
-    return total * shape.n_layers
+    prefills = list(prefills)
+    out = 0.0
+    for count, layer in as_stack(shape).groups:
+        total = 0.0
+        for prefix, tail in prefills:
+            total += least_seconds(*layer.prefill_attn_call(prefix, tail), peak)
+        out += total * count
+    return out
 
 
-def model_flops(shape: Shape, prefills: Sequence[Tuple[int, int]],
+def model_flops(shape: Union[Shape, Stack], prefills: Sequence[Tuple[int, int]],
                 ticks: Sequence[Sequence[int]]) -> float:
     """FLOPs the model needs for these prefills and decode ticks: two per
     weight per token through the layers, the head once per prefill (only the
     last position's logits are needed) and once per decoded token, plus
     attention. Embedding lookups are gathers and count nothing."""
-    per_tok = 2.0 * layer_matmul_params(shape) * shape.n_layers
-    head = 2.0 * shape.d_model * shape.vocab
+    stack = as_stack(shape)
+    per_tok = sum(2.0 * layer.matmul_params() * count for count, layer in stack.groups)
+    head = 2.0 * stack.d_model * stack.vocab
     flops = 0.0
     for prefix, tail in prefills:
         flops += tail * per_tok + head
-        flops += shape.n_layers * prefill_attn_call(shape, prefix, tail)[0]
+        flops += sum(count * layer.prefill_attn_call(prefix, tail)[0]
+                     for count, layer in stack.groups)
     for lengths in ticks:
         flops += len(lengths) * (per_tok + head)
-        flops += shape.n_layers * decode_attn_call(shape, lengths)[0]
+        flops += sum(count * layer.decode_attn_call(lengths)[0]
+                     for count, layer in stack.groups)
     return flops
