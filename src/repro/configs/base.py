@@ -30,12 +30,59 @@ class LayerSpec:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int = 8
+    num_experts: int = 8         # experts the router scores
     top_k: int = 2
     d_ff: int = 0                # per-expert hidden dim
     capacity_factor: float = 1.25
     num_shared_experts: int = 0
     router_z_loss: float = 1e-3
+    norm_topk_prob: bool = True  # renormalise the top-k gates (Mixtral)
+    routed_scaling_factor: float = 1.0
+    # The experts this device holds: [held_start, held_start + num_held)
+    # of the router's ``num_experts`` (expert parallelism). None: all.
+    held_start: int = 0
+    num_held: Optional[int] = None
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights this device holds."""
+        return self.num_experts if self.num_held is None else self.num_held
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (DeepSeek-V2 ``rope_scaling``, type "yarn")."""
+
+    factor: float = 1.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2 section 2.1), without query
+    compression: every query head is ``qk_nope_head_dim`` + ``qk_rope_head_dim``
+    wide; keys and values come from one ``kv_lora_rank`` latent per token
+    (RMS-normed) plus one shared rope key. The paged cache holds that latent
+    and the roped key, ``kv_lora_rank + qk_rope_head_dim`` values a token."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 10000.0
+    yarn: Optional[YarnConfig] = None
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,26 +140,38 @@ class ModelConfig:
     # training
     z_loss: float = 1e-4
 
+    #: Fields of :class:`LatentModelConfig`; a plain stack has neither.
+    lead_pattern = ()
+    mla = None
+
     def pattern_for_depth(self) -> Tuple[Tuple[LayerSpec, ...], Tuple[LayerSpec, ...]]:
         """(scanned periods pattern, remainder layers)."""
         p = len(self.layer_pattern)
-        n_periods = self.n_layers // p
-        rem = self.n_layers - n_periods * p
+        n_periods = self.n_periods
+        rem = self.n_layers - len(self.lead_pattern) - n_periods * p
         return self.layer_pattern, self.layer_pattern[:rem]
 
     @property
     def n_periods(self) -> int:
-        return self.n_layers // len(self.layer_pattern)
+        return (self.n_layers - len(self.lead_pattern)) // len(self.layer_pattern)
+
+    def all_layers(self) -> Tuple[LayerSpec, ...]:
+        """Every layer's spec, in order: lead, scanned periods, remainder."""
+        pattern, rem = self.pattern_for_depth()
+        return tuple(self.lead_pattern) + tuple(pattern) * self.n_periods + tuple(rem)
 
     def param_count(self) -> int:
         """Analytic parameter count (embedding included once if tied)."""
         d, h, hkv, hd, dff = (
             self.d_model, self.n_heads, self.n_kv_heads, self.head_dim, self.d_ff
         )
-        n_attn = n_mlp = n_moe = n_ssm = n_cross = 0
-        pattern, rem = self.pattern_for_depth()
-        layers = list(pattern) * self.n_periods + list(rem)
+        layers = self.all_layers()
         attn_p = d * hd * (h + 2 * hkv) + h * hd * d
+        if self.mla:
+            a = self.mla
+            attn_p = (d * h * a.qk_head_dim + d * a.latent_dim + a.kv_lora_rank
+                      + a.kv_lora_rank * h * (a.qk_nope_head_dim + a.v_head_dim)
+                      + h * a.v_head_dim * d)
         mlp_p = 3 * d * dff if dff else 0
         ssm_p = 0
         if self.ssm:
@@ -126,7 +185,7 @@ class ModelConfig:
         moe_p = 0
         if self.moe:
             m = self.moe
-            moe_p = d * m.num_experts + (m.num_experts + m.num_shared_experts) * 3 * d * m.d_ff
+            moe_p = d * m.num_experts + (m.held + m.num_shared_experts) * 3 * d * m.d_ff
         total = 0
         for spec in layers:
             if spec.kind in ("attn", "hybrid"):
@@ -152,14 +211,20 @@ class ModelConfig:
         if not self.moe:
             return self.param_count()
         m = self.moe
-        full_moe = (m.num_experts + m.num_shared_experts) * 3 * self.d_model * m.d_ff
+        full_moe = (m.held + m.num_shared_experts) * 3 * self.d_model * m.d_ff
         act_moe = (m.top_k + m.num_shared_experts) * 3 * self.d_model * m.d_ff
-        n_moe_layers = sum(
-            1 for s in (list(self.layer_pattern) * self.n_periods
-                        + list(self.pattern_for_depth()[1]))
-            if s.ffn == "moe"
-        )
+        n_moe_layers = sum(1 for s in self.all_layers() if s.ffn == "moe")
         return self.param_count() - n_moe_layers * (full_moe - act_moe)
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentModelConfig(ModelConfig):
+    """A stack with leading layers and latent attention (DeepSeek-V2)."""
+
+    # Layers applied once, before the scanned periods (the leading dense
+    # layers); counted in ``n_layers``.
+    lead_pattern: Tuple[LayerSpec, ...] = ()
+    mla: Optional[MLAConfig] = None  # latent attention on every attn layer
 
 
 @dataclasses.dataclass(frozen=True)

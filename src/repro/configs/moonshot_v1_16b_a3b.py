@@ -1,6 +1,13 @@
-"""moonshot-v1-16b-a3b (Moonlight-16B-A3B) [moe]: 48L d_model=2048 16H
-(GQA kv=16) vocab=163840, MoE 64 experts top-6 (expert d_ff=1408).
-[hf:moonshotai/Moonlight-16B-A3B]
+"""moonshot-v1-16b-a3b [moe]: a GQA stand-in at Moonlight-16B-A3B's widths,
+not its published attention. 48L d_model=2048 16H (kv=16) vocab=163840, MoE
+64 experts top-6 (expert d_ff=1408).
+
+The published Moonlight-16B-A3B (hf:moonshotai/Moonlight-16B-A3B) is 27
+layers of latent attention (MLA, DeepSeek-V3-style); this config keeps plain
+grouped-query heads, so it exercises the expert layer at those widths and
+nothing of MLA. The latent-attention layer now exists (``models/mla.py``,
+served for DeepSeek-V2-Lite through ``LatentModelConfig``); the shapes here
+are kept as they were.
 """
 
 from repro.configs.base import (

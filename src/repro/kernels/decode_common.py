@@ -70,7 +70,7 @@ def live_block_range(length, window, block_len: int):
 def accumulate_kv_block(
     q, k, v, acc_ref, m_ref, l_ref,
     *, scale, softcap, window, block_start, block_len: int, length,
-    k_scale=None, v_scale=None,
+    k_scale=None, v_scale=None, native: bool = False,
 ):
     """One online-softmax step over a KV block, shared by every decode
     kernel body (dense one-pass and split-K, and the paged kernel's one
@@ -94,10 +94,15 @@ def accumulate_kv_block(
     without widening a ``(block_len, D)`` scale; the 1-byte codes widen to
     fp32 here, in VMEM — HBM streamed only the codes. ``None`` leaves the
     pools untouched.
+
+    ``native`` keeps q, k and v in their own (narrower) dtype for both
+    products, which accumulate in float32; the probabilities are rounded
+    to v's dtype. Without it every operand is widened to float32 first.
     """
-    q = q.astype(jnp.float32)                # (G, D)
-    k = k.astype(jnp.float32)                # (block_len, D)
-    v = v.astype(jnp.float32)
+    if not native:
+        q = q.astype(jnp.float32)            # (G, D)
+        k = k.astype(jnp.float32)            # (block_len, D)
+        v = v.astype(jnp.float32)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
@@ -117,7 +122,11 @@ def accumulate_kv_block(
     if v_scale is not None:
         p = p * v_scale
     v_valid = _valid(block_start, (block_len, 1), 0, length, window)
-    v = jnp.where(v_valid, v, 0.0)
+    if native:
+        v = jnp.where(v_valid, v, jnp.zeros((), v.dtype))
+        p = p.astype(v.dtype)
+    else:
+        v = jnp.where(v_valid, v, 0.0)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )

@@ -49,8 +49,14 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import plan as plan_lib
 from repro.kernels import ref as ref_mod
 from repro.kernels.decode_attention import flash_decode
-from repro.kernels.paged_decode_attention import paged_flash_decode
-from repro.kernels.paged_prefill_attention import paged_flash_prefill
+from repro.kernels.paged_decode_attention import (
+    paged_flash_decode,
+    paged_latent_decode as _latent_decode_kernel,
+)
+from repro.kernels.paged_prefill_attention import (
+    paged_flash_prefill,
+    paged_latent_prefill as _latent_prefill_kernel,
+)
 from repro.kernels.flash_attention import (
     MappingConfig,
     flash_attention_fwd,
@@ -582,6 +588,78 @@ def paged_prefill_attention(
         )
 
     return _head_parallel(kernel, args, head_axes, 1)
+
+
+def _no_kernel_mesh(what: str) -> None:
+    if _KERNEL_MESH.get() is not None:
+        raise ValueError(f"{what} has one pool head and cannot be split over "
+                         "a serving mesh")
+
+
+def paged_latent_decode(
+    q: jnp.ndarray,
+    latent_pages: jnp.ndarray,
+    page_table: jnp.ndarray,
+    lengths: jnp.ndarray,
+    *,
+    v_dim: int,
+    scale: float,
+    plan: AttentionPlan,
+) -> jnp.ndarray:
+    """Latent-attention decode (``models.mla``). q: (B, H, W) absorbed
+    queries; latent_pages: (1, P, ps, W); returns (B, H, v_dim), the
+    probability-weighted latents. The pallas path is the named
+    ``paged_latent_decode`` kernel; xla/ref gathers the pages first."""
+    _no_kernel_mesh("latent attention")
+    if plan.impl in ("xla", "ref"):
+        kv = ref_mod.gather_pages(latent_pages, page_table)
+        return ref_mod.decode_attention(
+            q, kv, kv[..., :v_dim], lengths, scale=scale)
+    if plan.impl != "pallas":
+        raise ValueError(f"unknown impl {plan.impl!r}")
+    return _latent_decode_kernel(
+        q, latent_pages, page_table, lengths, v_dim=v_dim, scale=scale,
+        num_splits=plan.num_splits, pages_per_block=plan.pages_per_block,
+        interpret=plan.interpret,
+    )
+
+
+def paged_latent_prefill(
+    q: jnp.ndarray,
+    latent_pages: Optional[jnp.ndarray],
+    page_table: Optional[jnp.ndarray],
+    latent_tail: jnp.ndarray,
+    prefix_len: jnp.ndarray,
+    tail_len: jnp.ndarray,
+    *,
+    v_dim: int,
+    scale: float,
+    plan: AttentionPlan,
+) -> jnp.ndarray:
+    """Latent-attention prefill over a cached prefix (``models.mla``).
+    q: (B, St, H, W); latent_pages: (1, P, ps, W) or None (no prefix);
+    latent_tail: (B, St, W). Returns (B, St, H, v_dim). The pallas path is
+    the named ``paged_latent_prefill`` kernel; xla/ref gathers the prefix
+    and runs exact attention."""
+    _no_kernel_mesh("latent attention")
+    if plan.impl in ("xla", "ref"):
+        b, st, h, w = q.shape
+        if latent_pages is None:
+            latent_pages = jnp.zeros((1, 1, 8, w), latent_tail.dtype)
+            page_table = jnp.zeros((b, 0), jnp.int32)
+        tail = latent_tail[:, None]
+        o = ref_mod.paged_prefill_attention(
+            q.transpose(0, 2, 1, 3), latent_pages, latent_pages[..., :v_dim],
+            page_table, tail, tail[..., :v_dim], prefix_len, tail_len,
+            scale=scale,
+        )
+        return o.transpose(0, 2, 1, 3)
+    if plan.impl != "pallas":
+        raise ValueError(f"unknown impl {plan.impl!r}")
+    return _latent_prefill_kernel(
+        q, latent_pages, page_table, latent_tail, prefix_len, tail_len,
+        v_dim=v_dim, scale=scale, interpret=plan.interpret,
+    )
 
 
 # -----------------------------------------------------------------------------

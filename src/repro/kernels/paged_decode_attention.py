@@ -113,19 +113,32 @@ def _paged_decode_kernel(
     pt_ref, len_ref,            # scalar-prefetch: (B, max_pages), (B,)
     *refs,                      # q, k/v HBM, [ks, vs,] out(s), scratch
     scale, softcap, window, page_size, pages_per_block, blocks_per_split,
-    quantized, split,
+    quantized, split, v_dim=None,
 ):
     """One walk for both paths: a cell's ``[first, last)`` live blocks,
     cut to its split's range under split-K. One-pass emits the normalized
-    output; split-K emits the raw ``(acc, m, l)``."""
-    q_ref, k_hbm, v_hbm, *refs = refs
+    output; split-K emits the raw ``(acc, m, l)``.
+
+    ``v_dim`` set is the latent mode (:func:`paged_latent_decode`): one
+    pool, each block copied once, its first ``v_dim`` columns the values,
+    and both products on the pool's own dtype with float32 accumulation."""
+    latent = v_dim is not None
+    if latent:
+        q_ref, k_hbm, *refs = refs
+        v_hbm = None
+    else:
+        q_ref, k_hbm, v_hbm, *refs = refs
     if quantized:
         ks_ref, vs_ref, *refs = refs
     if split:
         acc_out, m_out, l_out, *refs = refs
     else:
         o_ref, *refs = refs
-    k_buf, v_buf, sem, acc_ref, m_ref, l_ref = refs
+    if latent:
+        k_buf, sem, acc_ref, m_ref, l_ref = refs
+        v_buf = None
+    else:
+        k_buf, v_buf, sem, acc_ref, m_ref, l_ref = refs
     d = k_buf.shape[-1]
     bk = pages_per_block * page_size
     b_idx = pl.program_id(0)
@@ -153,8 +166,9 @@ def _paged_decode_kernel(
         for j, (_, (head, page)) in enumerate(block_slots(block)):
             out.append(pltpu.make_async_copy(
                 k_hbm.at[head, page], k_buf.at[buf, j], sem.at[0, buf]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[head, page], v_buf.at[buf, j], sem.at[1, buf]))
+            if not latent:
+                out.append(pltpu.make_async_copy(
+                    v_hbm.at[head, page], v_buf.at[buf, j], sem.at[1, buf]))
         return out
 
     def start(block, buf):
@@ -193,14 +207,18 @@ def _paged_decode_kernel(
         if quantized:
             k_scale = page_scales(ks_ref, block)
             v_scale = page_scales(vs_ref, block)
+        q = q_ref[0, 0]
+        if latent:
+            k = k_buf[buf].reshape(bk, d)
+            v = k[:, :v_dim]
+        else:
+            k = k_buf[buf].astype(jnp.float32).reshape(bk, d)
+            v = v_buf[buf].astype(jnp.float32).reshape(bk, d)
         decode_common.accumulate_kv_block(
-            q_ref[0, 0],
-            k_buf[buf].astype(jnp.float32).reshape(bk, d),
-            v_buf[buf].astype(jnp.float32).reshape(bk, d),
-            acc_ref, m_ref, l_ref,
+            q, k, v, acc_ref, m_ref, l_ref,
             scale=scale, softcap=softcap, window=window,
             block_start=block * bk, block_len=bk, length=length,
-            k_scale=k_scale, v_scale=v_scale,
+            k_scale=k_scale, v_scale=v_scale, native=latent,
         )
         return carry
 
@@ -358,3 +376,119 @@ def paged_flash_decode(
     acc, m, l = fn(*prefetch, qg, k_pages, v_pages, *scale_rows)
     out = decode_common.combine_split_states(acc, m[..., :1], l[..., :1])
     return out[:, :, :group, :].reshape(b, hq, d).astype(q.dtype)
+
+
+def paged_latent_decode(
+    q: jnp.ndarray,
+    latent_pages: jnp.ndarray,
+    page_table: jnp.ndarray,
+    lengths: jnp.ndarray,
+    *,
+    v_dim: int,
+    scale: float,
+    num_splits: int = 1,
+    pages_per_block: Optional[int] = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Absorbed latent-attention decode (DeepSeek-V2 MLA) over a paged
+    latent pool: every query head attends one shared key per token.
+
+    q: (B, Hq, D) absorbed queries (latent part, then rope part);
+    latent_pages: (1, P, page_size, D), each token's normed latent in its
+    first ``v_dim`` columns and its roped key after them; page_table:
+    (B, max_pages); lengths: (B,). Returns (B, Hq, v_dim): each head's
+    probability-weighted latent.
+
+    The walk is :func:`paged_flash_decode`'s, on a grid of ``(B,)`` cells
+    (one pool head) with the ``Hq`` heads as the q block: each block of
+    pages is copied once, and the values are its first ``v_dim`` columns
+    in VMEM, so no second pool exists. ``num_splits > 1`` splits each
+    row's blocks as the paged kernel does.
+    """
+    b, hq, d = q.shape
+    hkv, _, page_size, dk = latent_pages.shape
+    if hkv != 1 or dk != d:
+        raise ValueError(f"latent pool {latent_pages.shape} for q {q.shape}")
+    if page_size % 8:
+        raise ValueError(f"page_size {page_size} must be a sublane multiple (8)")
+    max_pages = page_table.shape[1]
+    if pages_per_block is None:
+        from repro.kernels import plan as plan_lib
+
+        pages_per_block = plan_lib.decode_pages_per_block(
+            max_pages, page_size, d, latent_pages.dtype.itemsize,
+            latent=True,
+        )
+    pages_per_block = min(pages_per_block,
+                          -(-max_pages // max(1, num_splits)))
+    gp = max(8, -(-hq // 8) * 8)
+    qg = q.astype(latent_pages.dtype).reshape(b, 1, hq, d)
+    if gp != hq:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - hq), (0, 0)))
+    num_blocks = -(-max_pages // pages_per_block)
+    ranges = layout_lib.decode_split_ranges(num_blocks, num_splits)
+    num_splits = len(ranges)
+    split = num_splits > 1
+    prefetch = [page_table.astype(jnp.int32), lengths.astype(jnp.int32)]
+    in_specs = [
+        pl.BlockSpec((1, 1, gp, d), _q_index_map),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    if split:
+        grid = (b, 1, num_splits)
+        out_specs = [
+            pl.BlockSpec((1, 1, 1, gp, v_dim), _split_out_index_map),
+            pl.BlockSpec((1, 1, 1, gp, 128), _split_out_index_map),
+            pl.BlockSpec((1, 1, 1, gp, 128), _split_out_index_map),
+        ]
+        out_shape = [
+            jax.ShapeDtypeStruct((b, 1, num_splits, gp, v_dim), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, num_splits, gp, 128), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, num_splits, gp, 128), jnp.float32),
+        ]
+    else:
+        grid = (b, 1)
+        out_specs = pl.BlockSpec((1, 1, gp, v_dim), _q_index_map)
+        out_shape = jax.ShapeDtypeStruct((b, 1, gp, v_dim), q.dtype)
+    itemsize = latent_pages.dtype.itemsize
+    fn = pl.pallas_call(
+        functools.partial(
+            _paged_decode_kernel,
+            scale=scale, softcap=None, window=None,
+            page_size=page_size, pages_per_block=pages_per_block,
+            blocks_per_split=ranges[0][1] - ranges[0][0],
+            quantized=False, split=split, v_dim=v_dim,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((2, pages_per_block, page_size, d),
+                           latent_pages.dtype),
+                pltpu.SemaphoreType.DMA((1, 2)),
+                pltpu.VMEM((gp, v_dim), jnp.float32),
+                pltpu.VMEM((gp, 128), jnp.float32),
+                pltpu.VMEM((gp, 128), jnp.float32),
+            ],
+        ),
+        out_shape=out_shape,
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=(compat.PARALLEL,) * len(grid),
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=int(2.0 * b * hq * max_pages * page_size * (d + v_dim)),
+            bytes_accessed=int(b * (itemsize * max_pages * page_size * d
+                                    + itemsize * hq * (d + v_dim))),
+            transcendentals=int(b * hq * max_pages * page_size),
+        ),
+        interpret=interpret,
+        name="paged_latent_decode_split" if split else "paged_latent_decode",
+    )
+    if not split:
+        out = fn(*prefetch, qg, latent_pages)
+        return out[:, 0, :hq, :]
+    acc, m, l = fn(*prefetch, qg, latent_pages)
+    out = decode_common.combine_split_states(acc, m[..., :1], l[..., :1])
+    return out[:, 0, :hq, :].astype(q.dtype)

@@ -481,6 +481,7 @@ def decode_pages_per_block(
     *,
     num_splits: int = 1,
     vmem_budget_bytes: Optional[int] = None,
+    latent: bool = False,
 ) -> int:
     """Pages per compute block of the paged decode kernel, from the shape
     alone: :data:`PAGED_DECODE_BLOCK_TOKENS` worth of pages, clamped to the
@@ -496,17 +497,26 @@ def decode_pages_per_block(
     ppb = min(ppb, max_pages, -(-max_pages // max(1, num_splits)))
     ppb = max(1, ppb)
     while ppb > 1 and paged_decode_vmem_bytes(
-        ppb * page_size, head_dim, dtype_bytes
+        ppb * page_size, head_dim, dtype_bytes, latent=latent,
     ) > vmem_budget_bytes:
         ppb //= 2
     return ppb
 
 
 def paged_decode_vmem_bytes(block_tokens: int, head_dim: int,
-                            dtype_bytes: int) -> int:
+                            dtype_bytes: int, *, latent: bool = False) -> int:
     """VMEM the paged decode kernel holds for one block size: K and V
-    double buffers in the pool's dtype, plus their fp32 widenings."""
+    double buffers in the pool's dtype, plus their fp32 widenings. The
+    latent mode holds one double-buffered pool block and widens nothing."""
+    if latent:
+        return block_tokens * head_dim * 2 * dtype_bytes
     return block_tokens * head_dim * (2 * 2 * dtype_bytes + 2 * 4)
+
+
+#: Query rows (heads x positions) per cell of the latent prefill kernel,
+#: and the key block it walks, in tokens.
+LATENT_PREFILL_ROWS = 512
+LATENT_PREFILL_BLOCK_TOKENS = 256
 
 
 # -----------------------------------------------------------------------------
@@ -537,10 +547,11 @@ def _plan_cached(
     num_devices: int,
     device_link_bw: Optional[float],
     kv_dtype: str,
+    latent: bool = False,
 ) -> AttentionPlan:
     if mapping_name != "auto":
         mapping = PAPER_MAPPINGS[mapping_name]  # KeyError = fail fast
-    elif phase == EXTEND and kv_layout == PAGED:
+    elif latent or (phase == EXTEND and kv_layout == PAGED):
         # The paged prefill kernel takes no MappingConfig (its schedule is
         # the fixed head-first page walk); skip the candidate sweep and
         # carry the default paper schedule for introspection only.
@@ -563,7 +574,12 @@ def _plan_cached(
         placement = "head_aligned"
 
     resolved_impl = _resolve_impl(phase, kv_layout, impl, backend)
-    if phase == EXTEND and kv_layout == PAGED and impl == "auto" \
+    if latent and phase != DECODE:
+        # The latent prefill kernel has no gather-route rival on the chip;
+        # elsewhere "auto" takes the gather oracle, as decode does.
+        resolved_impl = ("pallas" if impl == "pallas"
+                         or (impl == "auto" and backend == "tpu") else "xla")
+    elif phase == EXTEND and kv_layout == PAGED and impl == "auto" \
             and prefix_pages > 0:
         # Route choice (PR-4 satellite): the paged kernel reads the prefix
         # once but exposes only B x Hkv parallel cells; the gather route
@@ -611,6 +627,7 @@ def _plan_cached(
         pages_per_block = decode_pages_per_block(
             -(-seq_kv // page_size), page_size, head_dim, dtype_bytes,
             num_splits=num_splits, vmem_budget_bytes=vmem_budget_bytes,
+            latent=latent,
         )
 
     return AttentionPlan(
@@ -650,6 +667,7 @@ def plan_attention(
     num_devices: int = 1,
     device_link_bw: Optional[float] = None,
     kv_dtype: str = "fp32",
+    latent: bool = False,
 ) -> AttentionPlan:
     """Resolve the best :class:`AttentionPlan` for an attention shape.
 
@@ -677,6 +695,11 @@ def plan_attention(
 
     ``vmem_budget_bytes=None`` takes the resident-K/V budget from the
     chip's VMEM (:func:`vmem_limits`); the compiler limit always does.
+
+    ``latent`` plans the latent-attention kernels (one pool head,
+    ``num_kv_heads`` 1, every query head in the q block, ``head_dim`` the
+    latent width): decode blocks hold one pool copy, and prefill runs the
+    latent kernel on the chip and the gather oracle elsewhere.
     """
     if phase not in PHASES:
         raise ValueError(f"unknown phase {phase!r}; expected one of {PHASES}")
@@ -716,6 +739,7 @@ def plan_attention(
         int(num_devices),
         float(device_link_bw) if device_link_bw is not None else None,
         kv_dtype,
+        bool(latent),
     )
     for plans in _RECORDERS:
         plans.append(plan)
@@ -804,6 +828,7 @@ def plan_for_config(
     num_devices: int = 1,
     device_link_bw: Optional[float] = None,
     kv_dtype: str = "fp32",
+    latent: bool = False,
 ) -> AttentionPlan:
     """:func:`plan_attention` with the schedule/impl policy read from a
     ``ModelConfig``. Models, engines and benchmarks call this instead of
@@ -827,6 +852,7 @@ def plan_for_config(
         num_devices=num_devices,
         device_link_bw=device_link_bw,
         kv_dtype=kv_dtype,
+        latent=latent,
     )
 
 

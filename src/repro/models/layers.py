@@ -18,6 +18,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def default_dtype():
@@ -63,16 +64,52 @@ def apply_rotary(
     x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0
 ) -> jnp.ndarray:
     """x: (B, H, S, D); positions: (B, S) or (S,) absolute positions."""
-    d = x.shape[-1]
-    freqs = rotary_freqs(d, theta)
+    return apply_rotary_freqs(x, positions, rotary_freqs(x.shape[-1], theta))
+
+
+def apply_rotary_freqs(
+    x: jnp.ndarray, positions: jnp.ndarray, inv_freq, scale: float = 1.0
+) -> jnp.ndarray:
+    """Rotate the two halves of ``x``'s last axis by ``positions`` times the
+    given inverse frequencies (``(D/2,)``), cos and sin scaled by ``scale``.
+    x: (B, H, S, D); positions: (B, S) or (S,)."""
     if positions.ndim == 1:
         positions = positions[None, :]
+    freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, D/2)
     cos = jnp.cos(angles)[:, None]  # (B, 1, S, D/2)
     sin = jnp.sin(angles)[:, None]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature factor ``0.1 * mscale * ln(scale) + 1``."""
+    return 1.0 if scale <= 1.0 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, base: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies (DeepSeek-V2's ``rope_scaling``): the
+    interpolated ``base^(-2i/dim) / factor`` on low frequencies, the
+    original on high ones, and a linear ramp over the dims between
+    ``low = floor(d(beta_fast))`` and ``high = ceil(d(beta_slow))`` with
+    ``d(b) = dim * ln(original_max / (b * 2 pi)) / (2 ln base)``."""
+    def correction_dim(rotations):
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inter = extra / factor
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    mask = 1.0 - ramp
+    return (inter * (1.0 - mask) + extra * mask).astype(np.float32)
 
 
 # -----------------------------------------------------------------------------

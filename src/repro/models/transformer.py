@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import LayerSpec, ModelConfig
 from repro.models import attention as attn_lib
-from repro.models import layers, moe as moe_lib, ssm as ssm_lib
+from repro.models import layers, mla as mla_lib, moe as moe_lib, ssm as ssm_lib
 
 Params = Dict[str, Any]
 
@@ -36,7 +36,8 @@ def init_layer(key, cfg: ModelConfig, spec: LayerSpec) -> Params:
     ks = jax.random.split(key, 6)
     p: Params = {"ln1": layers.init_rmsnorm(cfg.d_model)}
     if spec.kind in ("attn", "hybrid"):
-        p["attn"] = attn_lib.init_attention(ks[0], cfg)
+        p["attn"] = (mla_lib.init_mla(ks[0], cfg) if cfg.mla
+                     else attn_lib.init_attention(ks[0], cfg))
     if spec.kind in ("mamba", "hybrid"):
         p["mamba"] = ssm_lib.init_mamba(ks[1], cfg.d_model, cfg.ssm)
     if spec.kind == "hybrid":
@@ -55,12 +56,24 @@ def init_layer(key, cfg: ModelConfig, spec: LayerSpec) -> Params:
     return p
 
 
-def _zero_aux() -> Dict[str, jnp.ndarray]:
-    return {
+def _zero_aux(tokens: Optional[int] = None) -> Dict[str, jnp.ndarray]:
+    """The layers' summed aux. ``tokens``: also count, per token, the
+    routed slots landing on this device's held experts (serving modes of
+    an expert stack)."""
+    aux = {
         "moe_lb_loss": jnp.zeros((), jnp.float32),
         "moe_z_loss": jnp.zeros((), jnp.float32),
         "moe_dropped_frac": jnp.zeros((), jnp.float32),
     }
+    if tokens is not None:
+        aux["moe_held_slots"] = jnp.zeros((tokens,), jnp.int32)
+    return aux
+
+
+def _aux_tokens(cfg: ModelConfig, mode: str, x) -> Optional[int]:
+    if cfg.moe is None or mode == "train":
+        return None
+    return x.shape[0] * x.shape[1]
 
 
 def apply_layer(
@@ -90,11 +103,28 @@ def apply_layer(
     length, and ``plan`` the engine-resolved
     :class:`~repro.kernels.plan.AttentionPlan` for the extend phase.
     """
-    aux = _zero_aux()
+    aux = _zero_aux(_aux_tokens(cfg, mode, x))
     h = layers.rmsnorm(p["ln1"], x)
     new_cache: Params = {}
 
+    def run_mla():
+        if mode == "train":
+            return mla_lib.mla_block(p["attn"], h, cfg, positions=positions), None
+        c = None if cache is None else cache.get("attn")
+        if mode == "prefill":
+            return mla_lib.mla_prefill(
+                p["attn"], h, cfg, cache_len=cache_len,
+                pool=None if c is None else c["latent_pages"],
+                page_table=page_table, prefix_len=prefix_len, tail_len=lengths,
+                positions=positions,
+            )
+        if c is None or "latent_pages" not in c:
+            raise ValueError("latent attention decodes over the paged latent pool")
+        return mla_lib.mla_decode_paged(p["attn"], h, cfg, c, page_table, lengths)
+
     def run_attn():
+        if cfg.mla is not None:
+            return run_mla()
         if mode == "train":
             return attn_lib.attention_block(
                 p["attn"], h, cfg, spec, positions=positions,
@@ -186,9 +216,12 @@ def apply_layer(
     if spec.ffn == "mlp":
         x = x + layers.mlp(p["mlp"], layers.rmsnorm(p["ln2"], x))
     elif spec.ffn == "moe":
-        y, moe_aux = moe_lib.moe_ffn(
-            p["moe"], layers.rmsnorm(p["ln2"], x), cfg.moe, shard_buffers=shard_moe
-        )
+        hm = layers.rmsnorm(p["ln2"], x)
+        if mode == "train":
+            y, moe_aux = moe_lib.moe_ffn(p["moe"], hm, cfg.moe,
+                                         shard_buffers=shard_moe)
+        else:
+            y, moe_aux = moe_lib.moe_ffn_dropless(p["moe"], hm, cfg.moe)
         aux = {k: aux[k] + moe_aux[k] for k in aux}
         x = x + y
     return x, (new_cache or None), aux
@@ -237,6 +270,7 @@ def init_model_eager(key, cfg: ModelConfig) -> Params:
     pattern, rem = cfg.pattern_for_depth()
     n_periods = cfg.n_periods
     ks = jax.random.split(key, 4 + len(rem))
+    lead = cfg.lead_pattern
     params: Params = {}
     if cfg.num_codebooks > 1:
         params["embed"] = {
@@ -260,6 +294,11 @@ def init_model_eager(key, cfg: ModelConfig) -> Params:
             for p in range(n_periods)
         ])
         stacks.append(jax.vmap(lambda k, spec=spec: init_layer(k, cfg, spec))(keys))
+    if lead:
+        params["layers_lead"] = tuple(
+            init_layer(jax.random.fold_in(ks[1], 1 << 20 | i), cfg, spec)
+            for i, spec in enumerate(lead)
+        )
     params["layers"] = tuple(stacks)
     params["layers_rem"] = tuple(
         init_layer(ks[4 + i], cfg, spec) for i, spec in enumerate(rem)
@@ -313,7 +352,22 @@ def _run_stack(
     prefix_len=None, plan=None, shard_moe=lambda t: t, remat: bool = False,
 ):
     pattern, rem = cfg.pattern_for_depth()
-    aux_tot = _zero_aux()
+    aux_tot = _zero_aux(_aux_tokens(cfg, mode, x))
+    kw = dict(
+        mode=mode, lengths=lengths, positions=positions,
+        encoder_states=encoder_states, cache_len=cache_len,
+        page_table=page_table, prefix_len=prefix_len, plan=plan,
+        shard_moe=shard_moe,
+    )
+
+    new_lead = []
+    lead_caches = caches.get("lead") if caches else None
+    for i, spec in enumerate(cfg.lead_pattern):
+        c_i = None if lead_caches is None else lead_caches[i]
+        x, nc, a = apply_layer(params["layers_lead"][i], x, cfg, spec,
+                               cache=c_i, **kw)
+        new_lead.append(nc)
+        aux_tot = {k: aux_tot[k] + a[k] for k in aux_tot}
 
     def period_body(carry, xs_cache):
         x, aux = carry
@@ -363,6 +417,8 @@ def _run_stack(
     new_caches = None
     if mode in ("prefill", "decode"):
         new_caches = {"scanned": new_scanned, "rem": tuple(new_rem)}
+        if cfg.lead_pattern:
+            new_caches["lead"] = tuple(new_lead)
     return x, new_caches, aux_tot
 
 
@@ -401,8 +457,11 @@ def prefill(
     prefix_len: Optional[jnp.ndarray] = None,
     plan=None,
     shard_moe=lambda t: t,
+    return_aux: bool = False,
 ) -> Tuple[jnp.ndarray, Params]:
-    """Prefill: returns (logits at the last real position (B,V[,K]), caches).
+    """Prefill: returns (logits at the last real position (B,V[,K]), caches)
+    and, with ``return_aux``, the layers' summed aux third (an expert
+    stack's ``moe_held_slots`` is per token, (B*S,)).
 
     ``last_positions`` (B,): per-sequence index of the final prompt token
     (for right-padded prompts); defaults to S-1. Only one position's logits
@@ -438,7 +497,7 @@ def prefill(
             last_positions + 1 if last_positions is not None
             else jnp.full((b,), s, jnp.int32)
         )
-    x, caches, _ = _run_stack(
+    x, caches, aux = _run_stack(
         params, cfg, x, mode="prefill", encoder_states=enc,
         cache_len=cache_len, caches=prefix_caches, lengths=tail_len,
         positions=positions, page_table=page_table, prefix_len=prefix_len,
@@ -449,6 +508,8 @@ def prefill(
     else:
         x = jnp.take_along_axis(x, last_positions[:, None, None], axis=1)
     x = layers.rmsnorm(params["ln_f"], x)
+    if return_aux:
+        return _logits(params, cfg, x)[:, 0], caches, aux
     return _logits(params, cfg, x)[:, 0], caches
 
 
@@ -461,24 +522,31 @@ def decode_step(
     *,
     page_table: Optional[jnp.ndarray] = None,
     shard_moe=lambda t: t,
+    return_aux: bool = False,
 ) -> Tuple[jnp.ndarray, Params]:
-    """One decode step: returns (logits (B,V[,K]), updated caches).
+    """One decode step: returns (logits (B,V[,K]), updated caches) and,
+    with ``return_aux``, the layers' summed aux third.
 
     ``page_table`` (B, max_pages): required when ``caches`` are paged
     (``init_paged_caches``); ignored for dense caches."""
     tok = token[:, None] if token.ndim == 1 else token[:, None, :]
     x = _embed_tokens(params, cfg, tok)
-    x, new_caches, _ = _run_stack(
+    x, new_caches, aux = _run_stack(
         params, cfg, x, mode="decode", caches=caches, lengths=lengths,
         page_table=page_table, shard_moe=shard_moe,
     )
     x = layers.rmsnorm(params["ln_f"], x)
+    if return_aux:
+        return _logits(params, cfg, x)[:, 0], new_caches, aux
     return _logits(params, cfg, x)[:, 0], new_caches
 
 
 def init_caches(params: Params, cfg: ModelConfig, batch: int, cache_len: int,
                 image_len: int = 0) -> Params:
     """Zero caches with the same tree structure prefill would emit."""
+    if cfg.mla is not None:
+        raise ValueError("latent attention is served over a paged latent "
+                         "pool (init_paged_caches), not dense stripes")
     dt = jnp.dtype(cfg.compute_dtype)
     pattern, rem = cfg.pattern_for_depth()
 
@@ -498,7 +566,10 @@ def init_caches(params: Params, cfg: ModelConfig, batch: int, cache_len: int,
         )
         for spec in pattern
     )
-    return {"scanned": scanned, "rem": tuple(one(s) for s in rem)}
+    caches = {"scanned": scanned, "rem": tuple(one(s) for s in rem)}
+    if cfg.lead_pattern:
+        caches["lead"] = tuple(one(s) for s in cfg.lead_pattern)
+    return caches
 
 
 def init_paged_caches(
@@ -510,17 +581,24 @@ def init_paged_caches(
     layer, vLLM-style). Only pure-attention stacks support paging — SSM
     state and cross-attention K/V are not page-structured. ``kv_dtype``
     selects the pool storage format (``cache.quant``): quantized pools
-    carry per-(head, page) scale arrays next to the code pools."""
+    carry per-(head, page) scale arrays next to the code pools. A latent
+    attention stack holds one latent pool per layer (``mla``), and the
+    layers before the scanned periods sit under ``"lead"``."""
     dt = jnp.dtype(cfg.compute_dtype)
     pattern, rem = cfg.pattern_for_depth()
-    for spec in list(pattern) + list(rem):
+    for spec in cfg.all_layers():
         if spec.kind != "attn" or spec.cross_attn:
             raise ValueError(
                 "paged caches require a pure self-attention stack; "
                 f"got layer kind={spec.kind!r} cross_attn={spec.cross_attn}"
             )
+    if cfg.mla is not None and kv_dtype != "fp32":
+        raise ValueError(f"the latent pool is stored in {cfg.compute_dtype}; "
+                         f"kv_dtype={kv_dtype!r} is not supported")
 
     def one(_spec: LayerSpec):
+        if cfg.mla is not None:
+            return {"attn": mla_lib.init_paged_cache(cfg, num_pages, page_size, dt)}
         return {"attn": attn_lib.init_paged_cache(
             cfg, num_pages, page_size, dt, kv_dtype=kv_dtype
         )}
@@ -531,4 +609,7 @@ def init_paged_caches(
         )
         for spec in pattern
     )
-    return {"scanned": scanned, "rem": tuple(one(s) for s in rem)}
+    caches = {"scanned": scanned, "rem": tuple(one(s) for s in rem)}
+    if cfg.lead_pattern:
+        caches["lead"] = tuple(one(s) for s in cfg.lead_pattern)
+    return caches

@@ -77,6 +77,37 @@ class _SeqState:
         self.submit_order = submit_order
 
 
+def _held_slot_counter(cfg: ModelConfig):
+    """Wraps a prefill's outputs: an expert stack's ``(logits, caches,
+    aux)`` becomes ``(logits, caches, held)``, ``held`` the routed slots
+    of the real tail tokens (``last_positions`` + 1 per row) that landed
+    on held experts, over every layer; other stacks' outputs pass."""
+    if cfg.moe is None:
+        return lambda out, last_positions: out
+
+    def count(out, last_positions):
+        logits, caches, aux = out
+        per_tok = aux["moe_held_slots"].reshape(last_positions.shape[0], -1)
+        real = jnp.arange(per_tok.shape[1])[None] <= last_positions[:, None]
+        return logits, caches, jnp.sum(jnp.where(real, per_tok, 0))
+
+    return count
+
+
+def _map_groups(fn, caches, *others):
+    """``fn(layer, *other_layers, scanned)`` over every layer of a cache
+    tree: the scanned periods (a leading periods axis), the remainder and
+    the lead layers (none), in the tree's own structure."""
+    out = {}
+    for group, scanned in (("scanned", True), ("rem", False), ("lead", False)):
+        if group in caches:
+            out[group] = tuple(
+                fn(*layers, scanned)
+                for layers in zip(caches[group], *(o[group] for o in others))
+            )
+    return out
+
+
 class _Backend:
     """Shared row bookkeeping + policy hooks."""
 
@@ -263,6 +294,9 @@ class _Backend:
         paged = self.kv_layout == "paged"
         stats = self.stats
         constrain = self._constrain
+        # An expert stack also returns, per tick, the live rows' routed
+        # slots that landed on held experts, summed over its layers.
+        experts = cfg.moe is not None
 
         def decode_scan(params, caches, pt, tok, lengths, gen, done,
                         temps, top_k, top_p, seeds, stops, max_toks):
@@ -275,12 +309,15 @@ class _Backend:
                 caches, pt, tok, lengths, gen, done = carry
                 live = ~done
                 lengths1 = lengths + live.astype(lengths.dtype)
-                if paged:
-                    logits, caches1 = transformer.decode_step(
-                        params, cfg, tok, caches, lengths1, page_table=pt)
+                kw = dict(page_table=pt) if paged else {}
+                if experts:
+                    logits, caches1, aux = transformer.decode_step(
+                        params, cfg, tok, caches, lengths1, return_aux=True,
+                        **kw)
+                    held = jnp.sum(jnp.where(live, aux["moe_held_slots"], 0))
                 else:
                     logits, caches1 = transformer.decode_step(
-                        params, cfg, tok, caches, lengths1)
+                        params, cfg, tok, caches, lengths1, **kw)
                 # Keep the scan carry head-sharded: without the pin GSPMD
                 # may re-layout the donated caches between ticks, turning
                 # the device-local page walk into resharding traffic.
@@ -308,6 +345,8 @@ class _Backend:
                 tok1 = (jnp.where(keep[:, None], nxt, tok) if multi
                         else jnp.where(keep, nxt, tok))
                 y = (tok, nxt, live, append_nxt, fed_stop, hit_max)
+                if experts:
+                    y += (held.astype(jnp.int32),)
                 return (caches1, pt1, tok1, lengths1, gen2, done | newly), y
 
             def skip(carry):
@@ -315,7 +354,10 @@ class _Backend:
                 # tick's masks read "nothing happened" on the host.
                 tok = carry[2]
                 false = jnp.zeros_like(carry[5])
-                return carry, (tok, tok, false, false, false, false)
+                y = (tok, tok, false, false, false, false)
+                if experts:
+                    y += (jnp.zeros((), jnp.int32),)
+                return carry, y
 
             def body(carry, _):
                 return jax.lax.cond(carry[5].all(), skip, tick, carry)
@@ -676,6 +718,9 @@ class PagedBackend(_Backend):
         #: publishing request uid) for pages admitted-but-not-yet-flushed
         #: this round. Cleared by :meth:`flush` once everything published.
         self._pending_pages: Dict[bytes, Tuple[int, int]] = {}
+        #: An expert stack's held-expert slots of the last flush, one
+        #: count per launch (``_held_slot_counter``).
+        self.flush_held_slots: List[int] = []
         self.caches = transformer.init_paged_caches(
             params, cfg, num_pages, page_size, kv_dtype=kv_dtype
         )
@@ -723,7 +768,13 @@ class PagedBackend(_Backend):
         (Hkv / D)-head K+V slice of that page, summed over every layer
         (one pool per attention layer, all driven by the same ids).
         Quantized pools store 1-byte codes plus one fp32 scale per
-        (kv head, page) for K and V each."""
+        (kv head, page) for K and V each. A latent-attention stack holds
+        one lane-padded latent row per token a layer."""
+        if cfg.mla is not None:
+            from repro.models import mla as mla_lib
+
+            return (cfg.n_layers * page_size
+                    * mla_lib.latent_bytes_per_token(cfg)[1])
         heads_dev = -(-cfg.n_kv_heads // max(num_devices, 1))
         if kv_dtype in quant.QMAX:
             per_head = page_size * cfg.head_dim * quant.kv_itemsize(kv_dtype) + 4
@@ -934,6 +985,10 @@ class PagedBackend(_Backend):
 
         def layer(c, t, scanned):
             a = c["attn"]
+            if "latent_pages" in a:
+                lp, _ = s(a["latent_pages"], None, t["attn"]["latent"],
+                          scanned, "fp32")
+                return {"attn": {"latent_pages": lp}}
             kv_dtype = quant.kv_dtype_of(a["k_pages"].dtype)
             kp, ks = s(a["k_pages"], a.get("k_scales"), t["attn"]["k"],
                        scanned, kv_dtype)
@@ -945,16 +1000,7 @@ class PagedBackend(_Backend):
                 out["v_scales"] = vs
             return {"attn": out}
 
-        return {
-            "scanned": tuple(
-                layer(c, t, True)
-                for c, t in zip(caches["scanned"], tail_caches["scanned"])
-            ),
-            "rem": tuple(
-                layer(c, t, False)
-                for c, t in zip(caches["rem"], tail_caches["rem"])
-            ),
-        }
+        return _map_groups(layer, caches, tail_caches)
 
     @staticmethod
     def _copy_page(caches, src, dst):
@@ -969,6 +1015,8 @@ class PagedBackend(_Backend):
 
         def layer(c, scanned):
             a = c["attn"]
+            if "latent_pages" in a:
+                return {"attn": {"latent_pages": cp(a["latent_pages"], scanned)}}
             out = {
                 "k_pages": cp(a["k_pages"], scanned),
                 "v_pages": cp(a["v_pages"], scanned),
@@ -978,10 +1026,7 @@ class PagedBackend(_Backend):
                 out["v_scales"] = quant.cow_scales(a["v_scales"], src, dst)
             return {"attn": out}
 
-        return {
-            "scanned": tuple(layer(c, True) for c in caches["scanned"]),
-            "rem": tuple(layer(c, False) for c in caches["rem"]),
-        }
+        return _map_groups(layer, caches)
 
     @staticmethod
     def _restore_page(caches, payload, dst):
@@ -998,6 +1043,9 @@ class PagedBackend(_Backend):
 
         def layer(c, pl, scanned):
             a = c["attn"]
+            if "latent_pages" in a:
+                return {"attn": {"latent_pages": put(a["latent_pages"],
+                                                     pl["latent"], scanned)}}
             out = {
                 "k_pages": put(a["k_pages"], pl["k"], scanned),
                 "v_pages": put(a["v_pages"], pl["v"], scanned),
@@ -1011,16 +1059,7 @@ class PagedBackend(_Backend):
                 )
             return {"attn": out}
 
-        return {
-            "scanned": tuple(
-                layer(c, p, True)
-                for c, p in zip(caches["scanned"], payload["scanned"])
-            ),
-            "rem": tuple(
-                layer(c, p, False)
-                for c, p in zip(caches["rem"], payload["rem"])
-            ),
-        }
+        return _map_groups(layer, caches, payload)
 
     def _page_payload(self, pid: int):
         """Host (numpy) copy of one physical page across every layer's
@@ -1035,6 +1074,8 @@ class PagedBackend(_Backend):
                 (slice(None), slice(None), pid) if scanned
                 else (slice(None), pid)
             )
+            if "latent_pages" in a:
+                return {"latent": np.asarray(a["latent_pages"][idx])}
             out = {
                 "k": np.asarray(a["k_pages"][idx]),
                 "v": np.asarray(a["v_pages"][idx]),
@@ -1044,10 +1085,7 @@ class PagedBackend(_Backend):
                 out["vs"] = np.asarray(a["v_scales"][..., pid])
             return out
 
-        return {
-            "scanned": tuple(grab(c, True) for c in self.caches["scanned"]),
-            "rem": tuple(grab(c, False) for c in self.caches["rem"]),
-        }
+        return _map_groups(grab, self.caches)
 
     # -- prefill -----------------------------------------------------------
 
@@ -1075,13 +1113,15 @@ class PagedBackend(_Backend):
         key = (bucket, prefix_pages, rows)
         if key not in self._prefill_p:
             cfg = self.cfg
+            count = _held_slot_counter(cfg)
 
             if prefix_pages == 0:
                 def prefill(params, tokens, last_positions):
-                    return transformer.prefill(
+                    return count(transformer.prefill(
                         params, cfg, tokens, cache_len=bucket,
                         last_positions=last_positions,
-                    )
+                        return_aux=cfg.moe is not None,
+                    ), last_positions)
 
                 fn = prefill
             else:
@@ -1096,12 +1136,13 @@ class PagedBackend(_Backend):
 
                 def extend(params, tokens, last_positions, caches,
                            page_table, prefix_len):
-                    return transformer.prefill(
+                    return count(transformer.prefill(
                         params, cfg, tokens, cache_len=bucket,
                         last_positions=last_positions,
                         prefix_caches=caches, page_table=page_table,
                         prefix_len=prefix_len, plan=plan,
-                    )
+                        return_aux=cfg.moe is not None,
+                    ), last_positions)
 
                 fn = extend
             self._prefill_p[key] = jax.jit(self._on_mesh(fn))
@@ -1345,6 +1386,7 @@ class PagedBackend(_Backend):
         the bit-exactness oracle in tests). Returns per-row last-position
         logits."""
         first_logits: Dict[int, np.ndarray] = {}
+        self.flush_held_slots = []
         todo = list(records)
         published: set = set()
         while todo:
@@ -1392,7 +1434,7 @@ class PagedBackend(_Backend):
                 self.stats["batched_prefills"] += rows > 1
                 fn = self._prefill_paged_fn(bucket, mb, rows)
                 if mb == 0:
-                    logits, tail_caches = fn(
+                    logits, tail_caches, *held = fn(
                         self.params, jnp.asarray(padded), last
                     )
                 else:
@@ -1408,7 +1450,7 @@ class PagedBackend(_Backend):
                         [len(r["matched"]) * ps for r in grp], jnp.int32
                     )
                     self.stats["extend_prefills"] += rows
-                    logits, tail_caches = fn(
+                    logits, tail_caches, *held = fn(
                         self.params, jnp.asarray(padded), last, self.caches,
                         jnp.asarray(pt), plens,
                     )
@@ -1423,6 +1465,10 @@ class PagedBackend(_Backend):
                     self.caches, tail_caches, jnp.asarray(pids)
                 )
                 logits_np = np.asarray(logits)
+                if held:
+                    # Read once the logits' transfer has waited for this
+                    # launch, so the count adds no wait of its own.
+                    self.flush_held_slots.append(int(np.asarray(held[0])))
                 for i, r in enumerate(grp):
                     # Publish this prompt's full pages for later requests.
                     if self.prefix_sharing:
@@ -1657,7 +1703,7 @@ class PagedBackend(_Backend):
              1, self.cache_len, self.cfg.head_dim),
             phase=plan_lib.DECODE, kv_layout=plan_lib.PAGED,
             page_size=self.page_size, num_devices=self.num_devices,
-            kv_dtype=self.kv_dtype,
+            kv_dtype=self.kv_dtype, latent=self.cfg.mla is not None,
         )
 
     def modeled_kv_layout(self) -> str:

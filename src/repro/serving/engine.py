@@ -53,6 +53,7 @@ import numpy as np
 from repro.cache.pool import OutOfPages
 from repro.configs.base import ModelConfig
 from repro.kernels import plan as plan_lib
+from repro.models import mla as mla_lib
 from repro.obs import NULL_TELEMETRY, Telemetry
 from repro.serving import sampling as sampling_lib
 from repro.serving.backends import DenseBackend, PagedBackend
@@ -83,10 +84,9 @@ def _paged_supported(cfg: ModelConfig) -> bool:
     stacks, single-codebook streams (mirrors ``init_paged_caches``)."""
     if cfg.num_codebooks != 1:
         return False
-    pattern, rem = cfg.pattern_for_depth()
     return all(
         spec.kind == "attn" and not spec.cross_attn
-        for spec in list(pattern) + list(rem)
+        for spec in cfg.all_layers()
     )
 
 
@@ -232,6 +232,8 @@ class LLMEngine:
 
         self._pending: Dict[int, np.ndarray] = {}   # row -> next token
         self._last_ticks = 0                        # live ticks, last scan
+        self._last_held = None                      # held-expert slots a tick
+        self._moe_layers = sum(1 for s in cfg.all_layers() if s.ffn == "moe")
         self._last_kv_lens: List[List[int]] = []    # per live tick (traced)
         self._streamed: Dict[int, int] = {}         # uid -> tokens emitted
         #: uid -> buffered outputs for live stream() consumers. Only uids
@@ -400,8 +402,12 @@ class LLMEngine:
                             reused_pages=len(rec.get("matched", ())))
                 if records:
                     tf = time.perf_counter()
-                    with self._tr.span("flush", rows=len(records)):
+                    with self._tr.span("flush", rows=len(records)) as sp:
                         self._flush(records)
+                        held = getattr(self.backend, "flush_held_slots", None)
+                        if self._tr.enabled and held:
+                            sp.args["held_slots"] = sum(held)
+                            sp.args["moe_layers"] = self._moe_layers
                     self._h_flush.observe(time.perf_counter() - tf)
             outputs: List[RequestOutput] = []
             if self.backend.active.any():
@@ -416,6 +422,9 @@ class LLMEngine:
                     if self._tr.enabled:
                         sp.args["ticks"] = self._last_ticks
                         sp.args["kv_lens"] = self._last_kv_lens
+                        if self._last_held is not None:
+                            sp.args["held_slots"] = self._last_held
+                            sp.args["moe_layers"] = self._moe_layers
                 dt = time.perf_counter() - td
                 self._h_decode.observe(dt)
                 self._decode_elapsed += dt
@@ -593,6 +602,13 @@ class LLMEngine:
             host_bytes_resident=int(prefix.get("host_bytes_resident", 0)),
             decode_splits=plan.num_splits if plan else 1,
             pages_per_block=plan.pages_per_block if plan else 0,
+            latent_bytes_per_token=(
+                mla_lib.latent_bytes_per_token(self.cfg)[0]
+                if self.cfg.mla else 0),
+            held_experts=(
+                (self.cfg.moe.held_start,
+                 self.cfg.moe.held_start + self.cfg.moe.held)
+                if self.cfg.moe else None),
         )
 
     def drift_model_fn(self):
@@ -701,6 +717,7 @@ class LLMEngine:
             if not rows:
                 self._last_ticks = 0
                 self._last_kv_lens = []
+                self._last_held = None
                 return []
             shape = (b.rows,) if self.cfg.num_codebooks == 1 else (
                 b.rows, self.cfg.num_codebooks)
@@ -729,11 +746,15 @@ class LLMEngine:
         :class:`RequestOutput` per row per sync, however many ticks ran."""
         b = self.backend
         with self._tr.span("decode.sync"):
-            tok_seq, nxt_seq, live, appended, fed_stop, hit_max = (
+            tok_seq, nxt_seq, live, appended, fed_stop, hit_max, *held = (
                 np.asarray(y) for y in ys)
             lengths_f = np.asarray(lengths_f)
         with self._tr.span("decode.replay"):
             self._last_ticks = int(live.any(axis=1).sum())
+            # An expert stack's held-expert slots of each live tick,
+            # summed over its layers.
+            self._last_held = (held[0][: self._last_ticks].tolist()
+                               if held else None)
             if self._tr.enabled:
                 # Live ticks come first; a row live at tick t attends over
                 # its pre-scan length plus the t + 1 tokens fed so far.

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 #: Floor applied to positive temperatures only (temperature == 0 never
 #: reaches the stochastic branch); keeps the scale finite under jit.
@@ -95,12 +96,35 @@ def sample_tokens(logits, temperature, top_k, top_p, seed, stream_pos):
     ``stream_pos``: ``(B,)`` int. Returns int32 ``(B,)`` (or ``(B, K)``).
     Rows with ``temperature <= 0`` are exact argmax and consume no
     randomness.
+
+    The row count is padded to a power of two (greedy rows of zeros,
+    dropped from the result), so a caller whose batch varies, as the
+    engine's first-token sample after each flush does, compiles one
+    program per bucket, not one per count. Rows are independent, so the
+    padding changes no row's token.
     """
-    return _sample_batch(
-        jnp.asarray(logits),
-        jnp.asarray(temperature, jnp.float32),
-        jnp.asarray(top_k, jnp.int32),
-        jnp.asarray(top_p, jnp.float32),
-        jnp.asarray(seed, jnp.int32),
-        jnp.asarray(stream_pos, jnp.int32),
-    )
+    rows = np.shape(logits)[0]
+    bucket = 1 << max(rows - 1, 0).bit_length()
+    args = [
+        _pad_rows(a, dt, bucket) for a, dt in (
+            (logits, None), (temperature, np.float32), (top_k, np.int32),
+            (top_p, np.float32), (seed, np.int32), (stream_pos, np.int32))
+    ]
+    out = _sample_batch(*(jnp.asarray(a) for a in args))
+    return out if bucket == rows else out[:rows]
+
+
+def _pad_rows(x, dtype, rows):
+    """``x`` as ``dtype`` (its own when None) with zero rows appended up
+    to ``rows``: on the host for host arrays, so the padding compiles
+    nothing."""
+    if isinstance(x, jax.Array):
+        x = x if dtype is None else x.astype(dtype)
+        pad = jnp.pad
+    else:
+        x = np.asarray(x, dtype)
+        pad = np.pad
+    extra = rows - x.shape[0]
+    if not extra:
+        return x
+    return pad(x, [(0, extra)] + [(0, 0)] * (x.ndim - 1))

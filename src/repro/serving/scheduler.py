@@ -120,6 +120,8 @@ class SchedulerStats:
     host_bytes_resident: int = 0   # host-tier bytes currently held
     decode_splits: int = 1         # paged decode plan: split-K ranges
     pages_per_block: int = 0       # paged decode plan: pages a kernel block
+    latent_bytes_per_token: int = 0  # latent attention: one token's row, a layer
+    held_experts: Optional[Tuple[int, int]] = None  # [start, stop) held here
 
     def summary(self) -> str:
         prefix = ("n/a" if self.prefix_hit_rate is None

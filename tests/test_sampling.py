@@ -186,3 +186,28 @@ def test_legacy_request_kwargs_build_sampling_params():
 
         Request(uid=1, prompt=np.arange(4), sampling=SamplingParams(),
                 max_new_tokens=3)
+
+
+def test_row_counts_share_a_program_per_power_of_two():
+    """Row counts pad to a power of two: counts 1..8 compile four
+    programs (1, 2, 4, 8), and every row's token is the one it gets
+    alone, sampled or greedy."""
+    from repro.serving.sampling import _sample_batch
+
+    rng = np.random.default_rng(6)
+    logits = rng.normal(size=(8, 40)).astype(np.float32)
+    t = np.asarray([0.0, 1.1, 0.7, 0.0, 1.9, 0.5, 0.0, 1.3], np.float32)
+    k = np.zeros((8,), np.int32)
+    p = np.full((8,), 0.9, np.float32)
+    s = np.arange(8, dtype=np.int32) + 11
+    c = np.full((8,), 3, np.int32)
+    solo = [int(np.asarray(sample_tokens(
+        logits[i:i + 1], t[i:i + 1], k[:1], p[:1], s[i:i + 1], c[:1]))[0])
+        for i in range(8)]
+    before = _sample_batch._cache_size()
+    for n in range(1, 9):
+        out = np.asarray(sample_tokens(logits[:n], t[:n], k[:n], p[:n],
+                                       s[:n], c[:n]))
+        assert out.shape == (n,)
+        assert out.tolist() == solo[:n]
+    assert _sample_batch._cache_size() - before <= 3   # 2, 4, 8 are new

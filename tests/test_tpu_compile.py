@@ -26,8 +26,12 @@ from repro.kernels import ops
 from repro.kernels import plan as plan_lib
 from repro.kernels.decode_attention import flash_decode
 from repro.kernels.flash_attention import flash_attention_fwd
-from repro.kernels.paged_decode_attention import paged_flash_decode
-from repro.kernels.paged_prefill_attention import paged_flash_prefill
+from repro.kernels.paged_decode_attention import (
+    paged_flash_decode, paged_latent_decode,
+)
+from repro.kernels.paged_prefill_attention import (
+    paged_flash_prefill, paged_latent_prefill,
+)
 
 HQ, HKV, D = 8, 4, 256
 SOFTCAP, WINDOW = 50.0, 4096
@@ -203,6 +207,60 @@ def test_paged_prefill_compiles(one_chip, kv_dtype):
         )
 
     _compile(prefill, *args)
+
+
+#: The latent cell's shapes (``deepseek-v2-lite.session-wide``): 64 rows,
+#: 16 query heads on one latent pool of 576-wide rows (lane-padded to 640),
+#: 512 of them the values, pages of 16, 6144-token contexts.
+LATENT = dict(rows=64, heads=16, width=640, v_dim=512, pages=384,
+              pool=64 * 384 + 1 + 256)
+
+
+@pytest.mark.parametrize("num_splits", [1, 2])
+def test_latent_decode_compiles_at_cell_shapes(one_chip, v5e_kind, num_splits):
+    c = LATENT
+    plan = plan_lib.plan_attention(
+        (c["rows"], c["heads"], 1, 1, c["pages"] * PAGE, c["width"]),
+        phase=plan_lib.DECODE, kv_layout=plan_lib.PAGED, page_size=PAGE,
+        backend="tpu", latent=True,
+    )
+    assert plan.impl == "pallas"
+
+    def decode(q, pool, pt, ln):
+        return paged_latent_decode(
+            q, pool, pt, ln, v_dim=c["v_dim"], scale=0.1,
+            num_splits=num_splits, pages_per_block=plan.pages_per_block)
+
+    compiled = _compile(
+        decode, _sds(one_chip, (c["rows"], c["heads"], c["width"])),
+        _sds(one_chip, (1, c["pool"], PAGE, c["width"])),
+        _sds(one_chip, (c["rows"], c["pages"]), jnp.int32),
+        _sds(one_chip, (c["rows"],), jnp.int32))
+    assert "paged_latent_decode" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tail,prefix_pages", [(1024, 512), (6144, 0)])
+def test_latent_prefill_compiles_at_cell_shapes(one_chip, tail, prefix_pages):
+    """An extend over the largest prefix bucket, and a fresh history."""
+    c = LATENT
+    q = _sds(one_chip, (1, tail, c["heads"], c["width"]))
+    lat = _sds(one_chip, (1, tail, c["width"]))
+    lens = [_sds(one_chip, (1,), jnp.int32)] * 2
+    if prefix_pages:
+        def prefill(q, pool, pt, lat, pl_, tl):
+            return paged_latent_prefill(q, pool, pt, lat, pl_, tl,
+                                        v_dim=c["v_dim"], scale=0.1)
+
+        args = [q, _sds(one_chip, (1, c["pool"], PAGE, c["width"])),
+                _sds(one_chip, (1, prefix_pages), jnp.int32), lat, *lens]
+    else:
+        def prefill(q, lat, pl_, tl):
+            return paged_latent_prefill(q, None, None, lat, pl_, tl,
+                                        v_dim=c["v_dim"], scale=0.1)
+
+        args = [q, lat, *lens]
+    compiled = _compile(prefill, *args)
+    assert "paged_latent_prefill" in compiled.as_text()
 
 
 @pytest.mark.parametrize("num_splits", [1, 4])
